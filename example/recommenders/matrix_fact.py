@@ -2,7 +2,7 @@
 demo shape): user/item embeddings -> dot product -> rating regression,
 trained with Module.fit on synthetic low-rank ratings.
 
-Usage: python matrix_fact.py --num-epochs 8
+Usage: python matrix_fact.py --num-epochs 20
 """
 import argparse
 import os
@@ -41,26 +41,30 @@ def synthetic_ratings(num_users, num_items, factor, n, rng):
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--num-epochs", type=int, default=10)
+    ap.add_argument("--num-epochs", type=int, default=20)
     ap.add_argument("--batch-size", type=int, default=128)
     ap.add_argument("--num-users", type=int, default=200)
     ap.add_argument("--num-items", type=int, default=150)
     ap.add_argument("--factor", type=int, default=8)
-    ap.add_argument("--lr", type=float, default=0.1)
+    ap.add_argument("--lr", type=float, default=0.05)
     args = ap.parse_args()
 
     np.random.seed(0)       # NDArrayIter shuffle draws from the global rng
     mx.random.seed(0)
     rng = np.random.RandomState(0)
+    # ~95 ratings per user for 8 factors: the factorization is well
+    # determined (at 25 per user the held-out error plateaus near 0.7
+    # whatever the optimizer does)
+    n_train = 19000
     users, items, scores = synthetic_ratings(
-        args.num_users, args.num_items, args.factor, 6000, rng)
+        args.num_users, args.num_items, args.factor, n_train + 1000, rng)
 
     train = mx.io.NDArrayIter(
-        {"user": users[:5000], "item": items[:5000]},
-        {"score_label": scores[:5000]}, args.batch_size, shuffle=True)
+        {"user": users[:n_train], "item": items[:n_train]},
+        {"score_label": scores[:n_train]}, args.batch_size, shuffle=True)
     val = mx.io.NDArrayIter(
-        {"user": users[5000:], "item": items[5000:]},
-        {"score_label": scores[5000:]}, args.batch_size)
+        {"user": users[n_train:], "item": items[n_train:]},
+        {"score_label": scores[n_train:]}, args.batch_size)
 
     sym = build_symbol(args.num_users, args.num_items, args.factor)
     mod = mx.mod.Module(sym, data_names=["user", "item"],
@@ -73,10 +77,9 @@ def main():
     rmse = dict(mod.score(val, mx.metric.RMSE()))["rmse"]
     print("validation rmse %.4f" % rmse)
     # rank-8 truth with 0.05 noise: scores have std ~1.4, an unfit
-    # model sits there; adam at lr 0.1 is what actually generalizes in
-    # 10 epochs on this synthetic set (seeded run lands at ~0.62 —
-    # lr 0.05 stalls at ~1.04, lr 0.02 at ~1.08)
-    assert rmse < 0.75, rmse
+    # model sits there; adam at lr 0.05 lands at 0.32-0.34 in 20 epochs
+    # across seeds (lr 0.1 oscillates around 0.6)
+    assert rmse < 0.5, rmse
     print("matrix factorization done")
 
 

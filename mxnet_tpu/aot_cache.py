@@ -13,8 +13,11 @@ PAPERS.md) removes it from restart latency entirely:
   tells the watchdog the startup grace can shrink
   (:func:`mxnet_tpu.watchdog.note_warm_start`).
 
-**The donated-deserialize hazard.**  On this container's CPU backend
-(jaxlib 0.4.36 thunk runtime) executing a *deserialized* executable whose
+**The donated-deserialize hazard.**  On the CPU backend (reproduced
+against jaxlib 0.4.36; on 0.9.0 a handful of forced-donated warm starts
+ran clean under ``MALLOC_CHECK_=3``, which is not yet proof — ROADMAP D5
+settles it and then this machinery goes) executing a *deserialized*
+executable whose
 program has ``donate_argnums`` input-output aliasing corrupts the process
 heap: flaky SIGSEGV/SIGABRT inside ``execute_sharded``, double-frees at
 interpreter teardown, occasionally deterministic wrong numerics — all
@@ -65,25 +68,50 @@ from __future__ import annotations
 import atexit
 import contextlib
 import hashlib
-import io
 import os
 import pickle
 import threading
 
 from . import telemetry as _telemetry
 
-__all__ = ["cache_dir", "enabled", "fingerprint", "cache_key", "load",
+__all__ = ["enable_persistent_cache", "cache_dir", "enabled",
+           "fingerprint", "cache_key", "load",
            "store", "variant", "deserialized_donation_safe",
            "deserialized_spmd_safe", "bypass_persistent_cache",
            "donation_cache_guard", "memo_get", "memo_put", "clear_memo",
            "drain", "spawn_variant_store", "twin_hotswap_cell"]
 
-_FORMAT = "mxtpu-aot-4"  # bump to orphan every existing entry
+_FORMAT = "mxtpu-aot-5"  # bump to orphan every existing entry
 
 #: variants an entry can carry (exactly one per entry; the writer picks
 #: what its own backend can safely consume on restart)
 VARIANT_DONATED = "donated"
 VARIANT_PLAIN = "plain"
+
+
+#: jax's persistent compilation cache when JAX_COMPILATION_CACHE_DIR is
+#: unset: a FIXED path inside the checkout (git-ignored).  The path is
+#: part of the cache's key, so a directory that moves never hits —
+#: never derive it from a temporary name.  tools/launch.py exports the
+#: same path to its workers.
+DEFAULT_JAX_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
+
+
+def enable_persistent_cache():
+    """Place jax's persistent compilation cache before the first
+    compile (entry scripts call this: chip_smoke.py, bench.py,
+    tools/serve_worker.py).  Where the environment sets
+    ``JAX_COMPILATION_CACHE_DIR`` jax already uses it and nothing is
+    set in code; otherwise the cache goes to
+    :data:`DEFAULT_JAX_CACHE_DIR`.  Returns the directory in use."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        import jax
+        path = DEFAULT_JAX_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def cache_dir():
@@ -96,8 +124,8 @@ def enabled():
 
 def deserialized_donation_safe():
     """Can this backend EXECUTE a deserialized executable that donates
-    inputs?  False on CPU: jaxlib 0.4.36's thunk runtime corrupts the
-    heap replaying donated input-output aliasing from a deserialized
+    inputs?  False on CPU: the thunk runtime corrupted the heap
+    replaying donated input-output aliasing from a deserialized
     executable (module docstring; ROBUSTNESS.md §8).  TPU/GPU PJRT
     serialization is the supported production path.  Override with
     ``MXTPU_AOT_FORCE_DONATED=1`` after a jaxlib upgrade proves clean."""
@@ -114,10 +142,10 @@ def deserialized_spmd_safe():
     from bytes flakily corrupts the heap ("corrupted double-linked
     list" aborts mid `execute_sharded`) or deadlocks its collective
     rendezvous (participants waiting forever at the all-gather) —
-    reproduced standalone under MALLOC_CHECK_=3 against jaxlib 0.4.36,
-    PR-7 root cause (ROBUSTNESS.md §8).  So on such backends mesh
-    programs are never stored to or loaded from disk — the in-process
-    memo (the ORIGINAL compiled object) is their only warm tier, and a
+    reproduced standalone under MALLOC_CHECK_=3, PR-7 root cause
+    (ROBUSTNESS.md §8; module docstring for what is known on 0.9.0).
+    So on such backends mesh programs are never stored to or loaded
+    from disk — the in-process memo (the ORIGINAL compiled object) is their only warm tier, and a
     cross-process restart pays one compile.  TPU-class PJRT
     serialization remains the supported production path.  Shares the
     ``MXTPU_AOT_FORCE_DONATED=1`` override (one jaxlib upgrade gate
@@ -344,49 +372,32 @@ def donation_cache_guard(fn):
 
 
 # -- serialization ---------------------------------------------------------
-#
-# jax.experimental.serialize_executable.deserialize_and_load calls
-# ``backend.deserialize_executable(bytes)`` WITHOUT the executable's
-# CompileOptions; jax's persistent cache always passes them through
-# (compilation_cache.get_executable_and_time).  Entries carry the options
-# proto and loading goes through an options-passing unpickler so the
-# reconstructed executable matches what the compiler produced.  (This is
-# necessary hygiene but NOT sufficient to make donated deserialization
-# safe on CPU — see deserialized_donation_safe.)
+# jax.experimental.serialize_executable is the whole job: ``serialize``
+# pickles the unloaded executable (device and client references by
+# persistent id) and ``deserialize_and_load`` rebuilds a
+# jax.stages.Compiled on this process's backend.  The one thing it
+# cannot know is WHICH of the backend's devices the program ran on (it
+# defaults to all of them, so a one-device program comes back expecting
+# a shard per device): entries carry the executable's ordered device
+# ids.
 
 
 def _serialize(compiled):
-    """(pickled-executable, CompileOptions proto, in_tree, out_tree) for a
-    jax.stages.Compiled.  Raises if the executable exposes no options —
-    storing an entry that can only be deserialized unsafely is worse than
-    recompiling."""
+    """(pickled-executable, in_tree, out_tree, device_ids) for a
+    jax.stages.Compiled."""
     from jax.experimental import serialize_executable as _se
     ser, in_tree, out_tree = _se.serialize(compiled)
-    opts = compiled._executable.xla_executable.compile_options()
-    return ser, opts.SerializeAsString(), in_tree, out_tree
+    ids = [d.id for d in compiled.runtime_executable().local_devices()]
+    return ser, in_tree, out_tree, ids
 
 
-def _deserialize(ser, opts_blob, in_tree, out_tree):
-    """deserialize_and_load, except the backend gets the original
-    CompileOptions (see section comment)."""
+def _deserialize(ser, in_tree, out_tree, device_ids):
     import jax
-    from jax._src.lib import xla_client as _xc
     from jax.experimental import serialize_executable as _se
-
-    backend = jax.devices()[0].client
-    opts = _xc.CompileOptions.ParseFromString(opts_blob)
-
-    class _Unpickler(_se._JaxPjrtUnpickler):
-        def persistent_load(self, pid):
-            if pid[0] == "exec":
-                return self.backend.deserialize_executable(pid[1], opts)
-            return super().persistent_load(pid)
-
-    unloaded, args_info_flat, no_kwargs = _Unpickler(
-        io.BytesIO(ser), backend).load()
-    return jax.stages.Compiled(unloaded.load(),
-                               in_tree.unflatten(args_info_flat),
-                               out_tree, no_kwargs=no_kwargs)
+    by_id = {d.id: d for d in jax.devices()}
+    return _se.deserialize_and_load(
+        ser, in_tree, out_tree,
+        execution_devices=[by_id[i] for i in device_ids])
 
 
 def load(key):
@@ -408,14 +419,14 @@ def load(key):
         return None
     try:
         with _telemetry.span("aot.deserialize", cat="aot"):
-            fmt, var, ser, opts_blob, in_tree, out_tree, meta = \
+            fmt, var, ser, in_tree, out_tree, ids, meta = \
                 pickle.loads(blob)
             if fmt != _FORMAT:
                 raise ValueError("format %r != %r" % (fmt, _FORMAT))
             if var == VARIANT_DONATED and not deserialized_donation_safe():
                 raise ValueError("donated executable is not safe to "
                                  "execute on this backend")
-            compiled = _deserialize(ser, opts_blob, in_tree, out_tree)
+            compiled = _deserialize(ser, in_tree, out_tree, ids)
     except Exception as e:
         # a stale/corrupt entry must cost one compile, never the run.
         # Unlink it so the next restart doesn't pay the failed parse
@@ -439,26 +450,25 @@ def store(key, compiled, var, meta=None):
     ckpt fault budgets or pollute checkpoint metrics).  ``meta`` is an
     optional JSON-able sidecar stored alongside (the compile-time
     cost/memory attribution, republished as gauges on a warm load).
-    Best-effort — a read-only or full cache dir costs the warm start,
-    not the run."""
+
+    A store that cannot store RAISES (after counting
+    ``aot.cache_errors``): a warm-start layer that silently stops
+    storing turns every restart into a cold compile with nothing to
+    show for it.  Background stores (:func:`spawn_variant_store`) hold
+    the error for the next :func:`drain`."""
     try:
         with _telemetry.span("aot.serialize", cat="aot"):
-            ser, opts_blob, in_tree, out_tree = _serialize(compiled)
-            blob = pickle.dumps((_FORMAT, var, ser, opts_blob, in_tree,
-                                 out_tree, meta))
-        d = cache_dir()
-        os.makedirs(d, exist_ok=True)
+            ser, in_tree, out_tree, ids = _serialize(compiled)
+            blob = pickle.dumps((_FORMAT, var, ser, in_tree, out_tree,
+                                 ids, meta))
+        os.makedirs(cache_dir(), exist_ok=True)
         from .checkpoint import _plain_atomic_write
         _plain_atomic_write(_path(key), blob)
-        _telemetry.histogram("aot.entry_bytes").observe(len(blob))
-        return True
-    except Exception as e:
+    except Exception:
         _telemetry.counter("aot.cache_errors").inc()
-        import logging
-        logging.warning("mxnet_tpu.aot_cache: failed to store entry "
-                        "(%s: %s); restarts will recompile",
-                        type(e).__name__, e)
-        return False
+        raise
+    _telemetry.histogram("aot.entry_bytes").observe(len(blob))
+    return True
 
 
 # -- the shared §8 tiers: variant store + twin hot-swap --------------------
@@ -480,24 +490,17 @@ def spawn_variant_store(mk_jit, examples, key, compiled, meta=None,
     from . import telemetry as _tel
 
     def work():
-        try:
-            if deserialized_donation_safe():
-                store(key, compiled, VARIANT_DONATED, meta)
-                return
-            with _tel.suppress_compile_accounting():
-                with _tel.span("aot.twin_compile", cat="aot"):
-                    twin = mk_jit(donated=False) \
-                        .lower(*examples).compile()
-            _tel.counter("aot.twin_compiles").inc()
-            store(key, twin, VARIANT_PLAIN, meta)
-        except Exception as e:
-            _tel.counter("aot.cache_errors").inc()
-            import logging
-            logging.warning("%s: AOT background store failed (%s: %s); "
-                            "restarts will recompile", where,
-                            type(e).__name__, e)
+        if deserialized_donation_safe():
+            store(key, compiled, VARIANT_DONATED, meta)
+            return
+        with _tel.suppress_compile_accounting():
+            with _tel.span("aot.twin_compile", cat="aot"):
+                twin = mk_jit(donated=False) \
+                    .lower(*examples).compile()
+        _tel.counter("aot.twin_compiles").inc()
+        store(key, twin, VARIANT_PLAIN, meta)
 
-    return spawn_background(work, "mxtpu-aot-store")
+    return spawn_background(work, "mxtpu-aot-store", where)
 
 
 def twin_hotswap_cell(mk_jit, examples, key, twin, where="aot_cache"):
@@ -541,6 +544,7 @@ def twin_hotswap_cell(mk_jit, examples, key, twin, where="aot_cache"):
 # next restart a recompile, nothing else.
 
 _bg_threads = []
+_bg_errors = []     # failures of background tasks, re-raised by drain()
 _bg_lock = threading.Lock()
 
 
@@ -552,11 +556,29 @@ def _drain_at_exit():
     on CPU) — turning a clean exit into an abort.  Ten seconds covers any
     realistic twin/store; a genuinely wedged thread still only delays
     exit, never hangs it."""
-    drain(timeout=10)
+    try:
+        drain(timeout=10)
+    except Exception as e:
+        import logging
+        logging.error("mxnet_tpu.aot_cache: background task failed "
+                      "(%s: %s)", type(e).__name__, e)
 
 
-def spawn_background(fn, name):
-    t = threading.Thread(target=fn, name=name, daemon=True)
+def spawn_background(fn, name, where="aot_cache"):
+    """Run ``fn`` on a daemon thread.  An exception it raises is kept
+    and re-raised by the next :func:`drain` — a background store that
+    fails must not pass for one that succeeded."""
+    def run():
+        try:
+            fn()
+        except Exception as e:
+            import logging
+            logging.error("%s: background task %s failed (%s: %s)",
+                          where, name, type(e).__name__, e)
+            with _bg_lock:
+                _bg_errors.append(e)
+
+    t = threading.Thread(target=run, name=name, daemon=True)
     # start BEFORE publishing: a concurrent drain() joining an unstarted
     # thread raises RuntimeError
     t.start()
@@ -571,7 +593,8 @@ def drain(timeout=None):
     """Join pending background work (tests; also safe to call before
     process exit to maximise what the next restart finds in the cache).
     ``timeout`` bounds the WHOLE drain, not each join — two wedged
-    threads cost ``timeout`` once, not twice."""
+    threads cost ``timeout`` once, not twice.  Re-raises the first
+    failure a background task recorded since the last drain."""
     import time as _time
     deadline = None if timeout is None else _time.monotonic() + timeout
     with _bg_lock:
@@ -586,4 +609,8 @@ def drain(timeout=None):
             t.join(remaining)
     with _bg_lock:
         _bg_threads[:] = [x for x in _bg_threads if x.is_alive()]
+        errors = _bg_errors[:]
+        del _bg_errors[:]
+    if errors:
+        raise errors[0]
     return not _bg_threads

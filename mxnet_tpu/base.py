@@ -37,22 +37,11 @@ def _as_list(obj):
 
 
 def _distributed_initialized(jax):
-    """Has jax.distributed already joined a mesh in this process?  The
-    public ``is_initialized`` only exists on newer jax; fall back to the
-    coordination client's global state.  Getting this wrong is not
-    cosmetic: re-running bring-up would make rank 0's port pre-probe see
-    its OWN live coordination service and exit 76."""
-    try:
-        if jax.distributed.is_initialized():
-            return True
-    except AttributeError:
-        pass
-    try:
-        from jax._src.distributed import global_state
-        return global_state.client is not None or \
-            global_state.coordinator_address is not None
-    except Exception:
-        return False
+    """Has jax.distributed already joined a mesh in this process?
+    Getting this wrong is not cosmetic: re-running bring-up would make
+    rank 0's port pre-probe see its OWN live coordination service and
+    exit 76."""
+    return jax.distributed.is_initialized()
 
 
 def _membership_env_changed(jax):
@@ -208,23 +197,16 @@ def _maybe_init_distributed():
         # MXNetError naming the coordinator
         _wait_for_coordinator(coord, timeout * (retries + 1))
     try:
-        try:
-            # belt only (the TCP probe above bounds the dead-coordinator
-            # case): never BELOW jax's own 300s default — the connect
-            # timeout is sized for "is the coordinator reachable", not
-            # for a slow-but-healthy whole-cluster join (hosts can start
-            # minutes apart on a real pod)
-            jax.distributed.initialize(
-                coordinator_address=coord, num_processes=num,
-                process_id=rank,
-                initialization_timeout=int(
-                    max(300, timeout * (retries + 1))))
-        except TypeError:
-            # older jax without initialization_timeout: the TCP probe
-            # above already bounded the dead-coordinator case
-            jax.distributed.initialize(
-                coordinator_address=coord, num_processes=num,
-                process_id=rank)
+        # belt only (the TCP probe above bounds the dead-coordinator
+        # case): never BELOW jax's own 300s default — the connect
+        # timeout is sized for "is the coordinator reachable", not for
+        # a slow-but-healthy whole-cluster join (hosts can start
+        # minutes apart on a real pod)
+        jax.distributed.initialize(
+            coordinator_address=coord, num_processes=num,
+            process_id=rank,
+            initialization_timeout=int(
+                max(300, timeout * (retries + 1))))
     except (KeyboardInterrupt, SystemExit):
         raise
     except Exception as e:  # jax wraps grpc errors inconsistently

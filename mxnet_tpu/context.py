@@ -14,6 +14,7 @@ Reference: /root/reference/python/mxnet/context.py
 """
 from __future__ import annotations
 
+import logging
 import threading
 
 import jax
@@ -39,6 +40,7 @@ class Context:
     devtype2str = {1: "cpu", 2: "gpu", 3: "cpu_pinned", 4: "tpu"}
     devstr2type = {v: k for k, v in devtype2str.items()}
     _default_ctx = threading.local()
+    _warned_no_accel = False
 
     def __init__(self, device_type, device_id=0):
         if isinstance(device_type, Context):
@@ -89,6 +91,14 @@ class Context:
         devs = jax.local_devices()
         accel = [d for d in devs if d.platform != "cpu"]
         if self.device_type in ("tpu", "gpu"):
+            if not accel and not Context._warned_no_accel:
+                # said once, at INFO: CPU test runs live on this
+                # resolution; code that must be on a chip checks the
+                # platform of what it built (chip_smoke.py)
+                Context._warned_no_accel = True
+                logging.info("mxnet_tpu.context: no accelerator here — "
+                             "'%s' contexts resolve to CPU devices",
+                             self.device_type)
             pool = accel or [d for d in devs if d.platform == "cpu"]
         else:
             pool = [d for d in devs if d.platform == "cpu"]

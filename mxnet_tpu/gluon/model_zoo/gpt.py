@@ -32,7 +32,7 @@ __all__ = ["GPTBlock", "GPTLM", "get_gpt", "gpt2_tiny",
            "gpt2_tiny_moe", "gpt2_small", "gpt2_medium",
            "pack_sequences", "packed_positions", "generate",
            "decode_params", "paged_decode_step", "paged_prefill",
-           "paged_suffix_prefill", "sample_tokens"]
+           "sample_tokens"]
 
 
 class GPTBlock(HybridBlock):
@@ -273,8 +273,6 @@ def packed_positions(segments):
     change = jnp.concatenate(
         [jnp.ones_like(seg[:, :1], dtype=bool),
          seg[:, 1:] != seg[:, :-1]], axis=1)
-    # lax.cummax, not jnp.maximum.accumulate: ufunc .accumulate methods
-    # only exist in newer jax than this build (0.4.37)
     from jax import lax as _lax
     start = _lax.cummax(jnp.where(change, idx, 0), axis=1)
     return (idx - start).astype(jnp.int32)
@@ -1100,194 +1098,156 @@ def _first_token(logits, sampling, new_pages):
     return logits, tok, new_key, new_pages
 
 
-def paged_prefill(p, tokens, prompt_len, block_table_row, kv_pages,
-                  n_heads, sampling=None):
-    """Admit one request: a single batched causal pass over its (padded)
-    prompt that scatters every position's K/V into the slot's pages and
-    returns the last prompt position's logits — the first generated
-    token costs one forward, not ``prompt_len`` decode steps.
-
-    - ``tokens``: int32 [T_pad] — prompt padded to the engine's static
-      prefill length (one compiled program for every prompt length);
-    - ``prompt_len``: int32 scalar (traced — no per-length recompiles);
-    - ``block_table_row``: int32 [max_pages_per_seq] for this slot;
-    - ``sampling``: None for greedy, or scalar ``(temperature, top_k,
-      top_p, key)`` for the request's first token.
-
-    Pad positions (>= prompt_len) are masked out of attention and their
-    K/V is scattered to scratch page 0.  Returns ``(logits [V] fp32,
-    first_token int32, new_kv_pages)`` (plus the advanced key before
-    ``new_kv_pages`` when sampling).
-    """
+def _prefill_rows(p, tokens, prompt_len, prefix_len, prefix_kv, n_heads):
+    """Compute half of an admission: one batched causal pass over the
+    (padded) suffix tokens, touching no page pool.  With ``prefix_kv``
+    (a cache HIT: per layer the slot's pages gathered through its block
+    table, fp32 ``[mp, page, K_kv, D]``) the suffix queries attend over
+    the cached prefix, masked at ``prefix_len``, PLUS the causal window
+    of the suffix itself, in one joint softmax; with ``None`` (a MISS,
+    ``prefix_len == 0``) over the causal window alone.  Returns
+    ``(h [T_pad, C], [(k, v) per layer, each [T_pad, K_kv, D]])``."""
     import jax
     import jax.numpy as jnp
-    from jax import lax
 
     t_pad = tokens.shape[0]
-    page_size = kv_pages[0][0].shape[1]
-    x = (p["wte"][tokens] + p["wpe"][:t_pad])[None]   # [1, T_pad, C]
-    c = x.shape[-1]
-    d = c // n_heads
-    pos = jnp.arange(t_pad)
-    valid = pos < prompt_len
-    mask = (jnp.tril(jnp.ones((t_pad, t_pad), bool))
-            & valid[None, :])[None, None]
-    phys = jnp.where(valid, block_table_row[pos // page_size], 0)
-    offs = pos % page_size
-    quantized = _kv_quantized(kv_pages)
-    new_pages = []
-    for lp, entry in zip(p["layers"], kv_pages):
-        kc, vc = entry[0], entry[1]
-        q, k, v = _block_qkv_kv(lp, x, n_heads)   # [1, H|K_kv, T_pad, D]
-        kd, vd = _bcast_kv(k, n_heads), _bcast_kv(v, n_heads)
-        st = jnp.einsum("bhqd,bhkd->bhqk", q, kd) / jnp.sqrt(
-            jnp.float32(d))
-        st = jnp.where(mask, st, -1e30)
-        pr = jax.nn.softmax(st, axis=-1)
-        o = jnp.einsum("bhqk,bhkd->bhqd", pr, vd)
-        o = o.transpose(0, 2, 1, 3).reshape(1, t_pad, c)
-        if quantized:
-            ks, vs = entry[2], entry[3]
-            kc, ks = _quant_scatter(kc, ks, phys, offs,
-                                    k[0].transpose(1, 0, 2), valid)
-            vc, vs = _quant_scatter(vc, vs, phys, offs,
-                                    v[0].transpose(1, 0, 2), valid)
-            new_pages.append((kc, vc, ks, vs))
-        else:
-            kc = kc.at[phys, offs].set(
-                k[0].transpose(1, 0, 2).astype(kc.dtype))
-            vc = vc.at[phys, offs].set(
-                v[0].transpose(1, 0, 2).astype(vc.dtype))
-            new_pages.append((kc, vc))
-        x = _block_finish(lp, x, o)
-    h = _ln(x[0], p["lnf_g"], p["lnf_b"])             # [T_pad, C]
-    last = lax.dynamic_index_in_dim(h, prompt_len - 1, 0,
-                                    keepdims=False)
-    logits = last @ p["wte"].T
-    return _first_token(logits, sampling, new_pages)
-
-
-def paged_suffix_prefill(p, tokens, prompt_len, prefix_len,
-                         block_table_row, cow_src, cow_dst, kv_pages,
-                         n_heads, sampling=None):
-    """Prefix-cache-aware admission (ISSUE 15): prefill ONLY the
-    un-cached suffix of a prompt whose leading ``prefix_len`` tokens'
-    K/V already sit in pages mapped by ``block_table_row`` (shared
-    full pages + optionally one copy-on-write page).
-
-    - ``tokens``: int32 [T_pad] — the SUFFIX tokens
-      (``prompt[prefix_len:]``), padded to the engine's static prefill
-      length; suffix position ``i`` is absolute position
-      ``prefix_len + i``;
-    - ``prompt_len`` / ``prefix_len``: int32 scalars, both TRACED — one
-      compiled program serves every hit length, and ``prefix_len == 0``
-      is a cache miss (full prefill) in the same program;
-    - ``cow_src`` / ``cow_dst``: int32 physical page ids.  The program
-      copies page ``cow_src`` into ``cow_dst`` per layer FIRST — the
-      copy-on-write for a prefix that ends mid-page: the donor page
-      stays immutable for its other readers while this request's
-      suffix tokens overwrite the copy's tail.  Pass scratch (0) for
-      both when no COW is needed (a scratch self-copy is a no-op);
-    - suffix queries attend over the cached prefix (gathered from the
-      pages through the block table, masked at ``prefix_len``) PLUS
-      the causal window of the suffix itself, in one joint softmax.
-
-    Returns like :func:`paged_prefill`: the logits are the LAST PROMPT
-    position's, so the first generated token is produced here (the
-    suffix is always >= 1 token — a fully-cached prompt still runs its
-    final position through the model).
-    """
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-
-    t_pad = tokens.shape[0]
-    page_size = kv_pages[0][0].shape[1]
-    mp = block_table_row.shape[0]
-    t_ctx = mp * page_size
-    suffix_len = prompt_len - prefix_len
     positions = prefix_len + jnp.arange(t_pad)
     x = (p["wte"][tokens] + p["wpe"][positions])[None]  # [1, T_pad, C]
     c = x.shape[-1]
     d = c // n_heads
-    i = jnp.arange(t_pad)
-    valid = i < suffix_len
+    valid = jnp.arange(t_pad) < prompt_len - prefix_len
     # suffix-vs-suffix: causal within the window, pads masked
     mask_suf = (jnp.tril(jnp.ones((t_pad, t_pad), bool))
                 & valid[None, :])[None, None]
-    # suffix-vs-cached-prefix: every suffix query sees every cached key
-    pre_valid = jnp.arange(t_ctx) < prefix_len
-    mask_pre = pre_valid[None, None, None, :]
+    if prefix_kv is not None:
+        # suffix-vs-cached-prefix: every suffix query sees every cached
+        # key
+        t_ctx = prefix_kv[0][0].shape[0] * prefix_kv[0][0].shape[1]
+        pre_valid = jnp.arange(t_ctx) < prefix_len
+        mask_pre = pre_valid[None, None, None, :]
+    scale = jnp.sqrt(jnp.float32(d))
+    rows = []
+    for i, lp in enumerate(p["layers"]):
+        q, k, v = _block_qkv_kv(lp, x, n_heads)   # [1, H|K_kv, T_pad, D]
+        kd, vd = _bcast_kv(k, n_heads), _bcast_kv(v, n_heads)
+        st = jnp.where(mask_suf,
+                       jnp.einsum("bhqd,bhkd->bhqk", q, kd) / scale,
+                       -1e30)
+        if prefix_kv is not None:
+            # cached prefix K/V: [mp, page, K_kv, D] -> [1, H, t_ctx, D]
+            kp, vp = (_bcast_kv(a.reshape(t_ctx, -1, d)
+                                .transpose(1, 0, 2)[None], n_heads)
+                      for a in prefix_kv[i])
+            # positions past the cached prefix read scratch/unwritten
+            # pages whose contents are GARBAGE — a NaN there (e.g. a
+            # hot-swap canary's torn-weight writes to scratch) would
+            # poison the output through 0 * NaN even though its softmax
+            # weight is exactly zero.  Zero the V rows, not just the
+            # scores.
+            vp = jnp.where(pre_valid[None, None, :, None], vp, 0.0)
+            st_pre = jnp.where(
+                mask_pre, jnp.einsum("bhqd,bhkd->bhqk", q, kp) / scale,
+                -1e30)
+            st = jnp.concatenate([st_pre, st], axis=-1)
+            vd = jnp.concatenate([vp, vd], axis=2)
+        pr = jax.nn.softmax(st, axis=-1)
+        o = jnp.einsum("bhqk,bhkd->bhqd", pr, vd)
+        o = o.transpose(0, 2, 1, 3).reshape(1, t_pad, c)
+        rows.append((k[0].transpose(1, 0, 2), v[0].transpose(1, 0, 2)))
+        x = _block_finish(lp, x, o)
+    return _ln(x[0], p["lnf_g"], p["lnf_b"]), rows
+
+
+def paged_prefill(p, tokens, prompt_len, prefix_len, block_table_row,
+                  cow_src, cow_dst, kv_pages, n_heads, sampling=None):
+    """Admit one request: a single batched pass over its (padded)
+    prompt that scatters every position's K/V into the slot's pages and
+    returns the last prompt position's logits — the first generated
+    token costs one forward, not ``prompt_len`` decode steps.  Prefix-
+    cache aware (ISSUE 15): only the un-cached SUFFIX of a prompt whose
+    leading ``prefix_len`` tokens' K/V already sit in pages mapped by
+    ``block_table_row`` (shared full pages + optionally one
+    copy-on-write page) is run through the model.
+
+    - ``tokens``: int32 [T_pad] — the suffix tokens
+      (``prompt[prefix_len:]``; the whole prompt on a miss), padded to
+      the engine's static prefill length; suffix position ``i`` is
+      absolute position ``prefix_len + i``;
+    - ``prompt_len`` / ``prefix_len``: int32 scalars, both TRACED — one
+      compiled program serves every prompt and hit length.
+      ``prefix_len == 0`` is a cache miss: ``lax.cond`` then runs the
+      plain causal pass, which never attends over the pages;
+    - ``block_table_row``: int32 [max_pages_per_seq] for this slot;
+    - ``cow_src`` / ``cow_dst``: int32 physical page ids.  Page
+      ``cow_src`` is copied into ``cow_dst`` per layer FIRST — the
+      copy-on-write for a prefix that ends mid-page: the donor page
+      stays immutable for its other readers while this request's
+      suffix tokens overwrite the copy's tail.  Pass scratch (0) for
+      both when no COW is needed (a scratch self-copy is a no-op);
+    - ``sampling``: None for greedy, or scalar ``(temperature, top_k,
+      top_p, key)`` for the request's first token.
+
+    Pad positions (>= the suffix length) are masked out of attention
+    and their K/V is scattered to scratch page 0.  Only the attention
+    math sits under the ``cond``, and the pools never enter it: the
+    copy-on-write and the slot's page gather before it and the scatter
+    after it touch the (donated) pools on the one path both branches
+    share.  A conditional that takes or returns the pools makes the
+    TPU compiler hold a second, layout-converted copy of every pool
+    at once, which no deployment-sized pool survives.
+
+    Returns ``(logits [V] fp32, first_token int32, new_kv_pages)``
+    (plus the advanced key before ``new_kv_pages`` when sampling): the
+    logits are the LAST PROMPT position's, so the first generated token
+    is produced here (the suffix is always >= 1 token — a fully-cached
+    prompt still runs its final position through the model).
+    """
+    import jax.numpy as jnp
+    from jax import lax
+
+    t_pad = tokens.shape[0]
+    page_size = kv_pages[0][0].shape[1]
+    quantized = _kv_quantized(kv_pages)
+    # copy-on-write FIRST: the prefix gather must see the copy, and it
+    # carries the donor page's SCALE row with its bytes — a COW page
+    # dequantizes identically to its donor
+    kv_pages = [tuple(a.at[cow_dst].set(a[cow_src]) for a in entry)
+                for entry in kv_pages]
+    prefix_kv = []
+    for entry in kv_pages:
+        kg = entry[0][block_table_row].astype(jnp.float32)
+        vg = entry[1][block_table_row].astype(jnp.float32)
+        if quantized:
+            kg = kg * entry[2][block_table_row][:, None, :, None]
+            vg = vg * entry[3][block_table_row][:, None, :, None]
+        prefix_kv.append((kg, vg))
+    h, rows = lax.cond(
+        prefix_len > 0,
+        lambda: _prefill_rows(p, tokens, prompt_len, prefix_len,
+                              prefix_kv, n_heads),
+        lambda: _prefill_rows(p, tokens, prompt_len, 0, None, n_heads))
+    suffix_len = prompt_len - prefix_len
+    positions = prefix_len + jnp.arange(t_pad)
+    valid = jnp.arange(t_pad) < suffix_len
     phys = jnp.where(valid, block_table_row[positions // page_size], 0)
     offs = positions % page_size
-    quantized = _kv_quantized(kv_pages)
     new_pages = []
-    for entry_i, lp in enumerate(p["layers"]):
-        entry = kv_pages[entry_i]
-        kc, vc = entry[0], entry[1]
-        # copy-on-write FIRST: the gather below must see the copy
-        kc = kc.at[cow_dst].set(kc[cow_src])
-        vc = vc.at[cow_dst].set(vc[cow_src])
-        if quantized:
-            # the copy carries the donor page's SCALE row with its
-            # bytes — a COW page dequantizes identically to its donor
-            ks, vs = entry[2], entry[3]
-            ks = ks.at[cow_dst].set(ks[cow_src])
-            vs = vs.at[cow_dst].set(vs[cow_src])
-            kg = (kc[block_table_row].astype(jnp.float32)
-                  * ks[block_table_row][:, None, :, None])
-            vg = (vc[block_table_row].astype(jnp.float32)
-                  * vs[block_table_row][:, None, :, None])
-        else:
-            kg = kc[block_table_row].astype(jnp.float32)
-            vg = vc[block_table_row].astype(jnp.float32)
-        q, k, v = _block_qkv_kv(lp, x, n_heads)
-        kd, vd = _bcast_kv(k, n_heads), _bcast_kv(v, n_heads)
-        # cached prefix K/V, gathered through the block table:
-        # [mp, page, K_kv, D] -> [1, H, t_ctx, D]
-        kp = _bcast_kv(kg.reshape(
-            t_ctx, -1, d).transpose(1, 0, 2)[None], n_heads)
-        vp = _bcast_kv(vg.reshape(
-            t_ctx, -1, d).transpose(1, 0, 2)[None], n_heads)
-        # positions past the cached prefix read scratch/unwritten pages
-        # whose contents are GARBAGE — a NaN there (e.g. a hot-swap
-        # canary's torn-weight writes to scratch) would poison the
-        # output through 0 * NaN even though its softmax weight is
-        # exactly zero.  Zero the V rows, not just the scores.
-        vp = jnp.where(pre_valid[None, None, :, None], vp, 0.0)
-        scale = jnp.sqrt(jnp.float32(d))
-        st_pre = jnp.where(mask_pre,
-                           jnp.einsum("bhqd,bhkd->bhqk", q, kp) / scale,
-                           -1e30)
-        st_suf = jnp.where(mask_suf,
-                           jnp.einsum("bhqd,bhkd->bhqk", q, kd) / scale,
-                           -1e30)
-        pr = jax.nn.softmax(jnp.concatenate([st_pre, st_suf], axis=-1),
-                            axis=-1)
-        o = jnp.einsum("bhqk,bhkd->bhqd", pr,
-                       jnp.concatenate([vp, vd], axis=2))
-        o = o.transpose(0, 2, 1, 3).reshape(1, t_pad, c)
+    for entry, (k, v) in zip(kv_pages, rows):
         if quantized:
             # the COW page is the only written page with pre-existing
             # content; _quant_scatter's grow-only rescale handles it
             # (fresh pages start at an offs == 0 row and reset)
-            kc, ks = _quant_scatter(kc, ks, phys, offs,
-                                    k[0].transpose(1, 0, 2), valid)
-            vc, vs = _quant_scatter(vc, vs, phys, offs,
-                                    v[0].transpose(1, 0, 2), valid)
+            kc, ks = _quant_scatter(entry[0], entry[2], phys, offs, k,
+                                    valid)
+            vc, vs = _quant_scatter(entry[1], entry[3], phys, offs, v,
+                                    valid)
             new_pages.append((kc, vc, ks, vs))
         else:
-            kc = kc.at[phys, offs].set(
-                k[0].transpose(1, 0, 2).astype(kc.dtype))
-            vc = vc.at[phys, offs].set(
-                v[0].transpose(1, 0, 2).astype(vc.dtype))
-            new_pages.append((kc, vc))
-        x = _block_finish(lp, x, o)
-    h = _ln(x[0], p["lnf_g"], p["lnf_b"])             # [T_pad, C]
-    last = lax.dynamic_index_in_dim(h, suffix_len - 1, 0,
-                                    keepdims=False)
-    logits = last @ p["wte"].T
-    return _first_token(logits, sampling, new_pages)
+            kc, vc = entry
+            new_pages.append((kc.at[phys, offs].set(k.astype(kc.dtype)),
+                              vc.at[phys, offs].set(v.astype(vc.dtype))))
+    last = lax.dynamic_index_in_dim(h, suffix_len - 1, 0, keepdims=False)
+    return _first_token(last @ p["wte"].T, sampling, new_pages)
 
 
 def get_gpt(num_layers, units, num_heads, vocab_size=50257, max_len=1024,

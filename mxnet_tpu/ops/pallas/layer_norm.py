@@ -28,15 +28,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from .flash_attention import _pallas_call, _pl
 
 __all__ = ["fused_layer_norm_residual", "use_pallas"]
-
-
-def _pl():
-    """Lazy pallas import (flash_attention.py discipline: the checkify
-    import chain can fail at process level in forced-CPU test envs)."""
-    from jax.experimental import pallas as pl
-    return pl
 
 
 def use_pallas(x, axis):
@@ -82,8 +78,10 @@ def _kernel_call(x2, r2, gamma, beta, eps, interpret, block_rows=256):
     R, D = x2.shape
     bm = min(block_rows, R)
     grid = ((R + bm - 1) // bm,)
-    return pl.pallas_call(
-        functools.partial(_ln_kernel, eps=eps),
+    return _pallas_call(
+        functools.partial(_ln_kernel, eps=np.float32(eps)),
+        (x2, r2, gamma, beta),
+        interpret=interpret,
         out_shape=(jax.ShapeDtypeStruct((R, D), x2.dtype),
                    jax.ShapeDtypeStruct((R, 1), jnp.float32),
                    jax.ShapeDtypeStruct((R, 1), jnp.float32)),
@@ -97,8 +95,7 @@ def _kernel_call(x2, r2, gamma, beta, eps, interpret, block_rows=256):
         out_specs=(pl.BlockSpec((bm, D), lambda i: (i, 0)),
                    pl.BlockSpec((bm, 1), lambda i: (i, 0)),
                    pl.BlockSpec((bm, 1), lambda i: (i, 0))),
-        interpret=interpret,
-    )(x2, r2, gamma, beta)
+    )
 
 
 @functools.lru_cache(maxsize=None)
@@ -147,6 +144,6 @@ def fused_layer_norm_residual(x, r, gamma, beta, eps=1e-5, interpret=None):
     """``LayerNorm(x + r)`` over the LAST axis as one Pallas kernel.
     ``interpret=None`` auto-selects interpreter mode off-TPU (the
     flash_attention convention)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return _make_fused(float(eps), bool(interpret))(x, r, gamma, beta)
+    if interpret is not None:
+        interpret = bool(interpret)
+    return _make_fused(float(eps), interpret)(x, r, gamma, beta)

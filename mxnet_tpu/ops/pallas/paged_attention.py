@@ -43,10 +43,9 @@ discipline as flash_attention.py.
 the pools may hold int8 payloads; each kernel cell dequantizes its ONE
 fetched page row in VMEM (``int8 * scale``) right before the score
 matmul, so HBM moves a quarter of the fp32 bytes while scores, softmax
-and the output accumulate in fp32 exactly as before.  The scale rows
-ride the SAME block-table index map as their pages — the gather stays
-the address computation.  ``k_scales is None`` is byte-for-byte the
-pre-quantization kernel (same specs, same op order, same AOT keys).
+and the output accumulate in fp32 exactly as before.  The slot's scale
+rows are gathered through the block table before the launch and reach
+the cell in SMEM, where it reads them as scalars.
 """
 from __future__ import annotations
 
@@ -54,37 +53,36 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from .flash_attention import _use_interpret
-
-_NEG_INF = -1e30
-
-
-def _pl():
-    from jax.experimental import pallas as pl
-    return pl
+from .flash_attention import (_NEG_INF, _TINY, _pallas_call, _pl,
+                              _scratch)
 
 
-def _scratch(shape):
-    from jax.experimental.pallas import tpu as pltpu
-    return pltpu.VMEM(shape, jnp.float32)
-
-
-def _decode_kernel(ctx_ref, bt_ref, q_ref, k_ref, v_ref, *rest,
-                   page_size, n_heads, n_kv, scale, quantized=False):
+def _paged_kernel(ctx_ref, bt_ref, q_ref, k_ref, v_ref, *rest,
+                  page_size, n_kv, g, n_q, scale, quantized=False):
     """One (slot, page) grid step: online-softmax accumulate the
-    physical page the block table routed in.  The KV-head axis is an
-    UNROLLED loop of 2-D matmuls inside the cell — each KV head's
-    page-row feeds its WHOLE query-head group (``g = n_heads // n_kv``
-    rows of the VMEM scratch) from one fetch, so grouped-query heads
-    cost no extra page bandwidth and folding heads into one cell cuts
-    grid-cell overhead ``n_kv``-fold (on the interpret/CPU path that
-    overhead is most of the decode step's cost).  ``ctx_ref``/
-    ``bt_ref`` are the scalar-prefetched context lengths and block
-    table (the index maps already consumed ``bt_ref`` for the page
-    gather; only masking reads it here).  With ``quantized`` the cell
-    additionally receives the page's (1, n_kv) scale rows and
-    dequantizes the fetched K/V in VMEM before the fp32 matmuls."""
+    physical page the block table routed in, for ``n_q`` query
+    positions per slot at once (``n_q == 1`` is plain decode; more is
+    the speculative-verify sweep, where query position ``i`` has its
+    OWN context length — the per-position causal mask of batched
+    verification).
+
+    The KV-head axis is an UNROLLED loop of 2-D matmuls inside the
+    cell: KV head ``kv`` owns the ``n_q * g`` query rows
+    ``q_ref[0, kv]`` (position-major, ``row = i * g + h``), so one
+    page fetch serves every query position and every head of the
+    group, and folding heads into one cell cuts grid-cell overhead
+    ``n_kv``-fold.  ``ctx_ref`` (``[S, n_q]``) and ``bt_ref`` are
+    scalar-prefetched into SMEM: the index maps already consumed
+    ``bt_ref`` for the page gather, and the context lengths are read
+    here as SCALARS (SMEM admits no vector loads).  A row whose context
+    ends before this page multiplies its softmax weights by zero, so
+    the page leaves that row's accumulators untouched exactly as if it
+    had been skipped; rows with ``ctx == 0`` never accumulate and emit
+    zeros.  With ``quantized`` the cell also sees the slot's gathered
+    ``(n_kv, max_pages)`` scales in SMEM and dequantizes the fetched
+    K/V page in VMEM before the fp32 matmuls."""
     if quantized:
         ks_ref, vs_ref, o_ref, o_acc, m_acc, l_acc = rest
     else:
@@ -93,8 +91,9 @@ def _decode_kernel(ctx_ref, bt_ref, q_ref, k_ref, v_ref, *rest,
     s = pl.program_id(0)
     j = pl.program_id(1)
     nj = pl.num_programs(1)
-    ctx = ctx_ref[s]
-    g = n_heads // n_kv
+    rows = n_q * g
+    ctxs = [ctx_ref[s, i] for i in range(n_q)]
+    ctx_max = functools.reduce(jnp.maximum, ctxs)
 
     @pl.when(j == 0)
     def _init():
@@ -102,41 +101,45 @@ def _decode_kernel(ctx_ref, bt_ref, q_ref, k_ref, v_ref, *rest,
         m_acc[...] = jnp.full_like(m_acc, _NEG_INF)
         l_acc[...] = jnp.zeros_like(l_acc)
 
-    @pl.when(j * page_size < ctx)
+    @pl.when(j * page_size < ctx_max)
     def _accumulate():
-        # positions past the context length (the ragged tail of the
-        # slot's final in-range page) contribute nothing
+        # positions past a row's context length (the ragged tail of its
+        # final in-range page, or a later query position's keys)
+        # contribute nothing
         pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)
-        in_range = pos < ctx
+            jnp.int32, (rows, page_size), 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, page_size), 0)
+        ctx_rows = ctxs[0]
+        for i in range(1, n_q):
+            ctx_rows = jnp.where(row >= i * g, ctxs[i], ctx_rows)
+        mask = pos < ctx_rows
         for kv in range(n_kv):
-            grp = slice(kv * g, (kv + 1) * g)
-            q = q_ref[0, grp, :].astype(jnp.float32) * scale   # (g, D)
-            k = k_ref[0, :, kv, :].astype(jnp.float32)   # (page, D)
-            v = v_ref[0, :, kv, :].astype(jnp.float32)   # (page, D)
+            q = q_ref[0, kv].astype(jnp.float32) * scale   # (rows, D)
+            k = k_ref[0, :, kv, :].astype(jnp.float32)     # (page, D)
+            v = v_ref[0, :, kv, :].astype(jnp.float32)     # (page, D)
             if quantized:
-                k = k * ks_ref[0, kv]
-                v = v * vs_ref[0, kv]
+                k = k * ks_ref[0, kv, j]
+                v = v * vs_ref[0, kv, j]
             st = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)        # (g, page)
-            st = jnp.where(in_range, st, _NEG_INF)
-            m_prev = m_acc[grp, :]
+                preferred_element_type=jnp.float32)        # (rows, page)
+            st = jnp.where(mask, st, _NEG_INF)
+            m_prev = m_acc[kv]
             m_new = jnp.maximum(m_prev, st.max(axis=-1, keepdims=True))
-            p = jnp.exp(st - m_new)
+            # a row with no key yet sits at m == -1e30, where
+            # exp(st - m) would be 1: zero the masked weights
+            p = jnp.where(mask, jnp.exp(st - m_new), 0.0)
             corr = jnp.exp(m_prev - m_new)
-            l_acc[grp, :] = l_acc[grp, :] * corr + \
-                p.sum(axis=-1, keepdims=True)
-            o_acc[grp, :] = o_acc[grp, :] * corr + \
-                jax.lax.dot_general(
-                    p, v, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-            m_acc[grp, :] = m_new
+            l_acc[kv] = l_acc[kv] * corr + p.sum(axis=-1, keepdims=True)
+            o_acc[kv] = o_acc[kv] * corr + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_acc[kv] = m_new
 
     @pl.when(j == nj - 1)
     def _emit():
-        # an empty slot (ctx == 0) never accumulated: l == 0, emit zeros
-        l_safe = jnp.maximum(l_acc[...], 1e-30)
+        # a row that never accumulated (ctx == 0) has l == 0: emit zeros
+        l_safe = jnp.maximum(l_acc[...], _TINY)
         o_ref[0] = (o_acc[...] / l_safe).astype(o_ref.dtype)
 
 
@@ -155,11 +158,16 @@ def _check_scales(k_pages, k_scales, v_scales):
     return True
 
 
-def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
-                    scale=None, k_scales=None, v_scales=None):
-    """Decode attention for every resident slot in ONE kernel launch.
+def paged_attention_multi(q, k_pages, v_pages, block_tables,
+                          context_lens, scale=None, k_scales=None,
+                          v_scales=None):
+    """Paged attention for ``G`` query positions per slot in ONE kernel
+    launch (``G == 1`` is the decode step, see :func:`paged_attention`;
+    ``G > 1`` is speculative verification).
 
-    - ``q``: [S, H, D] — the current token's query per slot;
+    - ``q``: [S, G, H, D] — G query positions per slot (the last
+      emitted token plus the draft tokens, already scattered into the
+      pages this step);
     - ``k_pages``/``v_pages``: [num_pages, page_size, K_kv, D] — the
       shared physical page pools (page 0 is the serving allocator's
       scratch page, never referenced by an in-range block-table entry).
@@ -168,172 +176,18 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
       multi-head, ``K_kv == 1`` multi-query);
     - ``block_tables``: int32 [S, max_pages_per_seq] — logical page j of
       slot s lives in physical page ``block_tables[s, j]``;
-    - ``context_lens``: int32 [S] — tokens of history per slot (0 for an
-      empty slot, whose output row is zeros);
-    - ``k_scales``/``v_scales``: optional fp32 [num_pages, K_kv] —
-      per-page-per-KV-head dequant scales for quantized (int8) pools;
-      each cell multiplies its fetched page row by its scale row in
-      VMEM before the fp32 score matmul.  ``None`` (the default) is
-      the identical pre-quantization kernel.
-
-    Returns [S, H, D] in ``q``'s dtype.  Raggedness is free of FLOPs:
-    pages past ``context_lens[s]`` are skipped, the final partial page
-    is masked per position.
-    """
-    pl = _pl()
-    from jax.experimental.pallas import tpu as pltpu
-    s_n, h, d = q.shape
-    page_size = k_pages.shape[1]
-    n_kv = k_pages.shape[2]
-    if h % n_kv:
-        raise ValueError(
-            "query heads (%d) must be a multiple of KV heads (%d)"
-            % (h, n_kv))
-    quantized = _check_scales(k_pages, k_scales, v_scales)
-    max_pages = block_tables.shape[1]
-    if scale is None:
-        scale = d ** -0.5
-    ctx = jnp.asarray(context_lens, jnp.int32)
-    bt = jnp.asarray(block_tables, jnp.int32)
-
-    page_spec = lambda: pl.BlockSpec(                       # noqa: E731
-        (1, page_size, n_kv, d), lambda s, j, c, b: (b[s, j], 0, 0, 0))
-    in_specs = [
-        pl.BlockSpec((1, h, d), lambda s, j, c, b: (s, 0, 0)),
-        page_spec(), page_spec(),
-    ]
-    args = [ctx, bt, q, k_pages, v_pages]
-    if quantized:
-        # the scale rows ride the SAME logical->physical translation as
-        # their pages — one (1, n_kv) row per fetched page
-        scale_spec = lambda: pl.BlockSpec(                  # noqa: E731
-            (1, n_kv), lambda s, j, c, b: (b[s, j], 0))
-        in_specs += [scale_spec(), scale_spec()]
-        args += [k_scales, v_scales]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(s_n, max_pages),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, h, d), lambda s, j, c, b: (s, 0, 0)),
-        scratch_shapes=[_scratch((h, d)), _scratch((h, 1)),
-                        _scratch((h, 1))],
-    )
-    return pl.pallas_call(
-        functools.partial(_decode_kernel, page_size=page_size,
-                          n_heads=h, n_kv=n_kv, scale=float(scale),
-                          quantized=quantized),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s_n, h, d), q.dtype),
-        interpret=_use_interpret(),
-    )(*args)
-
-
-def _verify_kernel(ctx_ref, bt_ref, q_ref, k_ref, v_ref, *rest,
-                   page_size, n_heads, n_kv, n_q, scale,
-                   quantized=False):
-    """One (slot, page) grid step of the speculative-verify sweep: the
-    SAME page stream as ``_decode_kernel`` but ``n_q`` query positions
-    per slot, each with its OWN context length (query position ``i``
-    attends through the draft token written at its position — the
-    per-position causal mask of batched verification).  One physical
-    page fetch serves every query position and every query-head group,
-    and ALL positions accumulate in one vectorised pass — the per-page
-    op count matches the single-query kernel instead of growing with
-    ``n_q`` (masked positions multiply their softmax weights by zero,
-    so a page past a row's context leaves that row's accumulators
-    untouched, exactly as if the page had been skipped).  Positions
-    with ``ctx == 0`` (inactive slot, or a query row past the slot's
-    draft length) never accumulate and emit zeros.  The scratch rows
-    are laid out ``[n_q * n_heads, D]`` KV-head major: row
-    ``kv * n_q * g + i * g + h`` holds position ``i``, group head
-    ``h`` of KV head ``kv``."""
-    if quantized:
-        ks_ref, vs_ref, o_ref, o_acc, m_acc, l_acc = rest
-    else:
-        o_ref, o_acc, m_acc, l_acc = rest
-    pl = _pl()
-    s = pl.program_id(0)
-    j = pl.program_id(1)
-    nj = pl.num_programs(1)
-    g = n_heads // n_kv
-
-    @pl.when(j == 0)
-    def _init():
-        o_acc[...] = jnp.zeros_like(o_acc)
-        m_acc[...] = jnp.full_like(m_acc, _NEG_INF)
-        l_acc[...] = jnp.zeros_like(l_acc)
-
-    ctxv = ctx_ref[s]
-    ctx_max = jnp.max(ctxv)
-
-    @pl.when(j * page_size < ctx_max)
-    def _accumulate():
-        d = o_acc.shape[-1]
-        pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)
-        # [1, n_q * g, page] — broadcasts over the KV-head batch dim
-        maskf = jnp.repeat(pos < ctxv[:, None], g,
-                           axis=0)[None].astype(jnp.float32)
-        # every KV head in ONE batched dot: q [KV, n_q * g, D] against
-        # the page's k/v [page, KV, D] (batch dim 1), so the per-page
-        # op count stays constant in both heads and query positions
-        q = (q_ref[0].astype(jnp.float32) * scale).reshape(
-            n_q, n_kv, g, d).transpose(1, 0, 2, 3).reshape(
-            n_kv, n_q * g, d)
-        kf = k_ref[0].astype(jnp.float32)          # (page, KV, D)
-        vf = v_ref[0].astype(jnp.float32)
-        if quantized:
-            kf = kf * ks_ref[0][None, :, None]
-            vf = vf * vs_ref[0][None, :, None]
-        st = jax.lax.dot_general(
-            q, kf,
-            (((2,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)    # [KV, n_q * g, page]
-        st = jnp.where(maskf > 0, st, _NEG_INF)
-        m_prev = m_acc[...].reshape(n_kv, n_q * g, 1)
-        m_new = jnp.maximum(m_prev, st.max(axis=-1, keepdims=True))
-        p = jnp.exp(st - m_new) * maskf
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_acc[...].reshape(n_kv, n_q * g, 1) * corr + \
-            p.sum(axis=-1, keepdims=True)
-        o_new = o_acc[...].reshape(n_kv, n_q * g, d) * corr + \
-            jax.lax.dot_general(
-                p, vf,
-                (((2,), (0,)), ((0,), (1,))),
-                preferred_element_type=jnp.float32)
-        m_acc[...] = m_new.reshape(n_kv * n_q * g, 1)
-        l_acc[...] = l_new.reshape(n_kv * n_q * g, 1)
-        o_acc[...] = o_new.reshape(n_kv * n_q * g, d)
-
-    @pl.when(j == nj - 1)
-    def _emit():
-        l_safe = jnp.maximum(l_acc[...], 1e-30)
-        d = o_acc.shape[-1]
-        o_ref[0] = (o_acc[...] / l_safe).reshape(
-            n_kv, n_q, g, d).transpose(1, 0, 2, 3).reshape(
-            n_q, n_heads, d).astype(o_ref.dtype)
-
-
-def paged_attention_multi(q, k_pages, v_pages, block_tables,
-                          context_lens, scale=None, k_scales=None,
-                          v_scales=None):
-    """Speculative-verify attention: ``n_q`` query positions per slot in
-    ONE kernel launch over the same paged pools.
-
-    - ``q``: [S, G, H, D] — G query positions per slot (the last
-      emitted token plus the draft tokens, already scattered into the
-      pages this step);
     - ``context_lens``: int32 [S, G] — per-POSITION context length
       (query ``i`` of slot ``s`` attends to positions
       ``< context_lens[s, i]``; 0 masks the row to zeros — inactive
-      slots and rows past the slot's draft length).
+      slots and rows past the slot's draft length);
+    - ``k_scales``/``v_scales``: optional fp32 [num_pages, K_kv] —
+      per-page-per-KV-head dequant scales for quantized (int8) pools;
+      each cell multiplies its fetched page by its scale row in VMEM
+      before the fp32 score matmul.
 
-    Same grid, page stream, and per-page online softmax as
-    :func:`paged_attention` — one page fetch serves all G positions —
-    so ``G == 1`` with the same contexts reproduces the single-query
-    kernel's op order exactly.  ``k_scales``/``v_scales`` dequantize
-    the fetched page in VMEM exactly as in :func:`paged_attention`.
-    Returns [S, G, H, D].
+    Returns [S, G, H, D] in ``q``'s dtype.  Raggedness is free of
+    FLOPs: pages past a slot's longest context are skipped, the final
+    partial page is masked per position.
     """
     pl = _pl()
     from jax.experimental.pallas import tpu as pltpu
@@ -344,6 +198,8 @@ def paged_attention_multi(q, k_pages, v_pages, block_tables,
         raise ValueError(
             "query heads (%d) must be a multiple of KV heads (%d)"
             % (h, n_kv))
+    g = h // n_kv
+    rows = n_q * g
     quantized = _check_scales(k_pages, k_scales, v_scales)
     max_pages = block_tables.shape[1]
     if scale is None:
@@ -355,37 +211,64 @@ def paged_attention_multi(q, k_pages, v_pages, block_tables,
             % ((s_n, n_q), tuple(ctx.shape)))
     bt = jnp.asarray(block_tables, jnp.int32)
 
-    page_spec = lambda: pl.BlockSpec(                       # noqa: E731
+    # KV-head-major query rows: the cell reads one aligned
+    # (n_q * g, D) tile per KV head, and the regrouping of this small
+    # tensor is XLA's, not the kernel's
+    qk = q.reshape(s_n, n_q, n_kv, g, d).transpose(0, 2, 1, 3, 4) \
+        .reshape(s_n, n_kv, rows, d)
+    q_spec = pl.BlockSpec((1, n_kv, rows, d),
+                          lambda s, j, c, b: (s, 0, 0, 0))
+    page_spec = pl.BlockSpec(
         (1, page_size, n_kv, d), lambda s, j, c, b: (b[s, j], 0, 0, 0))
-    in_specs = [
-        pl.BlockSpec((1, n_q, h, d),
-                     lambda s, j, c, b: (s, 0, 0, 0)),
-        page_spec(), page_spec(),
-    ]
-    args = [ctx, bt, q, k_pages, v_pages]
+    in_specs = [q_spec, page_spec, page_spec]
+    args = [ctx, bt, qk, k_pages, v_pages]
     if quantized:
-        scale_spec = lambda: pl.BlockSpec(                  # noqa: E731
-            (1, n_kv), lambda s, j, c, b: (b[s, j], 0))
-        in_specs += [scale_spec(), scale_spec()]
-        args += [k_scales, v_scales]
+        # a (1, n_kv) row of the [num_pages, K_kv] pool is not a legal
+        # TPU block, and the cell wants each scale as a SCALAR: gather
+        # the slot's scale rows through the block table here and hand
+        # them to the cell in SMEM, page axis last (SMEM pads the last
+        # dim to 128 words)
+        scale_spec = pl.BlockSpec((1, n_kv, max_pages),
+                                  lambda s, j, c, b: (s, 0, 0),
+                                  memory_space=pltpu.SMEM)
+        in_specs += [scale_spec, scale_spec]
+        args += [k_scales[bt].transpose(0, 2, 1),
+                 v_scales[bt].transpose(0, 2, 1)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(s_n, max_pages),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, n_q, h, d),
-                               lambda s, j, c, b: (s, 0, 0, 0)),
-        scratch_shapes=[_scratch((n_q * h, d)),
-                        _scratch((n_q * h, 1)),
-                        _scratch((n_q * h, 1))],
+        out_specs=q_spec,
+        scratch_shapes=[_scratch((n_kv, rows, d)),
+                        _scratch((n_kv, rows, 1)),
+                        _scratch((n_kv, rows, 1))],
     )
-    return pl.pallas_call(
-        functools.partial(_verify_kernel, page_size=page_size,
-                          n_heads=h, n_kv=n_kv, n_q=n_q,
-                          scale=float(scale), quantized=quantized),
+    out = _pallas_call(
+        functools.partial(_paged_kernel, page_size=page_size,
+                          n_kv=n_kv, g=g, n_q=n_q,
+                          scale=np.float32(scale), quantized=quantized),
+        args,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s_n, n_q, h, d), q.dtype),
-        interpret=_use_interpret(),
-    )(*args)
+        out_shape=jax.ShapeDtypeStruct((s_n, n_kv, rows, d), q.dtype))
+    return out.reshape(s_n, n_kv, n_q, g, d).transpose(0, 2, 1, 3, 4) \
+        .reshape(s_n, n_q, h, d)
+
+
+def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
+                    scale=None, k_scales=None, v_scales=None):
+    """Decode attention for every resident slot in ONE kernel launch:
+    :func:`paged_attention_multi` at one query position per slot.
+
+    - ``q``: [S, H, D] — the current token's query per slot;
+    - ``context_lens``: int32 [S] — tokens of history per slot (0 for an
+      empty slot, whose output row is zeros).
+
+    Returns [S, H, D] in ``q``'s dtype.
+    """
+    ctx = jnp.asarray(context_lens, jnp.int32)
+    return paged_attention_multi(
+        q[:, None], k_pages, v_pages, block_tables, ctx[:, None],
+        scale=scale, k_scales=k_scales, v_scales=v_scales)[:, 0]
 
 
 def _dequant_pools(k_pages, v_pages, k_scales, v_scales):

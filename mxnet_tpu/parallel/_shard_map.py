@@ -1,17 +1,7 @@
-"""shard_map compat shim (jax.shard_map in >=0.8, experimental before)."""
+"""``jax.shard_map`` with this package's defaults."""
 from __future__ import annotations
 
-import inspect
-import os
-
 import jax
-
-try:
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_PARAMS = set(inspect.signature(_shard_map).parameters)
 
 
 def shard_map(fn, mesh, in_specs, out_specs, check_rep=False,
@@ -21,30 +11,7 @@ def shard_map(fn, mesh, in_specs, out_specs, check_rep=False,
     unlisted axes stay auto — GSPMD keeps propagating their shardings
     inside the body (used by the pipeline to run pp manually while tp
     rides XLA's Megatron propagation)."""
-    kw = {}
-    if "check_vma" in _PARAMS:
-        kw["check_vma"] = check_rep
-    elif "check_rep" in _PARAMS:
-        kw["check_rep"] = check_rep
-    if axis_names is not None:
-        axis_names = frozenset(axis_names)
-        if "axis_names" in _PARAMS:
-            kw["axis_names"] = axis_names
-        elif "auto" in _PARAMS and \
-                os.environ.get("MXTPU_SHARDMAP_PARTIAL_AUTO") == "1":
-            # pre-axis_names jax spells partial-auto as its complement:
-            # ``auto`` lists the axes GSPMD keeps propagating.  Opt-in
-            # only: on THIS build (jax 0.4.37 CPU) the auto= path gets
-            # past tracing but XLA hard-aborts (SIGABRT, uncatchable)
-            # compiling the partially-manual collectives — raising here
-            # is a clean per-test failure, an abort would take the whole
-            # process (and the tier-1 run) down with it.
-            kw["auto"] = frozenset(mesh.axis_names) - axis_names
-        else:
-            raise NotImplementedError(
-                "this jax version's shard_map has no axis_names "
-                "(partial-auto) support; MXTPU_SHARDMAP_PARTIAL_AUTO=1 "
-                "opts into the legacy auto= spelling where the backend "
-                "can compile it")
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
+    kw = {} if axis_names is None else \
+        {"axis_names": frozenset(axis_names)}
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_rep, **kw)

@@ -23,15 +23,10 @@ def axis_index(axis):
 
 
 def axis_size(axis):
-    """Static size of a named mesh axis, resolvable inside shard_map.
-
-    ``lax.axis_size`` only exists in newer jax; on this build (0.4.37)
-    the canonical spelling is ``psum(1, axis)``, which jax special-cases
-    to a Python int at trace time — so it stays usable as a loop bound
-    (the pipeline/ring kernels unroll over it)."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    return lax.psum(1, axis)
+    """Static size of a named mesh axis, resolvable inside shard_map: a
+    Python int at trace time, so it stays usable as a loop bound (the
+    pipeline/ring kernels unroll over it)."""
+    return lax.axis_size(axis)
 
 
 def allreduce(x, axis, op="sum"):

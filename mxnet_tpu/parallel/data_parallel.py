@@ -110,22 +110,55 @@ def make_train_step(loss_fn, mesh, optimizer_apply=None, optimizer_init=None,
         new_params, new_state = optimizer_apply(params, grads, opt_state)
         return new_params, new_state, loss
 
-    jitted = jax.jit(step, donate_argnums=(0, 1) if donate else ())
-    if donate:
-        # donated program compiling lazily at first dispatch: keep it
-        # out of jax's persistent cache on backends where replaying a
-        # donated executable from that cache corrupts the heap
-        # (aot_cache docs, ROBUSTNESS.md §8) — launch.py exports that
-        # cache to every worker by default
-        from .. import aot_cache
-        jitted = aot_cache.donation_cache_guard(jitted)
+    programs = {}
 
-    def step_fn(params, opt_state, batch, rng):
-        batch = jax.tree_util.tree_map(
+    def program(params, opt_state):
+        """(jitted step, the callable that dispatches it), built at the
+        first call: the new params and state are pinned to land exactly
+        where the donated ones lived.  Left to propagation, a one-device
+        mesh hands back SingleDeviceShardings, which miss the jit cache
+        on the second call — one full recompile of the step."""
+        if not programs:
+            def where(tree):
+                return jax.tree_util.tree_map(lambda x: x.sharding, tree)
+            jitted = jax.jit(
+                step, donate_argnums=(0, 1) if donate else (),
+                out_shardings=(where(params), where(opt_state), None))
+            call = jitted
+            if donate:
+                # donated program compiling lazily at first dispatch:
+                # keep it out of jax's persistent cache on backends
+                # where replaying a donated executable from that cache
+                # corrupts the heap (aot_cache docs, ROBUSTNESS.md §8)
+                # — launch.py exports that cache to every worker by
+                # default
+                from .. import aot_cache
+                call = aot_cache.donation_cache_guard(jitted)
+            programs.update(jitted=jitted, call=call)
+        return programs["jitted"], programs["call"]
+
+    def place(batch):
+        return jax.tree_util.tree_map(
             lambda b, s: jax.device_put(b, s) if not _is_committed(b, s)
             else b, batch, batch_sharding(batch))
-        return jitted(params, opt_state, batch, rng)
 
+    # the ambient mesh is how mesh-aware ops inside loss_fn (the Pallas
+    # attention kernels, which GSPMD cannot partition) find the axes to
+    # shard_map over
+    def step_fn(params, opt_state, batch, rng):
+        with jax.set_mesh(mesh):
+            return program(params, opt_state)[1](
+                params, opt_state, place(batch), rng)
+
+    def lower(params, opt_state, batch, rng):
+        """``jax.jit(...).lower`` of the step on the same arguments:
+        the handle for ahead-of-time compilation and for reading what
+        the program holds (``.compile().as_text()``)."""
+        with jax.set_mesh(mesh):
+            return program(params, opt_state)[0].lower(
+                params, opt_state, place(batch), rng)
+
+    step_fn.lower = lower
     return init_fn, step_fn
 
 

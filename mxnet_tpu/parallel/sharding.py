@@ -201,7 +201,7 @@ def shard_params(params, mesh, rules=None, donate=False):
     otherwise, which at scale is the difference between fitting the
     reshard in HBM or not.  The hazard making this non-trivial: a
     ``device_put`` that does NOT move data may ALIAS the source buffer
-    (the NDArray.copyto lesson, PERF.md §9) — deleting the source then
+    (the NDArray.copyto lesson, PERF.md §6) — deleting the source then
     tears down the result too.  (jit-identity donation can't help
     either: a cross-layout donation is "not usable" to XLA and the
     source survives.)  So the source is deleted only when the placement

@@ -17,6 +17,7 @@ Env autostart: MXNET_PROFILER_AUTOSTART=1 (reference env_var.md:101-108).
 """
 from __future__ import annotations
 
+import functools
 import json
 import os
 import threading
@@ -268,9 +269,15 @@ def instrument(fn, first_call_compiles=True):
     are invisible to the first-call heuristic, so post-warmup calls are
     bracketed by telemetry's monotonic jax.monitoring backend-compile
     event count: any compile event landing inside an instrumented call
-    feeds count_compile too."""
+    feeds count_compile too.
+
+    The wrapper's ``__wrapped__`` is ``fn`` — for an AOT-compiled
+    program that is the ``jax.stages.Compiled``, whose ``as_text()``
+    says what the program holds (chip_smoke.py looks for the Mosaic
+    custom call there)."""
     compiled = []
 
+    @functools.wraps(fn, assigned=())
     def wrapper(*args):
         count_dispatch()
         if not compiled:

@@ -74,7 +74,7 @@ Capacity multipliers (ISSUE 15):
   (serving/prefix_cache.py), maps the shared pages into the block
   table by reference (``PagedKVAllocator`` refcounts), copy-on-writes
   a prefix that ends mid-page, and prefills ONLY the un-cached suffix
-  (``gpt.paged_suffix_prefill``, one program for every hit length —
+  (``gpt.paged_prefill``, one program for every hit length —
   ``prefix_len`` is traced).  Registration happens after a SUCCESSFUL
   prefill; the ``serve.prefix.evict`` fault site force-drops the index
   between steps (victims fall back to a full prefill with correct
@@ -549,23 +549,16 @@ class ServingEngine:
 
         # ONE prefill program whether the prefix cache is on or off: a
         # traced prefix_len of 0 (every admission with the cache off,
-        # every miss with it on) executes the classic dense branch via
-        # lax.cond — no page gather, no COW copy, bit-identical to and
-        # as cheap as the pre-prefix-cache prefill; only hits pay the
-        # gather.  Samples the request's FIRST token under its params.
+        # every miss with it on) takes gpt.paged_prefill's plain causal
+        # branch — bit-identical to the pre-prefix-cache prefill; only
+        # hits pay the attention over the gathered prefix.  Samples the
+        # request's FIRST token under its params.
         def prefill(p, kv_pages, tokens, prompt_len, prefix_len,
                     bt_row, cow_src, cow_dst, temp, top_k, top_p, key):
-            from jax import lax
-            samp = (temp, top_k, top_p, key)
-            return lax.cond(
-                prefix_len > 0,
-                lambda: gpt.paged_suffix_prefill(
-                    p, tokens, prompt_len, prefix_len, bt_row,
-                    cow_src, cow_dst, kv_pages, n_heads,
-                    sampling=samp),
-                lambda: gpt.paged_prefill(
-                    p, tokens, prompt_len, bt_row, kv_pages,
-                    n_heads, sampling=samp))
+            return gpt.paged_prefill(
+                p, tokens, prompt_len, prefix_len, bt_row, cow_src,
+                cow_dst, kv_pages, n_heads,
+                sampling=(temp, top_k, top_p, key))
 
         def sds(x):
             return jax.ShapeDtypeStruct(x.shape, x.dtype)

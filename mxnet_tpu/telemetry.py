@@ -70,7 +70,7 @@ streams into the fleet view.
 ``tools/perf_probe/telemetry_report.py`` renders the per-rank artifacts
 (JSON-lines timeline and postmortem) for humans;
 ``tools/perf_probe/job_report.py`` aggregates a whole run dir;
-OBSERVABILITY.md is the metric-name / span-taxonomy / schema contract.
+OBSERVABILITY.md is the metric-name / span-name / schema contract.
 
 Env vars: ``MXTPU_TELEMETRY``, ``MXTPU_POSTMORTEM_DIR``,
 ``MXTPU_FLIGHT_RECORDER_STEPS`` (ring size, default 64),

@@ -36,7 +36,7 @@ crashes the checkpoint-restart machinery (PR 2) already handles:
 Telemetry (OBSERVABILITY.md): ``watchdog.stalls`` counter,
 ``watchdog.lease_age`` gauge (worst current age, maintained per poll),
 ``watchdog.heartbeats`` counter.  ROBUSTNESS.md §7 is the lease
-taxonomy / exit-code / env-var contract.
+names / exit-code / env-var contract.
 """
 from __future__ import annotations
 

@@ -1,0 +1,206 @@
+"""Ask the chip's compiler, without the chip.
+
+The TPU's compiler is installed wherever jax[tpu] is and compiles for a
+chip that is DESCRIBED, not attached (on-chip-measurement guide, §2.3).
+Every Pallas kernel of the main path is compiled here for a v5e at the
+widths ``chip_smoke.py`` runs them at (GPT-2 medium: 16 heads of 64, 16-
+token pages; T = 2048), under the suite's ``jax_enable_x64`` — the
+interpreter, which every other test of these kernels runs under, accepts
+block shapes, SMEM vector loads and f64 constants that Mosaic refuses.
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process may hold the TPU library, so the worker that is
+handed this file loads it, and the others never try.  For the same
+reason every compile runs in this process (no child could load the
+library), and these tests stay in this one file.
+
+Also here: the tier-1 run of ``chip_smoke.py --tiny``, the CPU rehearsal
+of the script the driver runs on the chip.
+"""
+import importlib
+import json
+import os
+import subprocess
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# the package re-exports functions under the modules' names
+flash = importlib.import_module("mxnet_tpu.ops.pallas.flash_attention")
+paged = importlib.import_module("mxnet_tpu.ops.pallas.paged_attention")
+layer_norm = importlib.import_module("mxnet_tpu.ops.pallas.layer_norm")
+
+SLOTS, HEADS, HEAD_DIM, PAGE, PAGES, PAGES_PER_SEQ = 8, 16, 64, 16, 2048, 128
+BATCH, SEQ = 4, 2048
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    # a compile for a described chip can be written to the persistent
+    # cache but never read back without the chip (the next run warns)
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def chip(topo, monkeypatch):
+    """One described chip, and the kernels steered onto Mosaic: they ask
+    ``jax.default_backend()``, which is still the CPU here."""
+    monkeypatch.setattr(flash, "_use_interpret", lambda: False)
+    one = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one)
+
+
+def compile_for_chip(fn, *shapes):
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    assert "tpu_custom_call" in compiled.as_text(), \
+        "the compiled program holds no Mosaic kernel"
+    return compiled
+
+
+# every format multi-head; grouped-query in the format with the most to
+# go wrong (the others differ from it only in the pool's dtype)
+PAGED_CASES = [(f, n_q, 16) for f in ("fp32", "bf16", "int8")
+               for n_q in (1, 5)] + [("int8", 1, 4), ("int8", 5, 4)]
+
+
+@pytest.mark.parametrize("kv_dtype,n_q,kv_heads", PAGED_CASES)
+def test_paged_kernel_compiles(chip, kv_dtype, n_q, kv_heads):
+    """Decode (one query position) and speculative verify (five) in
+    every page format.  Refused before PR 21: int8 (the (1, n_kv) scale
+    block of a [num_pages, K_kv] array) and verify (context lengths
+    read from SMEM as a vector)."""
+    dt = {"fp32": jnp.float32, "bf16": jnp.bfloat16,
+          "int8": jnp.int8}[kv_dtype]
+    q = chip((SLOTS, n_q, HEADS, HEAD_DIM), jnp.float32)
+    pool = chip((PAGES, PAGE, kv_heads, HEAD_DIM), dt)
+    tables = chip((SLOTS, PAGES_PER_SEQ), jnp.int32)
+    ctx = chip((SLOTS, n_q), jnp.int32)
+    if kv_dtype == "int8":
+        scales = chip((PAGES, kv_heads), jnp.float32)
+        compile_for_chip(
+            lambda q, k, v, b, c, ks, vs: paged.paged_attention_multi(
+                q, k, v, b, c, k_scales=ks, v_scales=vs),
+            q, pool, pool, tables, ctx, scales, scales)
+    else:
+        compile_for_chip(paged.paged_attention_multi, q, pool, pool,
+                         tables, ctx)
+
+
+@pytest.mark.parametrize("head_dim,packed", [(64, False), (64, True),
+                                             (128, False)])
+def test_flash_fwd_bwd_compiles(chip, head_dim, packed):
+    """Forward and split backward at T = 2048, with and without
+    segment ids (refused before PR 21: the (1, block) slice of the
+    [B, T] ids)."""
+    q = chip((BATCH, HEADS, SEQ, head_dim), jnp.bfloat16)
+    seg = chip((BATCH, SEQ), jnp.int32)
+
+    def grads(q, k, v, seg=None):
+        return jax.grad(
+            lambda q, k, v: flash.flash_attention(
+                q, k, v, causal=True, segment_ids=seg)
+            .astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+    compiled = compile_for_chip(grads, *((q, q, q, seg) if packed
+                                         else (q, q, q)))
+    # forward, dq and dk/dv: three kernels
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def test_flash_compiles_on_a_mesh(topo, monkeypatch):
+    """Mosaic kernels cannot be partitioned automatically: under a
+    dp x tp mesh the op shard_maps itself over batch and heads.  Refused
+    before PR 21 ("wrap the call in a shard_map")."""
+    monkeypatch.setattr(flash, "_use_interpret", lambda: False)
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("dp", "tp"))
+    q = jax.ShapeDtypeStruct(
+        (BATCH, HEADS, SEQ, HEAD_DIM), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P("dp", "tp", None, None)))
+    with jax.set_mesh(mesh):
+        compiled = compile_for_chip(
+            lambda q, k, v: flash.flash_attention(q, k, v, causal=True),
+            q, q, q)
+    assert "all-gather" not in compiled.as_text()
+
+
+def test_fused_layer_norm_residual_compiles(chip):
+    x = chip((BATCH * SEQ, 1024), jnp.bfloat16)
+    g = chip((1024,), jnp.bfloat16)
+    compile_for_chip(
+        lambda x, r, g, b: jax.grad(
+            lambda x, r, g, b: layer_norm.fused_layer_norm_residual(
+                x, r, g, b).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2, 3))(x, r, g, b), x, x, g, g)
+
+
+# -- chip_smoke.py --tiny: the CPU rehearsal of the chip run ----------------
+
+def _smoke(*argv, **env_extra):
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("XLA_FLAGS", "MXTPU_FAULT")}
+    env.update(JAX_PLATFORMS="cpu", **env_extra)
+    r = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py")] + list(argv),
+        env=env, capture_output=True, text=True, timeout=600)
+    lines = [l for l in r.stdout.splitlines() if l.strip()]
+    return r, [json.loads(l) for l in lines if l.startswith("{")]
+
+
+def test_chip_smoke_tiny_rehearsal(tmp_path):
+    r, docs = _smoke("--tiny",
+                     JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
+    # the last line is the verdict and nothing else, and it never
+    # pretends: the rehearsal says cpu
+    assert r.stdout.strip().splitlines()[-1] == json.dumps(docs[-1])
+    assert docs[-1] == {"ok": True, "device": {
+        "platform": "cpu", "kind": docs[-1]["device"]["kind"],
+        "count": 1}}
+    phases = {}
+    for d in docs[:-1]:
+        phases.setdefault(d["phase"], []).append(d)
+    for name in ("train_resnet", "train_gpt"):
+        (p,) = phases[name]
+        assert p["losses"][-1] < p["losses"][0]
+        assert p["recompiles"] == 0
+    assert phases["train_resnet"][0]["dispatches_per_step"] == 1.0
+    served = {(p["kv_dtype"], p["spec_k"]) for p in phases["serve"]}
+    assert served == {(f, k) for f in ("fp32", "bf16", "int8")
+                      for k in (0, 4)}
+    assert phases["start"][0]["compile_cache_dir"] == \
+        str(tmp_path / "cache")
+
+
+def test_chip_smoke_fails_when_a_phase_fails_or_no_chip():
+    # without --tiny the script is about a TPU and finds none
+    r, docs = _smoke()
+    assert r.returncode != 0
+    assert docs[-1]["ok"] is False
+    assert docs[-1]["device"]["platform"] == "cpu"
+    # a failed admission in the serving phase (an existing fault site)
+    # must fail the run, not be passed over
+    r, docs = _smoke("--tiny", MXTPU_FAULT="serve.prefill.error:1")
+    assert r.returncode != 0, r.stdout[-2000:]
+    assert docs[-1]["ok"] is False and "error" in docs[-1]
+    assert not any(d.get("ok") for d in docs)

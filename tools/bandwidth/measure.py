@@ -33,10 +33,7 @@ def measure(num_devices=0, size_mb=256.0, num_arrays=30, iters=10,
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     devs = jax.devices()
     n = min(num_devices, len(devs)) if num_devices else len(devs)

@@ -1,6 +1,6 @@
 """BENCH_MODE=graph probe: the rewrite pipeline's measurable contract.
 
-Builds the two bench graphs (PERF.md §15) as symbols — a ResNet-style
+Builds the two bench graphs (PERF.md §6) as symbols — a ResNet-style
 conv→bn→relu residual tower and a post-LN GPT transformer stack whose
 attention masks are built symbolically per block — binds each with the
 pipeline ON and OFF, and measures:

@@ -1,6 +1,6 @@
 """Fault-tolerance off the hot path: what does it actually cost?
 
-Two measurements backing PERF.md §12 (CPU micro-bench, same MLP fit
+Two measurements backing PERF.md §6 (CPU micro-bench, same MLP fit
 loop family as steptrace.py but sized so checkpoint serialization and
 XLA compilation are non-trivial):
 

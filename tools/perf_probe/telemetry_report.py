@@ -137,7 +137,7 @@ def render_report(doc, out, context=""):
 
 # phases the step loop actually blocks on under async checkpointing vs
 # the work the writer thread absorbs — the split telemetry_report exists
-# to make visible (PERF.md §12)
+# to make visible (PERF.md §6)
 _CKPT_HOT = ("ckpt.save", "ckpt.snapshot", "ckpt.async_wait")
 _CKPT_BG = ("ckpt.async_write", "ckpt.write", "ckpt.fsync", "ckpt.rename")
 
@@ -174,7 +174,7 @@ def _render_ckpt_pipeline(doc, out):
            rows, out)
 
 
-# the streaming input plane's phase taxonomy (mxnet_tpu/stream/,
+# the streaming input plane's phase names (mxnet_tpu/stream/,
 # OBSERVABILITY.md §11): worker-side decode/open phases folded consumer-
 # side, plus the two starvation signals a training rank actually blocks
 # on — io.queue_wait (consumer starved on the decode result queue) and

@@ -123,7 +123,8 @@ def main(argv=None):
     import jax
     jax.devices()   # backend up before the engine builds programs
 
-    from mxnet_tpu import telemetry, watchdog
+    from mxnet_tpu import aot_cache, telemetry, watchdog
+    aot_cache.enable_persistent_cache()
     from mxnet_tpu.serving import (CheckpointSubscriber, ReplicaLost,
                                    ServingEngine, ServingReplica)
     from mxnet_tpu.serving.rpc import RpcServer, write_port_file
@@ -155,7 +156,6 @@ def main(argv=None):
     # launcher spawns a replacement) until its executables are on
     # disk, or the replacement races the store and pays a foreground
     # compile the warm-spin-up contract forbids
-    from mxnet_tpu import aot_cache
     aot_cache.drain(timeout=180)
     server = RpcServer(replica, host=args.host, port=args.port,
                        attempt=attempt)
