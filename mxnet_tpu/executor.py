@@ -610,19 +610,22 @@ class Executor:
                 outs, new_aux = plan(merged, aux_vals, rng, True)
                 return tuple(outs), new_aux
 
-            outs, vjp, new_aux = jax.vjp(f, param_vals, has_aux=True)
-            # loss heads seed with ones, exactly like forward_backward's
-            # default out_grads — fused and unfused paths share semantics
-            ograds = tuple(jnp.ones(o.shape, o.dtype) for o in outs)
-            grads = vjp(ograds)[0]
+            with jax.named_scope("forward_backward"):
+                outs, vjp, new_aux = jax.vjp(f, param_vals, has_aux=True)
+                # loss heads seed with ones, exactly like
+                # forward_backward's default out_grads — fused and
+                # unfused paths share semantics
+                ograds = tuple(jnp.ones(o.shape, o.dtype) for o in outs)
+                grads = vjp(ograds)[0]
             new_params, new_state, ok = guarded(
                 param_vals, grads, opt_state, lr, wd, rescale, t, poison)
             # the guard's skip covers aux too: a NaN batch must not commit
             # poisoned forward-pass statistics (BatchNorm moving mean/var)
             # any more than poisoned weights
             merged_aux = dict(aux_vals)
-            for k, v in new_aux.items():
-                merged_aux[k] = jnp.where(ok, v, aux_vals[k])
+            with jax.named_scope("divergence_guard"):
+                for k, v in new_aux.items():
+                    merged_aux[k] = jnp.where(ok, v, aux_vals[k])
             return outs, new_params, new_state, merged_aux, ok
 
         if self._staged:

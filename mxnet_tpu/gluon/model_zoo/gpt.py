@@ -380,12 +380,15 @@ def _block_qkv(lp, x, n_heads):
     """Shared per-layer front half: LN1 + fused head-major qkv.
     x [B, T, C] -> q, k, v [B, H, T, D] (layout from basic_layers.py's
     FlashSelfAttention; the ONE copy _prefill and _decode_one share)."""
+    import jax
     b, t, c = x.shape
     d = c // n_heads
-    h = _ln(x, lp["ln1_g"], lp["ln1_b"])
-    qkv = (h @ lp["qkv_w"].T + lp["qkv_b"]).reshape(b, t, n_heads, 3, d)
-    qkv = qkv.transpose(0, 2, 1, 3, 4)           # [B, H, T, 3, D]
-    return qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
+    with jax.named_scope("attn"):
+        h = _ln(x, lp["ln1_g"], lp["ln1_b"])
+        qkv = (h @ lp["qkv_w"].T + lp["qkv_b"]).reshape(
+            b, t, n_heads, 3, d)
+        qkv = qkv.transpose(0, 2, 1, 3, 4)       # [B, H, T, 3, D]
+        return qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
 
 
 def _block_finish(lp, x, o):
@@ -393,22 +396,25 @@ def _block_finish(lp, x, o):
     residual + LN2 + MLP (dense gelu or mixture of experts) +
     residual."""
     import jax
-    x = x + o @ lp["out_w"].T + lp["out_b"]
-    h = _ln(x, lp["ln2_g"], lp["ln2_b"])
-    if "moe" in lp:
-        from ...parallel.moe import moe_dense
-        b, t, c = h.shape
-        gate_w, w1, b1, w2, b2 = lp["moe"]
-        # DROPLESS at inference (capacity == token count): GShard's
-        # capacity dropping is a training-throughput trade whose queue
-        # positions couple tokens across the batch — decode must stay
-        # position-local to match the cache-free forward
-        out = moe_dense(h.reshape(b * t, c), gate_w, w1, b1, w2, b2,
-                        capacity_factor=float(w1.shape[0]),
-                        act=jax.nn.gelu)
-        return x + out.reshape(b, t, c)
-    h = jax.nn.gelu(h @ lp["fc1_w"].T + lp["fc1_b"], approximate=True)
-    return x + h @ lp["fc2_w"].T + lp["fc2_b"]
+    with jax.named_scope("attn"):
+        x = x + o @ lp["out_w"].T + lp["out_b"]
+    with jax.named_scope("mlp"):
+        h = _ln(x, lp["ln2_g"], lp["ln2_b"])
+        if "moe" in lp:
+            from ...parallel.moe import moe_dense
+            b, t, c = h.shape
+            gate_w, w1, b1, w2, b2 = lp["moe"]
+            # DROPLESS at inference (capacity == token count): GShard's
+            # capacity dropping is a training-throughput trade whose
+            # queue positions couple tokens across the batch — decode
+            # must stay position-local to match the cache-free forward
+            out = moe_dense(h.reshape(b * t, c), gate_w, w1, b1, w2, b2,
+                            capacity_factor=float(w1.shape[0]),
+                            act=jax.nn.gelu)
+            return x + out.reshape(b, t, c)
+        h = jax.nn.gelu(h @ lp["fc1_w"].T + lp["fc1_b"],
+                        approximate=True)
+        return x + h @ lp["fc2_w"].T + lp["fc2_b"]
 
 
 def _decode_one(p, tok, pos, caches, n_heads):
@@ -657,15 +663,17 @@ def _block_qkv_kv(lp, x, n_heads):
     Returns ``q [B, H, T, D], k, v [B, K_kv, T, D]``."""
     if "qkv_w" in lp:
         return _block_qkv(lp, x, n_heads)
+    import jax
     b, t, c = x.shape
     d = c // n_heads
     kv_heads = lp["k_w"].shape[0] // d
-    h = _ln(x, lp["ln1_g"], lp["ln1_b"])
-    q = (h @ lp["q_w"].T + lp["q_b"]).reshape(b, t, n_heads, d)
-    k = (h @ lp["k_w"].T + lp["k_b"]).reshape(b, t, kv_heads, d)
-    v = (h @ lp["v_w"].T + lp["v_b"]).reshape(b, t, kv_heads, d)
-    return (q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3))
+    with jax.named_scope("attn"):
+        h = _ln(x, lp["ln1_g"], lp["ln1_b"])
+        q = (h @ lp["q_w"].T + lp["q_b"]).reshape(b, t, n_heads, d)
+        k = (h @ lp["k_w"].T + lp["k_b"]).reshape(b, t, kv_heads, d)
+        v = (h @ lp["v_w"].T + lp["v_b"]).reshape(b, t, kv_heads, d)
+        return (q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                v.transpose(0, 2, 1, 3))
 
 
 def _bcast_kv(k, n_heads):
@@ -825,7 +833,9 @@ def paged_decode_step(p, tokens, positions, active, kv_pages,
     page_size = kv_pages[0][0].shape[1]
     from ...ops.pallas.paged_attention import paged_attention
 
-    x = p["wte"][tokens][:, None] + p["wpe"][positions][:, None]
+    import jax
+    with jax.named_scope("embed"):
+        x = p["wte"][tokens][:, None] + p["wpe"][positions][:, None]
     c = x.shape[-1]
     # where each slot's new K/V lands: (physical page, in-page offset);
     # inactive slots are routed to scratch page 0
@@ -843,27 +853,33 @@ def paged_decode_step(p, tokens, positions, active, kv_pages,
         q, k, v = _block_qkv_kv(lp, x, n_heads)     # q [S, H, 1, D]
         if quantized:
             kc, vc, ks, vs = entry                  # k/v [S, K_kv, 1, D]
-            kc, ks = _quant_scatter(kc, ks, phys, offs, k[:, :, 0, :],
-                                    active)
-            vc, vs = _quant_scatter(vc, vs, phys, offs, v[:, :, 0, :],
-                                    active)
-            o = paged_attention(q[:, :, 0, :], kc, vc, block_tables,
-                                ctx, k_scales=ks, v_scales=vs)
+            with jax.named_scope("kv_write"):
+                kc, ks = _quant_scatter(kc, ks, phys, offs,
+                                        k[:, :, 0, :], active)
+                vc, vs = _quant_scatter(vc, vs, phys, offs,
+                                        v[:, :, 0, :], active)
+            with jax.named_scope("attn"):
+                o = paged_attention(q[:, :, 0, :], kc, vc, block_tables,
+                                    ctx, k_scales=ks, v_scales=vs)
             new_pages.append((kc, vc, ks, vs))
         else:
             kc, vc = entry
-            kc = kc.at[phys, offs].set(
-                k[:, :, 0, :].astype(kc.dtype))
-            vc = vc.at[phys, offs].set(
-                v[:, :, 0, :].astype(vc.dtype))
-            o = paged_attention(q[:, :, 0, :], kc, vc, block_tables,
-                                ctx)
+            with jax.named_scope("kv_write"):
+                kc = kc.at[phys, offs].set(
+                    k[:, :, 0, :].astype(kc.dtype))
+                vc = vc.at[phys, offs].set(
+                    v[:, :, 0, :].astype(vc.dtype))
+            with jax.named_scope("attn"):
+                o = paged_attention(q[:, :, 0, :], kc, vc, block_tables,
+                                    ctx)
             new_pages.append((kc, vc))
         x = _block_finish(lp, x, o.reshape(s_n, 1, c))
-    h = _ln(x[:, 0], p["lnf_g"], p["lnf_b"])
-    logits = h @ p["wte"].T
+    with jax.named_scope("lm_head"):
+        h = _ln(x[:, 0], p["lnf_g"], p["lnf_b"])
+        logits = h @ p["wte"].T
     if sampling is None:
-        return logits, logits.argmax(-1).astype(jnp.int32), new_pages
+        with jax.named_scope("sample"):
+            return logits, logits.argmax(-1).astype(jnp.int32), new_pages
     temps, top_ks, top_ps, keys = sampling
     # an all-greedy resident batch must not pay the sampling math
     # (vocab sorts + categorical per slot): cond executes ONE branch.
@@ -871,10 +887,11 @@ def paged_decode_step(p, tokens, positions, active, kv_pages,
     # its tokens, so its key still advances exactly once per token —
     # the per-request determinism law is composition-independent.
     from jax import lax
-    nxt, new_keys = lax.cond(
-        jnp.any(temps > 0),
-        lambda: sample_tokens(logits, temps, top_ks, top_ps, keys),
-        lambda: (logits.argmax(-1).astype(jnp.int32), keys))
+    with jax.named_scope("sample"):
+        nxt, new_keys = lax.cond(
+            jnp.any(temps > 0),
+            lambda: sample_tokens(logits, temps, top_ks, top_ps, keys),
+            lambda: (logits.argmax(-1).astype(jnp.int32), keys))
     return logits, nxt, new_keys, new_pages
 
 
@@ -1005,7 +1022,9 @@ def paged_spec_decode_step(p, tokens, positions, active, draft_len,
     # query-row validity: the slot is live and the row is the current
     # token (i == 0) or a real draft (i <= draft_len)
     qmask = active[:, None] & (qpos[None, :] <= draft_len[:, None])
-    x = p["wte"][tokens] + p["wpe"][positions]          # [S, K, C]
+    import jax
+    with jax.named_scope("embed"):
+        x = p["wte"][tokens] + p["wpe"][positions]      # [S, K, C]
     c = x.shape[-1]
     logical = positions // page_size
     phys = jnp.where(qmask,
@@ -1022,30 +1041,36 @@ def paged_spec_decode_step(p, tokens, positions, active, draft_len,
         vr = v.transpose(0, 2, 1, 3)
         if quantized:
             kc, vc, ks, vs = entry
-            kc, ks = _quant_scatter(
-                kc, ks, flat(phys), flat(offs),
-                kr.reshape((s_n * k1,) + kr.shape[2:]), flat(qmask))
-            vc, vs = _quant_scatter(
-                vc, vs, flat(phys), flat(offs),
-                vr.reshape((s_n * k1,) + vr.shape[2:]), flat(qmask))
-            o = paged_attention_multi(q.transpose(0, 2, 1, 3), kc, vc,
-                                      block_tables, ctx, k_scales=ks,
-                                      v_scales=vs)  # [S, K, H, D]
+            with jax.named_scope("kv_write"):
+                kc, ks = _quant_scatter(
+                    kc, ks, flat(phys), flat(offs),
+                    kr.reshape((s_n * k1,) + kr.shape[2:]), flat(qmask))
+                vc, vs = _quant_scatter(
+                    vc, vs, flat(phys), flat(offs),
+                    vr.reshape((s_n * k1,) + vr.shape[2:]), flat(qmask))
+            with jax.named_scope("attn"):
+                o = paged_attention_multi(
+                    q.transpose(0, 2, 1, 3), kc, vc, block_tables, ctx,
+                    k_scales=ks, v_scales=vs)       # [S, K, H, D]
             new_pages.append((kc, vc, ks, vs))
         else:
             kc, vc = entry
-            kc = kc.at[phys, offs].set(kr.astype(kc.dtype))
-            vc = vc.at[phys, offs].set(vr.astype(vc.dtype))
-            o = paged_attention_multi(q.transpose(0, 2, 1, 3), kc, vc,
-                                      block_tables, ctx)
+            with jax.named_scope("kv_write"):
+                kc = kc.at[phys, offs].set(kr.astype(kc.dtype))
+                vc = vc.at[phys, offs].set(vr.astype(vc.dtype))
+            with jax.named_scope("attn"):
+                o = paged_attention_multi(q.transpose(0, 2, 1, 3), kc,
+                                          vc, block_tables, ctx)
             new_pages.append((kc, vc))
         x = _block_finish(lp, x, o.reshape(s_n, k1, c))
-    h = _ln(x, p["lnf_g"], p["lnf_b"])
-    logits = h @ p["wte"].T                            # [S, K, V]
+    with jax.named_scope("lm_head"):
+        h = _ln(x, p["lnf_g"], p["lnf_b"])
+        logits = h @ p["wte"].T                        # [S, K, V]
     draft_valid = qmask[:, 1:]          # draft at input column i+1
-    greedy_next, acc_g = _spec_accept_greedy(logits, tokens,
-                                             draft_valid)
-    n_new_g = jnp.where(active, acc_g + 1, 0).astype(jnp.int32)
+    with jax.named_scope("sample"):
+        greedy_next, acc_g = _spec_accept_greedy(logits, tokens,
+                                                 draft_valid)
+        n_new_g = jnp.where(active, acc_g + 1, 0).astype(jnp.int32)
     if sampling is None:
         return logits, greedy_next, n_new_g, new_pages
     temps, top_ks, top_ps, keys = sampling
@@ -1068,9 +1093,10 @@ def paged_spec_decode_step(p, tokens, positions, active, draft_len,
                              keys)
         return out, n_new, new_keys
 
-    out_tokens, n_new, new_keys = lax.cond(
-        jnp.any(temps > 0), _sampled,
-        lambda: (greedy_next, n_new_g, keys))
+    with jax.named_scope("sample"):
+        out_tokens, n_new, new_keys = lax.cond(
+            jnp.any(temps > 0), _sampled,
+            lambda: (greedy_next, n_new_g, keys))
     return logits, out_tokens, n_new, new_keys, new_pages
 
 
@@ -1079,10 +1105,12 @@ def _first_token(logits, sampling, new_pages):
     4-tuple with the functionally-advanced key (scalar flavor of
     :func:`sample_tokens`; greedy requests skip the sampling math via
     cond)."""
+    import jax
     import jax.numpy as jnp
     from jax import lax
     if sampling is None:
-        return logits, logits.argmax(-1).astype(jnp.int32), new_pages
+        with jax.named_scope("sample"):
+            return logits, logits.argmax(-1).astype(jnp.int32), new_pages
     temp, top_k, top_p, key = sampling
 
     def _sampled():
@@ -1092,9 +1120,10 @@ def _first_token(logits, sampling, new_pages):
             jnp.reshape(top_p, (1,)).astype(jnp.float32), key[None])
         return tok[0], new_key[0]
 
-    tok, new_key = lax.cond(
-        temp > 0, _sampled,
-        lambda: (logits.argmax(-1).astype(jnp.int32), key))
+    with jax.named_scope("sample"):
+        tok, new_key = lax.cond(
+            temp > 0, _sampled,
+            lambda: (logits.argmax(-1).astype(jnp.int32), key))
     return logits, tok, new_key, new_pages
 
 
@@ -1112,7 +1141,8 @@ def _prefill_rows(p, tokens, prompt_len, prefix_len, prefix_kv, n_heads):
 
     t_pad = tokens.shape[0]
     positions = prefix_len + jnp.arange(t_pad)
-    x = (p["wte"][tokens] + p["wpe"][positions])[None]  # [1, T_pad, C]
+    with jax.named_scope("embed"):
+        x = (p["wte"][tokens] + p["wpe"][positions])[None]  # [1, T_pad, C]
     c = x.shape[-1]
     d = c // n_heads
     valid = jnp.arange(t_pad) < prompt_len - prefix_len
@@ -1129,30 +1159,31 @@ def _prefill_rows(p, tokens, prompt_len, prefix_len, prefix_kv, n_heads):
     rows = []
     for i, lp in enumerate(p["layers"]):
         q, k, v = _block_qkv_kv(lp, x, n_heads)   # [1, H|K_kv, T_pad, D]
-        kd, vd = _bcast_kv(k, n_heads), _bcast_kv(v, n_heads)
-        st = jnp.where(mask_suf,
-                       jnp.einsum("bhqd,bhkd->bhqk", q, kd) / scale,
-                       -1e30)
-        if prefix_kv is not None:
-            # cached prefix K/V: [mp, page, K_kv, D] -> [1, H, t_ctx, D]
-            kp, vp = (_bcast_kv(a.reshape(t_ctx, -1, d)
-                                .transpose(1, 0, 2)[None], n_heads)
-                      for a in prefix_kv[i])
-            # positions past the cached prefix read scratch/unwritten
-            # pages whose contents are GARBAGE — a NaN there (e.g. a
-            # hot-swap canary's torn-weight writes to scratch) would
-            # poison the output through 0 * NaN even though its softmax
-            # weight is exactly zero.  Zero the V rows, not just the
-            # scores.
-            vp = jnp.where(pre_valid[None, None, :, None], vp, 0.0)
-            st_pre = jnp.where(
-                mask_pre, jnp.einsum("bhqd,bhkd->bhqk", q, kp) / scale,
-                -1e30)
-            st = jnp.concatenate([st_pre, st], axis=-1)
-            vd = jnp.concatenate([vp, vd], axis=2)
-        pr = jax.nn.softmax(st, axis=-1)
-        o = jnp.einsum("bhqk,bhkd->bhqd", pr, vd)
-        o = o.transpose(0, 2, 1, 3).reshape(1, t_pad, c)
+        with jax.named_scope("attn"):
+            kd, vd = _bcast_kv(k, n_heads), _bcast_kv(v, n_heads)
+            st = jnp.where(mask_suf,
+                           jnp.einsum("bhqd,bhkd->bhqk", q, kd) / scale,
+                           -1e30)
+            if prefix_kv is not None:
+                # cached prefix K/V: [mp, page, K_kv, D] -> [1, H, t_ctx, D]
+                kp, vp = (_bcast_kv(a.reshape(t_ctx, -1, d)
+                                    .transpose(1, 0, 2)[None], n_heads)
+                          for a in prefix_kv[i])
+                # positions past the cached prefix read scratch/unwritten
+                # pages whose contents are GARBAGE — a NaN there (e.g. a
+                # hot-swap canary's torn-weight writes to scratch) would
+                # poison the output through 0 * NaN even though its softmax
+                # weight is exactly zero.  Zero the V rows, not just the
+                # scores.
+                vp = jnp.where(pre_valid[None, None, :, None], vp, 0.0)
+                st_pre = jnp.where(
+                    mask_pre, jnp.einsum("bhqd,bhkd->bhqk", q, kp) / scale,
+                    -1e30)
+                st = jnp.concatenate([st_pre, st], axis=-1)
+                vd = jnp.concatenate([vp, vd], axis=2)
+            pr = jax.nn.softmax(st, axis=-1)
+            o = jnp.einsum("bhqk,bhkd->bhqd", pr, vd)
+            o = o.transpose(0, 2, 1, 3).reshape(1, t_pad, c)
         rows.append((k[0].transpose(1, 0, 2), v[0].transpose(1, 0, 2)))
         x = _block_finish(lp, x, o)
     return _ln(x[0], p["lnf_g"], p["lnf_b"]), rows
@@ -1202,6 +1233,7 @@ def paged_prefill(p, tokens, prompt_len, prefix_len, block_table_row,
     is produced here (the suffix is always >= 1 token — a fully-cached
     prompt still runs its final position through the model).
     """
+    import jax
     import jax.numpy as jnp
     from jax import lax
 
@@ -1233,21 +1265,27 @@ def paged_prefill(p, tokens, prompt_len, prefix_len, block_table_row,
     offs = positions % page_size
     new_pages = []
     for entry, (k, v) in zip(kv_pages, rows):
-        if quantized:
-            # the COW page is the only written page with pre-existing
-            # content; _quant_scatter's grow-only rescale handles it
-            # (fresh pages start at an offs == 0 row and reset)
-            kc, ks = _quant_scatter(entry[0], entry[2], phys, offs, k,
-                                    valid)
-            vc, vs = _quant_scatter(entry[1], entry[3], phys, offs, v,
-                                    valid)
-            new_pages.append((kc, vc, ks, vs))
-        else:
-            kc, vc = entry
-            new_pages.append((kc.at[phys, offs].set(k.astype(kc.dtype)),
-                              vc.at[phys, offs].set(v.astype(vc.dtype))))
-    last = lax.dynamic_index_in_dim(h, suffix_len - 1, 0, keepdims=False)
-    return _first_token(last @ p["wte"].T, sampling, new_pages)
+        with jax.named_scope("kv_write"):
+            if quantized:
+                # the COW page is the only written page with
+                # pre-existing content; _quant_scatter's grow-only
+                # rescale handles it (fresh pages start at an offs == 0
+                # row and reset)
+                kc, ks = _quant_scatter(entry[0], entry[2], phys, offs,
+                                        k, valid)
+                vc, vs = _quant_scatter(entry[1], entry[3], phys, offs,
+                                        v, valid)
+                new_pages.append((kc, vc, ks, vs))
+            else:
+                kc, vc = entry
+                new_pages.append(
+                    (kc.at[phys, offs].set(k.astype(kc.dtype)),
+                     vc.at[phys, offs].set(v.astype(vc.dtype))))
+    with jax.named_scope("lm_head"):
+        last = lax.dynamic_index_in_dim(h, suffix_len - 1, 0,
+                                        keepdims=False)
+        logits = last @ p["wte"].T
+    return _first_token(logits, sampling, new_pages)
 
 
 def get_gpt(num_layers, units, num_heads, vocab_size=50257, max_len=1024,

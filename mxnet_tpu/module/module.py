@@ -12,7 +12,6 @@ the parallel layer rather than split by Python.
 from __future__ import annotations
 
 import logging
-import time
 
 from .. import context as ctx_mod
 from .. import ndarray as nd
@@ -599,6 +598,12 @@ class Module(BaseModule):
         check_async_error()
         if not self._fused_eligible():
             return super().fit_step(data_batch)
+        from .. import telemetry as _telemetry
+        with _telemetry.span("fit_step", "step",
+                             step=self._optimizer.num_update):
+            self._fused_fit_step(data_batch)
+
+    def _fused_fit_step(self, data_batch):
         from .. import fault as _fault
         from .. import profiler as _profiler
         from .. import random as _random
@@ -611,79 +616,89 @@ class Module(BaseModule):
         # below; the watchdog (armed when MXTPU_STALL_TIMEOUT is set)
         # diagnoses and exits 75 — retryable by the launcher
         _fault.stall_if("worker.stall")
-        fused = self._fused_setup()
-        exe = self._exec
-        feeds = self._feed_batch(data_batch)
-        for k, v in feeds.items():
-            exe.arg_dict[k]._set_data(
-                v._data if isinstance(v, nd.NDArray) else
-                nd.array(v)._data)
+        with _telemetry.span("fit_step.feed", "step"):
+            fused = self._fused_setup()
+            exe = self._exec
+            feeds = self._feed_batch(data_batch)
+            for k, v in feeds.items():
+                exe.arg_dict[k]._set_data(
+                    v._data if isinstance(v, nd.NDArray) else
+                    nd.array(v)._data)
 
-        update_names = fused["update_names"]
-        in_update = set(update_names)
-        param_vals = exe._raw({n: exe.arg_dict[n] for n in update_names})
-        other_vals = exe._raw({n: a for n, a in exe.arg_dict.items()
-                               if n not in in_update})
-        aux_vals = exe._raw_aux()
+            update_names = fused["update_names"]
+            in_update = set(update_names)
+            param_vals = exe._raw(
+                {n: exe.arg_dict[n] for n in update_names})
+            other_vals = exe._raw({n: a for n, a in exe.arg_dict.items()
+                                   if n not in in_update})
+            aux_vals = exe._raw_aux()
 
-        opt = self._optimizer
-        first_idx = None
-        update_idxs = []
-        pre_num_update = opt.num_update
-        for i, name in enumerate(self._param_names):
-            if name in in_update:
-                opt._update_count(i)
-                update_idxs.append(i)
-                if first_idx is None:
-                    first_idx = i
-        t = float(opt._index_update_count[first_idx]) \
-            if first_idx is not None else 1.0
-        lr = opt.fused_base_lr()
-        wd = float(opt.wd)
-        rescale = float(opt.rescale_grad)
-        scaler = getattr(self._precision, "loss_scaler", None)
-        if scaler is not None:
-            # loss scaling (precision.py): the graph's loss head is
-            # pre-scaled by scaler.scale; undo it on the grads through
-            # the DYNAMIC rescale scalar — scale moves never recompile
-            rescale *= scaler.unscale
-        poison = float("nan") if _fault.trigger("grad.nan") else 0.0
+            opt = self._optimizer
+            first_idx = None
+            update_idxs = []
+            pre_num_update = opt.num_update
+            for i, name in enumerate(self._param_names):
+                if name in in_update:
+                    opt._update_count(i)
+                    update_idxs.append(i)
+                    if first_idx is None:
+                        first_idx = i
+            t = float(opt._index_update_count[first_idx]) \
+                if first_idx is not None else 1.0
+            lr = opt.fused_base_lr()
+            wd = float(opt.wd)
+            rescale = float(opt.rescale_grad)
+            scaler = getattr(self._precision, "loss_scaler", None)
+            if scaler is not None:
+                # loss scaling (precision.py): the graph's loss head is
+                # pre-scaled by scaler.scale; undo it on the grads
+                # through the DYNAMIC rescale scalar — scale moves never
+                # recompile
+                rescale *= scaler.unscale
+            poison = float("nan") if _fault.trigger("grad.nan") else 0.0
+            rng = _random.next_key()
 
-        rng = _random.next_key()
-        t0 = time.perf_counter_ns()
-        # straggler stand-in: a bounded delay INSIDE the timed dispatch
-        # window, so the injected slowness shows exactly where a slow
-        # host's would — in this rank's fit_step.dispatch percentiles
-        # (job_report.py's straggler blame keys off them)
-        _fault.delay_if("step.slow")
-        outs, new_params, new_state, new_aux, ok = fused["step"](
-            param_vals, fused["state"], other_vals, aux_vals, rng,
-            lr, wd, rescale, t, poison)
-        t1 = time.perf_counter_ns()
-        fused["state"] = new_state
-        # donated inputs are dead now — re-point every wrapper at the
-        # step's outputs before anything else can touch them
-        for name, v in new_params.items():
-            exe.arg_dict[name]._set_data(v)
-        for name, v in new_aux.items():
-            exe.aux_dict[name]._set_data(v)
-        exe.outputs = [NDArray(o, exe._ctx) for o in outs]
-        self._params_dirty = True
-        _profiler.note_step()
-        # divergence guard verdict: reading the scalar costs one small
-        # host readback that the fit loop's metric update would force
-        # anyway (PERF.md "Divergence guard"); a skipped step rewinds the
-        # optimizer clocks so it is as if the batch never arrived.  The
-        # readback is also the step's device-sync point, so [t1, t2] is
-        # telemetry's "fit_step.sync" phase (~the device compute time).
-        ok_host = bool(ok)
-        t2 = time.perf_counter_ns()
+        with _telemetry.stamp_span("fit_step.dispatch") as disp:
+            # straggler stand-in: a bounded delay INSIDE the timed
+            # dispatch window, so the injected slowness shows exactly
+            # where a slow host's would — in this rank's
+            # fit_step.dispatch percentiles (job_report.py's straggler
+            # blame keys off them)
+            _fault.delay_if("step.slow")
+            outs, new_params, new_state, new_aux, ok = fused["step"](
+                param_vals, fused["state"], other_vals, aux_vals, rng,
+                lr, wd, rescale, t, poison)
+        # "fit_step.sync" is the host's time from the program call's
+        # return to the guard's verdict: re-pointing the wrappers (its
+        # child "fit_step.rebind", which overlaps the device's work),
+        # then the wait for the readback
+        with _telemetry.stamp_span("fit_step.sync") as sync:
+            with _telemetry.span("fit_step.rebind", "step"):
+                fused["state"] = new_state
+                # donated inputs are dead now — re-point every wrapper
+                # at the step's outputs before anything else can touch
+                # them
+                for name, v in new_params.items():
+                    exe.arg_dict[name]._set_data(v)
+                for name, v in new_aux.items():
+                    exe.aux_dict[name]._set_data(v)
+                exe.outputs = [NDArray(o, exe._ctx) for o in outs]
+                self._params_dirty = True
+                _profiler.note_step()
+            # divergence guard verdict: reading the scalar costs one
+            # small host readback that the fit loop's metric update would
+            # force anyway (PERF.md "Divergence guard"); a skipped step
+            # rewinds the optimizer clocks so it is as if the batch never
+            # arrived.  The readback is also the step's device-sync
+            # point.
+            ok_host = bool(ok)
         # loss for the flight recorder, free of extra syncs: only a
         # scalar head (loss-output nets) is worth a host read, and only
         # while recording actually consumes it
         loss = float(outs[0]) if outs and not outs[0].shape \
             and _telemetry.enabled() else None
-        _telemetry.note_train_step(t0, t1, t2, not ok_host, loss)
+        _telemetry.note_train_step(disp.t0, disp.t1, sync.t1, not ok_host,
+                                   loss)
         # progress lease: one monotonic store per completed step (no
         # dispatches — steptrace's 1.0 dispatch/step still holds)
         _watchdog.renew("fit_step", phase="train")

@@ -322,16 +322,24 @@ def make_guarded_apply(apply_fn, zero_shardings=None, param_shardings=None):
                 for name, v in tree.items()} if shardings else tree
 
     def guarded(params, grads, state, lr, wd, rescale_grad, t, poison):
-        grads = {name: g + poison for name, g in grads.items()}
-        grads = _wsc(grads, zero_shardings)  # dp grad sum → reduce-scatter
-        ok = all_finite(grads)
-        new_params, new_state = apply_fn(params, grads, state, lr, wd,
-                                         rescale_grad, t)
-        new_params = _wsc(new_params, zero_shardings)  # 1/N update compute
-        new_params = jax.tree_util.tree_map(
-            lambda n, o: jnp.where(ok, n, o), new_params, params)
-        new_state = jax.tree_util.tree_map(
-            lambda n, o: jnp.where(ok, n, o), new_state, state)
+        # scope names are what a device trace is read by (PERF.md
+        # section 3): the guard's pass over the gradients apart from
+        # the optimizer's arithmetic
+        with jax.named_scope("divergence_guard"):
+            grads = {name: g + poison for name, g in grads.items()}
+            # dp grad sum → reduce-scatter
+            grads = _wsc(grads, zero_shardings)
+            ok = all_finite(grads)
+        with jax.named_scope("optimizer_apply"):
+            new_params, new_state = apply_fn(params, grads, state, lr, wd,
+                                             rescale_grad, t)
+            # 1/N update compute
+            new_params = _wsc(new_params, zero_shardings)
+        with jax.named_scope("divergence_guard"):
+            new_params = jax.tree_util.tree_map(
+                lambda n, o: jnp.where(ok, n, o), new_params, params)
+            new_state = jax.tree_util.tree_map(
+                lambda n, o: jnp.where(ok, n, o), new_state, state)
         if zero_shardings:
             new_params = _wsc(new_params, param_shardings)  # all-gather
         return new_params, new_state, ok

@@ -252,6 +252,7 @@ def _flash_fwd_aligned(q, k, v, scale, causal, block_q, block_k, tk_true,
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           tk_true=tk_true, has_seg=has_seg),
         operands,
+        name="flash_fwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -549,6 +550,7 @@ def _flash_bwd_fused(res, g, scale, causal, block_q, block_k, h=1):
                               causal=causal, tq_true=tq, tk_true=tk,
                               k_base=k_base, has_seg=has_seg),
             operands,
+            name="flash_bwd_fused",
             grid=(bh, nk_c, tqp // block_q),
             in_specs=in_specs,
             out_specs=[
@@ -663,6 +665,7 @@ def _flash_bwd_split(res, g, scale, causal, block_q, block_k, h=1):
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           tk_true=tk, has_seg=has_seg),
         dq_ops,
+        name="flash_bwd_dq",
         grid=(bh, pl.cdiv(tq, block_q), tkp // block_k),
         in_specs=dq_specs,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -687,6 +690,7 @@ def _flash_bwd_split(res, g, scale, causal, block_q, block_k, h=1):
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           tq_true=tq, has_seg=has_seg),
         dkv_ops,
+        name="flash_bwd_dkv",
         grid=(bh, pl.cdiv(tk, block_k), tqp // block_q),
         in_specs=dkv_specs,
         out_specs=[

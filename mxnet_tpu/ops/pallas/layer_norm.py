@@ -81,6 +81,7 @@ def _kernel_call(x2, r2, gamma, beta, eps, interpret, block_rows=256):
     return _pallas_call(
         functools.partial(_ln_kernel, eps=np.float32(eps)),
         (x2, r2, gamma, beta),
+        name="layer_norm",
         interpret=interpret,
         out_shape=(jax.ShapeDtypeStruct((R, D), x2.dtype),
                    jax.ShapeDtypeStruct((R, 1), jnp.float32),

@@ -248,6 +248,7 @@ def paged_attention_multi(q, k_pages, v_pages, block_tables,
                           n_kv=n_kv, g=g, n_q=n_q,
                           scale=np.float32(scale), quantized=quantized),
         args,
+        name="paged_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_n, n_kv, rows, d), q.dtype))
     return out.reshape(s_n, n_kv, n_q, g, d).transpose(0, 2, 1, 3, 4) \

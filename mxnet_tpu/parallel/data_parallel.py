@@ -106,8 +106,14 @@ def make_train_step(loss_fn, mesh, optimizer_apply=None, optimizer_init=None,
             batch)
 
     def step(params, opt_state, batch, rng):
-        loss, grads = jax.value_and_grad(loss_fn)(params, batch, rng)
-        new_params, new_state = optimizer_apply(params, grads, opt_state)
+        # scope names are what a device trace is read by (PERF.md
+        # section 3); the backward's ops carry "loss" inside their
+        # transpose(jvp(...)) path
+        with jax.named_scope("loss"):
+            loss, grads = jax.value_and_grad(loss_fn)(params, batch, rng)
+        with jax.named_scope("optimizer_apply"):
+            new_params, new_state = optimizer_apply(params, grads,
+                                                    opt_state)
         return new_params, new_state, loss
 
     programs = {}

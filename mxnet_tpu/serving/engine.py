@@ -1055,7 +1055,10 @@ class ServingEngine:
         typed ``prefill_error`` verdict, slot + every reserved page
         released, never requeued — and the loop moves on."""
         placed = []
-        for req in self.sched.admit():
+        with _telemetry.span("serve.admit", "serving") as sp:
+            admitted = self.sched.admit()
+            sp.set(admitted=len(admitted))
+        for req in admitted:
             _telemetry.histogram("serving.queue_wait").observe(
                 req.queue_wait_s)
             _telemetry.note_request_event(
@@ -1078,15 +1081,29 @@ class ServingEngine:
                              error=str(e))
                 _telemetry.counter("serving.prefill_errors").inc()
                 continue
-            samp = self._arm_slot_sampling(req)
-            toks = _np.zeros(self.max_prefill_len, _np.int32)
-            # req.prefix_len is 0 with the cache off or on a miss: the
-            # suffix is then the whole prompt and the program's dense
-            # branch runs
-            suffix = req.prompt[req.prefix_len:]
-            toks[:suffix.size] = suffix
-            t0 = time.perf_counter_ns()
-            with _watchdog.guard("serve.prefill"):
+            # one span a request: a device gap under it is that
+            # request's (rid and trace tie it to the request events)
+            with _telemetry.span(
+                    "serve_prefill", "serving", rid=req.rid,
+                    trace=req.trace, prompt=int(req.prompt.size),
+                    prefix_len=req.prefix_len,
+                    queue_wait_us=int(req.queue_wait_s * 1e6)):
+                self._prefill_one(req)
+            placed.append(req)
+        return placed
+
+    def _prefill_one(self, req):
+        """The prefill dispatch of one admitted request, its first-token
+        readback and the bookkeeping that commits it."""
+        with _watchdog.guard("serve.prefill"):
+            with _telemetry.stamp_span("serve_prefill.dispatch") as disp:
+                samp = self._arm_slot_sampling(req)
+                toks = _np.zeros(self.max_prefill_len, _np.int32)
+                # req.prefix_len is 0 with the cache off or on a miss:
+                # the suffix is then the whole prompt and the program's
+                # dense branch runs
+                suffix = req.prompt[req.prefix_len:]
+                toks[:suffix.size] = suffix
                 logits, first, new_key, self._kv = self._prefill(
                     self._p, self._kv, toks,
                     _np.int32(req.prompt.size),
@@ -1097,41 +1114,38 @@ class ServingEngine:
                     _np.int32(req.cow_dst if req.cow_dst is not None
                               else SCRATCH_PAGE),
                     *samp)
-                t1 = time.perf_counter_ns()
+            with _telemetry.stamp_span("serve_prefill.sync") as sync:
                 first = int(first)          # device sync
-            t2 = time.perf_counter_ns()
-            # prefix/prefill-token accounting AFTER the dispatch
-            # landed: a prefill that failed (fault above) must not
-            # count tokens that were never prefilled
-            self._note_prefix_admission(req)
-            self._keys[req.slot] = _np.asarray(new_key, _np.uint32)
-            if self._prefix is not None:
-                # register the prompt's full pages under their content
-                # keys — ONLY now, after the prefill landed: a failed
-                # prefill must never leave the index naming pages whose
-                # contents never materialized (the cache stamps the
-                # cached_pages gauge itself)
-                self._prefix.insert(req.prompt,
-                                    self.sched.block_tables[req.slot])
-            _telemetry.note_train_step(t0, t1, t2,
-                                       where="serve_prefill")
-            _telemetry.note_request_event(
-                req.trace, "prefill", t_ns=t0,
-                args={"dispatch_s": round((t1 - t0) * 1e-9, 9),
-                      "sync_s": round((t2 - t1) * 1e-9, 9),
-                      "prefill_tokens":
-                          int(req.prompt.size) - req.prefix_len})
-            # the prefill's first token: one ``token`` event, stamped
-            # BEFORE _note_token so a finish-on-first-token (max_new=1)
-            # orders token -> verdict in the trace
-            _telemetry.note_request_event(req.trace, "token", t_ns=t2)
-            self.prefills += 1
-            _telemetry.counter("serving.prefills").inc()
-            self._note_token(req, first,
-                             _np.asarray(logits) if self._record_logits
-                             else None)
-            placed.append(req)
-        return placed
+        t0, t1, t2 = disp.t0, disp.t1, sync.t1
+        # prefix/prefill-token accounting AFTER the dispatch landed: a
+        # prefill that failed (fault above) must not count tokens that
+        # were never prefilled
+        self._note_prefix_admission(req)
+        self._keys[req.slot] = _np.asarray(new_key, _np.uint32)
+        if self._prefix is not None:
+            # register the prompt's full pages under their content keys
+            # — ONLY now, after the prefill landed: a failed prefill
+            # must never leave the index naming pages whose contents
+            # never materialized (the cache stamps the cached_pages
+            # gauge itself)
+            self._prefix.insert(req.prompt,
+                                self.sched.block_tables[req.slot])
+        _telemetry.note_train_step(t0, t1, t2, where="serve_prefill")
+        _telemetry.note_request_event(
+            req.trace, "prefill", t_ns=t0,
+            args={"dispatch_s": round((t1 - t0) * 1e-9, 9),
+                  "sync_s": round((t2 - t1) * 1e-9, 9),
+                  "prefill_tokens":
+                      int(req.prompt.size) - req.prefix_len})
+        # the prefill's first token: one ``token`` event, stamped BEFORE
+        # _note_token so a finish-on-first-token (max_new=1) orders
+        # token -> verdict in the trace
+        _telemetry.note_request_event(req.trace, "token", t_ns=t2)
+        self.prefills += 1
+        _telemetry.counter("serving.prefills").inc()
+        self._note_token(req, first,
+                         _np.asarray(logits) if self._record_logits
+                         else None)
 
     def _note_token(self, req, token, logits_row=None):
         now = time.perf_counter()
@@ -1161,23 +1175,32 @@ class ServingEngine:
         before the decode dispatch WITHOUT renewing — exactly the
         production failure (a hung XLA dispatch / device lockup) the
         watchdog's exit-75 path exists for."""
-        # the ``serve.prefix.evict`` drill: force-drop the whole prefix
-        # index between steps — victims fall back to a full prefill
-        # with correct tokens (the cache is a capacity optimization,
-        # NEVER a correctness dependency; test-pinned)
-        if self._prefix is not None and _fault.trigger(
-                "serve.prefix.evict"):
-            self.drop_prefix_cache()
-        # the ``serve.kv.scale_poison`` drill (ISSUE 20, int8 pools):
-        # NaN-poison one resident page's scale row between steps — the
-        # quantized divergence guard must catch the victim's non-finite
-        # logits on the next decode and re-prefill it with its correct
-        # tokens, leaving every other resident's stream untouched
-        if self.kv_dtype == "int8" and self.sched.running and \
-                _fault.trigger("serve.kv.scale_poison"):
-            self._poison_page_scale()
-        self._expire_deadlines()
-        self.sweep_streams()
+        with _telemetry.span("serve.step", "serving",
+                             step=self.decode_steps,
+                             live=self.sched.occupancy,
+                             queued=self.sched.queued):
+            return self._step()
+
+    def _step(self):
+        with _telemetry.span("serve.sweep", "serving"):
+            # the ``serve.prefix.evict`` drill: force-drop the whole
+            # prefix index between steps — victims fall back to a full
+            # prefill with correct tokens (the cache is a capacity
+            # optimization, NEVER a correctness dependency; test-pinned)
+            if self._prefix is not None and _fault.trigger(
+                    "serve.prefix.evict"):
+                self.drop_prefix_cache()
+            # the ``serve.kv.scale_poison`` drill (ISSUE 20, int8
+            # pools): NaN-poison one resident page's scale row between
+            # steps — the quantized divergence guard must catch the
+            # victim's non-finite logits on the next decode and
+            # re-prefill it with its correct tokens, leaving every other
+            # resident's stream untouched
+            if self.kv_dtype == "int8" and self.sched.running and \
+                    _fault.trigger("serve.kv.scale_poison"):
+                self._poison_page_scale()
+            self._expire_deadlines()
+            self.sweep_streams()
         placed = self._admit_and_prefill()
         # every placed request produced exactly one token in its prefill
         produced = len(placed)
@@ -1201,76 +1224,81 @@ class ServingEngine:
         _fault.stall_if("serve.decode.stall")
 
         if self.spec_k:
-            produced += self._spec_decode_once(running)
+            return produced + self._spec_decode_once(running)
+
+        s = self.num_slots
+        with _telemetry.span("serve.decode.pack", "serving",
+                             live=len(running)):
+            tokens = _np.zeros(s, _np.int32)
+            positions = _np.zeros(s, _np.int32)
+            active = _np.zeros(s, _np.bool_)
+            for req in running:
+                tokens[req.slot] = req.tokens[-1]
+                # context already in pages: prompt + generated-but-last;
+                # the last generated token is what this step feeds in,
+                # at position prompt_len + (n_generated - 1)
+                positions[req.slot] = \
+                    req.prompt.size + len(req.tokens) - 1
+                active[req.slot] = True
+            args = (tokens, positions, active,
+                    self.sched.block_tables.copy(), self._temps.copy(),
+                    self._top_ks.copy(), self._top_ps.copy(),
+                    self._keys.copy())
+
+        with _telemetry.stamp_span("serve_step.dispatch") as disp:
+            res = self._decode(self._p, self._kv, *args)
+            if self.kv_dtype == "int8":
+                logits, nxt, new_keys, self._kv, ok_dev = res
+            else:
+                logits, nxt, new_keys, self._kv = res
+                ok_dev = None
+        with _telemetry.stamp_span("serve_step.sync") as sync:
+            nxt = _np.asarray(nxt)           # device sync barrier
+        with _telemetry.span("serve.decode.emit", "serving") as emit:
+            # per-slot PRNG state advances FUNCTIONALLY inside the
+            # donated program; the host copy is the only carry between
+            # steps (np.array, not asarray: a jax-backed view is
+            # read-only and admission writes per-slot rows)
+            keys_prev = self._keys
+            self._keys = _np.array(new_keys, _np.uint32)
+            victims = ()
+            if ok_dev is not None:
+                okm = _np.asarray(ok_dev)
+                victims = tuple(r for r in running if not okm[r.slot])
+            _telemetry.note_train_step(disp.t0, disp.t1, sync.t1,
+                                       where="serve_step")
+            # ONE batched ``tokens`` event per decode step naming every
+            # advanced trace (all residents share the step's sync stamp
+            # anyway) — per-token tracing at flight-recorder cost; the
+            # per-trace token count is len-weighted at read time and
+            # must equal the serving.tokens delta bit-exactly
+            # (test-pinned)
+            _telemetry.note_request_event(
+                "", "tokens", t_ns=sync.t1,
+                args={"replica": self.trace_tag,
+                      "step": self.decode_steps,
+                      "traces": [r.trace for r in running
+                                 if r not in victims]})
+            self.decode_steps += 1
+            _watchdog.renew(self._lease, step=self.decode_steps,
+                            phase="serve_step")
+            logits_np = _np.asarray(logits) if self._record_logits \
+                else None
+            made = 0
+            for req in list(running):
+                if req in victims:
+                    continue
+                self._note_token(
+                    req, nxt[req.slot],
+                    None if logits_np is None else logits_np[req.slot])
+                made += 1
+            if victims:
+                self._repair_quant_victims(victims, keys_prev)
             if self.sched.idle:
                 _watchdog.release(self._lease)
             self._publish_gauges()
-            return produced
-
-        s = self.num_slots
-        tokens = _np.zeros(s, _np.int32)
-        positions = _np.zeros(s, _np.int32)
-        active = _np.zeros(s, _np.bool_)
-        for req in running:
-            tokens[req.slot] = req.tokens[-1]
-            # context already in pages: prompt + generated-but-last; the
-            # last generated token is what this step feeds in, at
-            # position prompt_len + (n_generated - 1)
-            positions[req.slot] = req.prompt.size + len(req.tokens) - 1
-            active[req.slot] = True
-
-        t0 = time.perf_counter_ns()
-        res = self._decode(
-            self._p, self._kv, tokens, positions, active,
-            self.sched.block_tables.copy(), self._temps.copy(),
-            self._top_ks.copy(), self._top_ps.copy(),
-            self._keys.copy())
-        if self.kv_dtype == "int8":
-            logits, nxt, new_keys, self._kv, ok_dev = res
-        else:
-            logits, nxt, new_keys, self._kv = res
-            ok_dev = None
-        t1 = time.perf_counter_ns()
-        nxt = _np.asarray(nxt)           # device sync barrier
-        t2 = time.perf_counter_ns()
-        # per-slot PRNG state advances FUNCTIONALLY inside the donated
-        # program; the host copy is the only carry between steps
-        # (np.array, not asarray: a jax-backed view is read-only and
-        # admission writes per-slot rows)
-        keys_prev = self._keys
-        self._keys = _np.array(new_keys, _np.uint32)
-        victims = ()
-        if ok_dev is not None:
-            okm = _np.asarray(ok_dev)
-            victims = tuple(r for r in running if not okm[r.slot])
-        _telemetry.note_train_step(t0, t1, t2, where="serve_step")
-        # ONE batched ``tokens`` event per decode step naming every
-        # advanced trace (all residents share the step's sync stamp
-        # anyway) — per-token tracing at flight-recorder cost; the
-        # per-trace token count is len-weighted at read time and must
-        # equal the serving.tokens delta bit-exactly (test-pinned)
-        _telemetry.note_request_event(
-            "", "tokens", t_ns=t2,
-            args={"replica": self.trace_tag, "step": self.decode_steps,
-                  "traces": [r.trace for r in running
-                             if r not in victims]})
-        self.decode_steps += 1
-        _watchdog.renew(self._lease, step=self.decode_steps,
-                        phase="serve_step")
-        logits_np = _np.asarray(logits) if self._record_logits else None
-        for req in list(running):
-            if req in victims:
-                continue
-            self._note_token(
-                req, nxt[req.slot],
-                None if logits_np is None else logits_np[req.slot])
-            produced += 1
-        if victims:
-            self._repair_quant_victims(victims, keys_prev)
-        if self.sched.idle:
-            _watchdog.release(self._lease)
-        self._publish_gauges()
-        return produced
+            emit.set(tokens=made)
+        return produced + made
 
     # -- speculative decoding (ISSUE 16) -----------------------------------
     def _draft_for(self, req):
@@ -1315,48 +1343,51 @@ class ServingEngine:
         produced."""
         s, k1 = self.num_slots, self.spec_k + 1
         ps = self.page_size
-        tokens = _np.zeros((s, k1), _np.int32)
-        positions = _np.zeros((s, k1), _np.int32)
-        active = _np.zeros(s, _np.bool_)
-        draft_len = _np.zeros(s, _np.int32)
-        drafted = 0
-        marked = []
-        for req in running:
-            drafts = self._draft_for(req)
-            base = int(req.prompt.size) + len(req.tokens) - 1
-            tokens[req.slot, 0] = req.tokens[-1]
-            if drafts:
-                tokens[req.slot, 1:1 + len(drafts)] = drafts
-            positions[req.slot] = base + _np.arange(k1)
-            draft_len[req.slot] = len(drafts)
-            active[req.slot] = True
-            drafted += len(drafts)
-            # pages strictly past the one holding the committed
-            # position receive ONLY draft K/V this dispatch
-            row = self.sched.block_tables[req.slot]
-            for li in range(base // ps + 1,
-                            (base + len(drafts)) // ps + 1):
-                marked.append(int(row[li]))
-        if marked:
-            self.alloc.mark_speculative(marked)
-        if drafted:
-            _telemetry.counter("serving.spec.draft_tokens").inc(drafted)
+        with _telemetry.span("serve.decode.pack", "serving",
+                             live=len(running)):
+            tokens = _np.zeros((s, k1), _np.int32)
+            positions = _np.zeros((s, k1), _np.int32)
+            active = _np.zeros(s, _np.bool_)
+            draft_len = _np.zeros(s, _np.int32)
+            drafted = 0
+            marked = []
+            for req in running:
+                drafts = self._draft_for(req)
+                base = int(req.prompt.size) + len(req.tokens) - 1
+                tokens[req.slot, 0] = req.tokens[-1]
+                if drafts:
+                    tokens[req.slot, 1:1 + len(drafts)] = drafts
+                positions[req.slot] = base + _np.arange(k1)
+                draft_len[req.slot] = len(drafts)
+                active[req.slot] = True
+                drafted += len(drafts)
+                # pages strictly past the one holding the committed
+                # position receive ONLY draft K/V this dispatch
+                row = self.sched.block_tables[req.slot]
+                for li in range(base // ps + 1,
+                                (base + len(drafts)) // ps + 1):
+                    marked.append(int(row[li]))
+            if marked:
+                self.alloc.mark_speculative(marked)
+            if drafted:
+                _telemetry.counter("serving.spec.draft_tokens").inc(
+                    drafted)
+            args = (tokens, positions, active, draft_len,
+                    self.sched.block_tables.copy(), self._temps.copy(),
+                    self._top_ks.copy(), self._top_ps.copy(),
+                    self._keys.copy())
 
-        t0 = time.perf_counter_ns()
         try:
-            res = self._decode(
-                self._p, self._kv, tokens, positions, active,
-                draft_len, self.sched.block_tables.copy(),
-                self._temps.copy(), self._top_ks.copy(),
-                self._top_ps.copy(), self._keys.copy())
-            if self.kv_dtype == "int8":
-                logits, out, n_new, new_keys, self._kv, ok_dev = res
-            else:
-                logits, out, n_new, new_keys, self._kv = res
-                ok_dev = None
-            t1 = time.perf_counter_ns()
-            out = _np.asarray(out)           # device sync barrier
-            n_new = _np.asarray(n_new)
+            with _telemetry.stamp_span("serve_step.dispatch") as disp:
+                res = self._decode(self._p, self._kv, *args)
+                if self.kv_dtype == "int8":
+                    logits, out, n_new, new_keys, self._kv, ok_dev = res
+                else:
+                    logits, out, n_new, new_keys, self._kv = res
+                    ok_dev = None
+            with _telemetry.stamp_span("serve_step.sync") as sync:
+                out = _np.asarray(out)           # device sync barrier
+                n_new = _np.asarray(n_new)
         finally:
             # acceptance is decided the moment the dispatch returns:
             # rejected positions are masked by every later read and
@@ -1365,7 +1396,18 @@ class ServingEngine:
             # release would trip over
             if marked:
                 self.alloc.clear_speculative(marked)
-        t2 = time.perf_counter_ns()
+        with _telemetry.span("serve.decode.emit", "serving") as emit:
+            produced = self._spec_emit(
+                running, out, n_new, draft_len, new_keys, ok_dev, logits,
+                disp.t0, disp.t1, sync.t1)
+            emit.set(tokens=produced)
+        return produced
+
+    def _spec_emit(self, running, out, n_new, draft_len, new_keys, ok_dev,
+                   logits, t0, t1, t2):
+        """Commit what one verified speculative dispatch accepted:
+        keys, acceptance accounting, the step's record and ``tokens``
+        event, then every emitted token."""
         keys_prev = self._keys
         self._keys = _np.array(new_keys, _np.uint32)
         victims = ()
@@ -1425,6 +1467,9 @@ class ServingEngine:
                 produced += 1
         if victims:
             self._repair_quant_victims(victims, keys_prev)
+        if self.sched.idle:
+            _watchdog.release(self._lease)
+        self._publish_gauges()
         return produced
 
     # -- quantized-pool divergence guard (ISSUE 20) -------------------------

@@ -13,12 +13,16 @@ needs instead:
   like ``profiler.count_dispatch``: a GIL-raced increment merely
   miscounts telemetry, and the fused step budget (<1% of a ~0.3 ms CPU
   MLP step) has no room for a lock acquire per observation.
-- **span(name, cat)** — a context manager timing one named phase.  Every
-  span feeds a phase histogram (always on) and, while the profiler is
-  collecting, a chrome-tracing duration event in the same stream the
-  executor writes, so data-loading / checkpoint / kvstore phases land in
-  the same trace as ``executor_forward``.  Nested spans carry a ``depth``
-  arg so the hierarchy survives trace viewers that don't infer nesting.
+- **span(name, cat, **args)** — a context manager timing one named
+  phase.  Every span feeds a phase histogram (always on) and, while the
+  profiler is collecting, a chrome-tracing duration event in the same
+  stream the executor writes, so data-loading / checkpoint / kvstore
+  phases land in the same trace as ``executor_forward``.  Nested spans
+  carry a ``depth`` arg so the hierarchy survives trace viewers that
+  don't infer nesting.  While a ``jax.profiler`` trace is being taken a
+  span is also a ``TraceAnnotation`` in that trace: the phases of
+  ``ServingEngine.step`` and ``Module.fit_step`` lie on the device
+  trace's own clock, over the device ops they waited for.
 - **flight recorder** — a bounded ring of the last K per-step records
   (dispatch/sync wall time, dispatch/compile deltas, skipped flag, loss
   when the step has a scalar head, fault-site firings).  On an unhandled
@@ -94,7 +98,8 @@ import time
 import numpy as _np
 
 __all__ = ["Counter", "Gauge", "Histogram", "counter", "gauge",
-           "histogram", "span", "observe_phase", "report", "reset",
+           "histogram", "span", "stamp_span", "observe_phase", "report",
+           "reset",
            "note_train_step", "note_fault", "mark_last_step_verdict",
            "flight_records", "flight_capacity", "dump_postmortem",
            "start_emitter", "stop_emitter", "set_enabled", "enabled",
@@ -311,34 +316,71 @@ def _span_hist(name):
 
 # -- spans -----------------------------------------------------------------
 _tls = threading.local()
+_TraceAnnotation = None
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, bound at the first span (jax is
+    not imported with this module: telemetry stays importable from the
+    bottom of the package)."""
+    global _TraceAnnotation
+    import jax
+    _TraceAnnotation = jax.profiler.TraceAnnotation
+    return _TraceAnnotation
 
 
 class span(object):
     """Time one named phase: always feeds the phase histogram ``name``
-    (seconds), and while the profiler collects, appends a chrome-tracing
+    (seconds); while ``mx.profiler`` collects, appends a chrome-tracing
     duration event of category ``cat`` with a ``depth`` arg reflecting
-    span nesting on this thread.
+    span nesting on this thread; and while a ``jax.profiler`` trace is
+    being taken, enters a ``TraceAnnotation`` of the same name, so the
+    phase lies in the device trace's own file, on its clock, over the
+    device ops it waited for.  ``args`` (and what :meth:`set` adds before
+    the exit) ride both events.  "Tracing on" is "a trace is being
+    taken": with none, a span costs one activity check and one histogram
+    fold, and never a device sync.
 
     >>> with telemetry.span("data.batchify", cat="data"):
     ...     batch = batchify_fn(samples)
     """
 
-    __slots__ = ("name", "cat", "_t0", "_depth")
+    __slots__ = ("name", "cat", "args", "t0", "t1", "_depth", "_ann",
+                 "_fold")
 
-    def __init__(self, name, cat="phase"):
+    def __init__(self, name, cat="phase", **args):
         self.name = name
         self.cat = cat
+        self.args = args
+        self._ann = None
+        self._fold = True
+
+    def set(self, **args):
+        """Args known only inside the phase (how many were admitted)."""
+        self.args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
 
     def __enter__(self):
         self._depth = getattr(_tls, "depth", 0)
         _tls.depth = self._depth + 1
-        self._t0 = time.perf_counter_ns()
+        ann = _TraceAnnotation or _trace_annotation()
+        if ann.is_enabled():
+            self._ann = ann(self.name, **self.args)
+            self._ann.__enter__()
+        else:
+            self._ann = None
+        self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
-        t1 = time.perf_counter_ns()
+        t1 = self.t1 = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         _tls.depth = self._depth
-        dur_ns = t1 - self._t0
+        if not self._fold:
+            return False
+        dur_ns = t1 - self.t0
         if not _DISABLED:
             _span_hist(self.name).observe(dur_ns * 1e-9)
         prof = _prof or _profiler()
@@ -346,11 +388,22 @@ class span(object):
         # explicit opt-in).  Trace-origin guard: a span opened before
         # profiler_set_state("run") must not emit a pre-origin
         # (negative-ts) phantom event.
-        if prof.is_running() and self._t0 // 1000 >= (prof._t0_us or 0):
-            prof.record_event(self.name, self._t0 // 1000,
+        if prof.is_running() and self.t0 // 1000 >= (prof._t0_us or 0):
+            prof.record_event(self.name, self.t0 // 1000,
                               dur_ns // 1000, cat=self.cat,
-                              args={"depth": self._depth})
+                              args=dict(self.args, depth=self._depth))
         return False
+
+
+def stamp_span(name, **args):
+    """A :class:`span` for the ``<where>.dispatch`` / ``<where>.sync``
+    phases of a program call: it writes the profiler-clock annotation and
+    keeps its ``t0`` / ``t1`` stamps, and the caller hands those to
+    :func:`note_train_step`, which folds the histograms and flight
+    record in batches -- so each interval is timed once."""
+    s = span(name, "step", **args)
+    s._fold = False
+    return s
 
 
 def observe_phase(name, seconds):
