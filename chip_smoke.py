@@ -318,13 +318,17 @@ def kernel_vs_oracle(sz, seed):
         .reshape(s_n, mp).astype(np.int32)
     errs = {}
     for fmt in ("fp32", "bf16", "int8"):
-        kv = [rs.randn(n_pages, page, h, d).astype(np.float32)
+        # pools as the engine stores them: [num_pages, page, K_kv * D]
+        kv = [rs.randn(n_pages, page, h * d).astype(np.float32)
               for _ in range(2)]
         scales = {}
         if fmt == "int8":
-            sc = [np.abs(x).max(axis=(1, 3)) / 127.0 for x in kv]
-            kv = [jnp.asarray(np.round(x / s[:, None, :, None]),
-                              jnp.int8) for x, s in zip(kv, sc)]
+            # one absmax scale for each page and KV head
+            heads = [x.reshape(n_pages, page, h, d) for x in kv]
+            sc = [np.abs(x).max(axis=(1, 3)) / 127.0 for x in heads]
+            kv = [jnp.asarray(np.round(x / s[:, None, :, None])
+                              .reshape(n_pages, page, h * d), jnp.int8)
+                  for x, s in zip(heads, sc)]
             scales = dict(k_scales=jnp.asarray(sc[0]),
                           v_scales=jnp.asarray(sc[1]))
         else:

@@ -701,9 +701,11 @@ def _quant_scatter(pool, scales, phys, offs, rows, mask):
     """Scatter one program's K or V rows into an INT8 page pool under
     per-page-per-KV-head absmax scales.
 
-    ``phys``/``offs``: int32 [R] physical page + in-page offset per
-    row; ``rows``: fp32 [R, K_kv, D]; ``mask``: bool [R] (False rows
-    route to scratch page 0, same as the full-precision scatter).
+    ``pool``: int8 [num_pages, page_size, K_kv * D]; ``phys``/``offs``:
+    int32 [R] physical page + in-page offset per row; ``rows``: fp32
+    [R, K_kv, D]; ``mask``: bool [R] (False rows route to scratch page
+    0, same as the full-precision scatter).  Only the GATHERED pages are
+    ever viewed per KV head, never the pool.
     Scale discipline:
 
     - a page receiving a row at offset 0 is FRESH (just allocated —
@@ -735,14 +737,18 @@ def _quant_scatter(pool, scales, phys, offs, rows, mask):
     # payloads (same s_pre/s_post), so the duplicate-index scatter is
     # deterministic
     ratio = jnp.where(s_post > 0, s_pre / s_post, 0.0)
-    old = pool[tgt].astype(jnp.float32)                # [R, page, KV, D]
+    r_n, n_kv, d = rows.shape
+    old = pool[tgt].astype(jnp.float32).reshape(       # [R, page, KV, D]
+        r_n, -1, n_kv, d)
     rescaled = jnp.clip(jnp.round(old * ratio[:, None, :, None]),
                         -_KV_QMAX, _KV_QMAX)
-    p1 = pool.at[tgt].set(rescaled.astype(pool.dtype))
+    p1 = pool.at[tgt].set(
+        rescaled.reshape(r_n, -1, n_kv * d).astype(pool.dtype))
     q = jnp.clip(
         jnp.round(rows / jnp.maximum(s_post, 1e-30)[:, :, None]),
         -_KV_QMAX, _KV_QMAX)
-    return p1.at[tgt, offs].set(q.astype(pool.dtype)), s1
+    return p1.at[tgt, offs].set(
+        q.reshape(r_n, n_kv * d).astype(pool.dtype)), s1
 
 
 def _filter_logits_per_slot(logits, top_k, top_p):
@@ -809,7 +815,10 @@ def paged_decode_step(p, tokens, positions, active, kv_pages,
       attend over nothing, so occupancy changes can NEVER perturb a
       resident slot's math (bit-checked by tests);
     - ``kv_pages``: list of per-layer ``(k_pages, v_pages)``, each
-      [num_pages, page_size, K_kv, D] — donated by the caller's jit.
+      [num_pages, page_size, K_kv * D] (a token's KV heads side by side
+      on the minor axis: the one shape the chip stores, the scatter
+      writes and the paged kernel reads, see
+      ``ops/pallas/paged_attention.py``) — donated by the caller's jit.
       ``K_kv < n_heads`` is grouped-query attention: the layer dicts
       must be the matching :func:`decode_params` conversion.  Pools
       may be any float dtype (bf16 halves bytes, values cast on
@@ -866,9 +875,9 @@ def paged_decode_step(p, tokens, positions, active, kv_pages,
             kc, vc = entry
             with jax.named_scope("kv_write"):
                 kc = kc.at[phys, offs].set(
-                    k[:, :, 0, :].astype(kc.dtype))
+                    k.reshape(s_n, -1).astype(kc.dtype))
                 vc = vc.at[phys, offs].set(
-                    v[:, :, 0, :].astype(vc.dtype))
+                    v.reshape(s_n, -1).astype(vc.dtype))
             with jax.named_scope("attn"):
                 o = paged_attention(q[:, :, 0, :], kc, vc, block_tables,
                                     ctx)
@@ -1056,8 +1065,10 @@ def paged_spec_decode_step(p, tokens, positions, active, draft_len,
         else:
             kc, vc = entry
             with jax.named_scope("kv_write"):
-                kc = kc.at[phys, offs].set(kr.astype(kc.dtype))
-                vc = vc.at[phys, offs].set(vr.astype(vc.dtype))
+                kc = kc.at[phys, offs].set(
+                    kr.reshape(s_n, k1, -1).astype(kc.dtype))
+                vc = vc.at[phys, offs].set(
+                    vr.reshape(s_n, k1, -1).astype(vc.dtype))
             with jax.named_scope("attn"):
                 o = paged_attention_multi(q.transpose(0, 2, 1, 3), kc,
                                           vc, block_tables, ctx)
@@ -1131,7 +1142,7 @@ def _prefill_rows(p, tokens, prompt_len, prefix_len, prefix_kv, n_heads):
     """Compute half of an admission: one batched causal pass over the
     (padded) suffix tokens, touching no page pool.  With ``prefix_kv``
     (a cache HIT: per layer the slot's pages gathered through its block
-    table, fp32 ``[mp, page, K_kv, D]``) the suffix queries attend over
+    table, fp32 ``[mp, page, K_kv * D]``) the suffix queries attend over
     the cached prefix, masked at ``prefix_len``, PLUS the causal window
     of the suffix itself, in one joint softmax; with ``None`` (a MISS,
     ``prefix_len == 0``) over the causal window alone.  Returns
@@ -1165,7 +1176,7 @@ def _prefill_rows(p, tokens, prompt_len, prefix_len, prefix_kv, n_heads):
                            jnp.einsum("bhqd,bhkd->bhqk", q, kd) / scale,
                            -1e30)
             if prefix_kv is not None:
-                # cached prefix K/V: [mp, page, K_kv, D] -> [1, H, t_ctx, D]
+                # cached prefix K/V: [mp, page, K_kv * D] -> [1, H, t_ctx, D]
                 kp, vp = (_bcast_kv(a.reshape(t_ctx, -1, d)
                                     .transpose(1, 0, 2)[None], n_heads)
                           for a in prefix_kv[i])
@@ -1245,14 +1256,18 @@ def paged_prefill(p, tokens, prompt_len, prefix_len, block_table_row,
     # dequantizes identically to its donor
     kv_pages = [tuple(a.at[cow_dst].set(a[cow_src]) for a in entry)
                 for entry in kv_pages]
-    prefix_kv = []
-    for entry in kv_pages:
-        kg = entry[0][block_table_row].astype(jnp.float32)
-        vg = entry[1][block_table_row].astype(jnp.float32)
-        if quantized:
-            kg = kg * entry[2][block_table_row][:, None, :, None]
-            vg = vg * entry[3][block_table_row][:, None, :, None]
-        prefix_kv.append((kg, vg))
+    from ...ops.pallas.paged_attention import dequant_pages
+
+    def gathered(pool, scales=None):
+        # the slot's pages, fp32 [mp, page, K_kv * D]
+        pages = pool[block_table_row]
+        if scales is None:
+            return pages.astype(jnp.float32)
+        return dequant_pages(pages, scales[block_table_row])
+
+    # an entry is (k, v) or (k, v, k_scales, v_scales)
+    prefix_kv = [(gathered(*entry[0::2]), gathered(*entry[1::2]))
+                 for entry in kv_pages]
     h, rows = lax.cond(
         prefix_len > 0,
         lambda: _prefill_rows(p, tokens, prompt_len, prefix_len,
@@ -1279,8 +1294,10 @@ def paged_prefill(p, tokens, prompt_len, prefix_len, block_table_row,
             else:
                 kc, vc = entry
                 new_pages.append(
-                    (kc.at[phys, offs].set(k.astype(kc.dtype)),
-                     vc.at[phys, offs].set(v.astype(vc.dtype))))
+                    (kc.at[phys, offs].set(
+                        k.reshape(t_pad, -1).astype(kc.dtype)),
+                     vc.at[phys, offs].set(
+                        v.reshape(t_pad, -1).astype(vc.dtype))))
     with jax.named_scope("lm_head"):
         last = lax.dynamic_index_in_dim(h, suffix_len - 1, 0,
                                         keepdims=False)

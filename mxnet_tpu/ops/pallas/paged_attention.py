@@ -2,11 +2,27 @@
 
 The serving runtime (mxnet_tpu/serving/) keeps every resident sequence's
 KV history in fixed-size PAGES drawn from one shared pool
-(``k_pages``/``v_pages``: [num_pages, page_size, K_kv, D]) with a
-per-sequence BLOCK TABLE mapping logical page index -> physical page id
-— the vLLM/"Ragged Paged Attention" memory model (PAPERS.md, arXiv
+(``k_pages``/``v_pages``: [num_pages, page_size, K_kv * D], a token's
+KV heads side by side on the minor axis) with a per-sequence BLOCK
+TABLE mapping logical page index -> physical page id — the
+vLLM/"Ragged Paged Attention" memory model (PAPERS.md, arXiv
 2604.15464) that lets mixed-length sequences share one kernel launch
 with zero padding waste beyond the last partial page.
+
+Why the pools are stored flat: the TPU tiles the two minor dimensions
+(8 sublanes x 128 lanes for 32-bit, 16 x 128 for bf16, 32 x 128 for
+int8).  With ``K_kv * D`` a multiple of 128 the default device layout of
+``[num_pages, page_size, K_kv * D]`` is row-major with no lane padding:
+the layout the chip keeps between programs, the one the ``kv_write``
+scatters and page gathers address, and the one this kernel's page block
+reads are the same, and a donated pool is updated in place.  A pool
+whose minor dimension is one head of ``D`` 64 gets a pages-minor-most
+layout instead (half of every lane tile would be padding), and every
+program then converts each whole pool to row-major and back around its
+scatter and this kernel.  Where ``K_kv * D`` is NOT
+a multiple of 128 (multi-query attention at ``D`` 64) the flat shape is
+still correct, but the compiler again keeps the pool pages-minor and
+the layout copies return; there is no second path for that case.
 
 Kernel shape (one launch serves ALL resident slots, any lengths):
 
@@ -92,6 +108,7 @@ def _paged_kernel(ctx_ref, bt_ref, q_ref, k_ref, v_ref, *rest,
     j = pl.program_id(1)
     nj = pl.num_programs(1)
     rows = n_q * g
+    d = q_ref.shape[-1]
     ctxs = [ctx_ref[s, i] for i in range(n_q)]
     ctx_max = functools.reduce(jnp.maximum, ctxs)
 
@@ -115,8 +132,9 @@ def _paged_kernel(ctx_ref, bt_ref, q_ref, k_ref, v_ref, *rest,
         mask = pos < ctx_rows
         for kv in range(n_kv):
             q = q_ref[0, kv].astype(jnp.float32) * scale   # (rows, D)
-            k = k_ref[0, :, kv, :].astype(jnp.float32)     # (page, D)
-            v = v_ref[0, :, kv, :].astype(jnp.float32)     # (page, D)
+            # KV head `kv` is a static lane slice of the flat page row
+            k = k_ref[0, :, kv * d:(kv + 1) * d].astype(jnp.float32)
+            v = v_ref[0, :, kv * d:(kv + 1) * d].astype(jnp.float32)
             if quantized:
                 k = k * ks_ref[0, kv, j]
                 v = v * vs_ref[0, kv, j]
@@ -143,13 +161,28 @@ def _paged_kernel(ctx_ref, bt_ref, q_ref, k_ref, v_ref, *rest,
         o_ref[0] = (o_acc[...] / l_safe).astype(o_ref.dtype)
 
 
-def _check_scales(k_pages, k_scales, v_scales):
+def _kv_heads(k_pages, h, d):
+    """``K_kv`` of a flat ``[num_pages, page_size, K_kv * D]`` pool read
+    by ``h`` query heads of size ``d``."""
+    if k_pages.ndim != 3 or k_pages.shape[2] % d:
+        raise ValueError(
+            "page pools must be [num_pages, page_size, K_kv * D] with "
+            "D = %d, got %r" % (d, tuple(k_pages.shape)))
+    n_kv = k_pages.shape[2] // d
+    if h % n_kv:
+        raise ValueError(
+            "query heads (%d) must be a multiple of KV heads (%d)"
+            % (h, n_kv))
+    return n_kv
+
+
+def _check_scales(k_pages, n_kv, k_scales, v_scales):
     """Both scale pools or neither; shape must be [num_pages, K_kv]."""
     if (k_scales is None) != (v_scales is None):
         raise ValueError("k_scales and v_scales must be given together")
     if k_scales is None:
         return False
-    want = (k_pages.shape[0], k_pages.shape[2])
+    want = (k_pages.shape[0], n_kv)
     for name, s in (("k_scales", k_scales), ("v_scales", v_scales)):
         if tuple(s.shape) != want:
             raise ValueError(
@@ -168,12 +201,13 @@ def paged_attention_multi(q, k_pages, v_pages, block_tables,
     - ``q``: [S, G, H, D] — G query positions per slot (the last
       emitted token plus the draft tokens, already scattered into the
       pages this step);
-    - ``k_pages``/``v_pages``: [num_pages, page_size, K_kv, D] — the
-      shared physical page pools (page 0 is the serving allocator's
+    - ``k_pages``/``v_pages``: [num_pages, page_size, K_kv * D] — the
+      shared physical page pools, KV head ``kv`` of a token in lanes
+      ``kv * D:(kv + 1) * D`` (page 0 is the serving allocator's
       scratch page, never referenced by an in-range block-table entry).
-      ``K_kv`` must divide ``H``; each KV head serves a contiguous
-      group of ``H // K_kv`` query heads (GQA; ``K_kv == H`` is classic
-      multi-head, ``K_kv == 1`` multi-query);
+      ``K_kv`` (the pool's width over ``D``) must divide ``H``; each KV
+      head serves a contiguous group of ``H // K_kv`` query heads (GQA;
+      ``K_kv == H`` is classic multi-head, ``K_kv == 1`` multi-query);
     - ``block_tables``: int32 [S, max_pages_per_seq] — logical page j of
       slot s lives in physical page ``block_tables[s, j]``;
     - ``context_lens``: int32 [S, G] — per-POSITION context length
@@ -193,14 +227,10 @@ def paged_attention_multi(q, k_pages, v_pages, block_tables,
     from jax.experimental.pallas import tpu as pltpu
     s_n, n_q, h, d = q.shape
     page_size = k_pages.shape[1]
-    n_kv = k_pages.shape[2]
-    if h % n_kv:
-        raise ValueError(
-            "query heads (%d) must be a multiple of KV heads (%d)"
-            % (h, n_kv))
+    n_kv = _kv_heads(k_pages, h, d)
     g = h // n_kv
     rows = n_q * g
-    quantized = _check_scales(k_pages, k_scales, v_scales)
+    quantized = _check_scales(k_pages, n_kv, k_scales, v_scales)
     max_pages = block_tables.shape[1]
     if scale is None:
         scale = d ** -0.5
@@ -219,7 +249,7 @@ def paged_attention_multi(q, k_pages, v_pages, block_tables,
     q_spec = pl.BlockSpec((1, n_kv, rows, d),
                           lambda s, j, c, b: (s, 0, 0, 0))
     page_spec = pl.BlockSpec(
-        (1, page_size, n_kv, d), lambda s, j, c, b: (b[s, j], 0, 0, 0))
+        (1, page_size, n_kv * d), lambda s, j, c, b: (b[s, j], 0, 0))
     in_specs = [q_spec, page_spec, page_spec]
     args = [ctx, bt, qk, k_pages, v_pages]
     if quantized:
@@ -272,14 +302,22 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
         scale=scale, k_scales=k_scales, v_scales=v_scales)[:, 0]
 
 
-def _dequant_pools(k_pages, v_pages, k_scales, v_scales):
-    """fp32 pools for the oracles: broadcast each page's per-KV-head
-    scale over its (page_size, D) payload."""
-    if _check_scales(k_pages, k_scales, v_scales):
-        k_pages = k_pages.astype(jnp.float32) * \
-            k_scales[:, None, :, None]
-        v_pages = v_pages.astype(jnp.float32) * \
-            v_scales[:, None, :, None]
+def dequant_pages(pages, scales):
+    """fp32 values of quantized pages ``[N, page_size, K_kv * D]`` under
+    their ``[N, K_kv]`` scales: each page's per-KV-head scale broadcast
+    over that head's (page_size, D) payload.  For the jnp oracles (whole
+    pools) and the prefill's gathered prefix pages."""
+    n, page_size, width = pages.shape
+    heads = pages.astype(jnp.float32).reshape(
+        n, page_size, scales.shape[1], -1)
+    return (heads * scales[:, None, :, None]).reshape(n, page_size, width)
+
+
+def _dequant_pools(k_pages, v_pages, n_kv, k_scales, v_scales):
+    """fp32 pools for the oracles."""
+    if _check_scales(k_pages, n_kv, k_scales, v_scales):
+        k_pages = dequant_pages(k_pages, k_scales)
+        v_pages = dequant_pages(v_pages, v_scales)
     return k_pages, v_pages
 
 
@@ -291,12 +329,12 @@ def paged_attention_multi_reference(q, k_pages, v_pages, block_tables,
     come back zero (the kernel's empty-row contract)."""
     s_n, n_q, h, d = q.shape
     page_size = k_pages.shape[1]
-    n_kv = k_pages.shape[2]
+    n_kv = _kv_heads(k_pages, h, d)
     g = h // n_kv
     max_pages = block_tables.shape[1]
     if scale is None:
         scale = d ** -0.5
-    k_pages, v_pages = _dequant_pools(k_pages, v_pages,
+    k_pages, v_pages = _dequant_pools(k_pages, v_pages, n_kv,
                                       k_scales, v_scales)
     bt = jnp.asarray(block_tables, jnp.int32)
     ctx = jnp.asarray(context_lens, jnp.int32)
@@ -325,16 +363,16 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables,
     on the densely-packed equivalent."""
     s_n, h, d = q.shape
     page_size = k_pages.shape[1]
-    n_kv = k_pages.shape[2]
+    n_kv = _kv_heads(k_pages, h, d)
     g = h // n_kv
     max_pages = block_tables.shape[1]
     if scale is None:
         scale = d ** -0.5
-    k_pages, v_pages = _dequant_pools(k_pages, v_pages,
+    k_pages, v_pages = _dequant_pools(k_pages, v_pages, n_kv,
                                       k_scales, v_scales)
     bt = jnp.asarray(block_tables, jnp.int32)
     ctx = jnp.asarray(context_lens, jnp.int32)
-    # [S, max_pages, page, K_kv, D] -> [S, T_max, K_kv, D]
+    # [S, max_pages, page, K_kv * D] -> [S, T_max, K_kv, D]
     k_seq = k_pages[bt].reshape(s_n, max_pages * page_size, n_kv, d)
     v_seq = v_pages[bt].reshape(s_n, max_pages * page_size, n_kv, d)
     if g > 1:

@@ -13,6 +13,14 @@ prefill dispatch (static padded prompt shape, traced length — no
 per-length recompiles) and leave by releasing pages; occupancy is a
 mask, never a shape, so request churn causes ZERO recompiles.
 
+The KV page pools are ``[num_pages, page_size, K_kv * D]`` per layer
+(``_init_pages``): with ``K_kv * D`` a multiple of 128 the TPU keeps
+that shape row-major with no lane padding, so the stored layout, the
+programs' scatters and the paged kernel's page block agree and a
+donated pool is updated in place (SERVING.md §2; a width that is no
+multiple of 128, multi-query attention at ``D`` 64, keeps a layout
+copy).
+
 Donation discipline (ROBUSTNESS.md §8): the KV page pools are donated
 every step, so
 
@@ -86,7 +94,7 @@ Capacity multipliers (ISSUE 15):
   ``serving.prefix.{hits,miss,shared_pages,cow_copies,evictions}`` +
   ``serving.prefill_tokens`` (logical tokens prefilled);
 - **grouped-query attention** (``kv_heads=`` / ``MXTPU_SERVE_KV_HEADS``)
-  — page pools shaped ``[num_pages, page_size, K_kv, D]`` with
+  — page pools shaped ``[num_pages, page_size, K_kv * D]`` with
   ``K_kv <= H`` (decode_params mean-pools the K/V projections), so KV
   bytes per resident token shrink ``H / K_kv``-fold and the same pool
   bytes hold proportionally more sequences;
@@ -451,8 +459,12 @@ class ServingEngine:
         import jax
         import jax.numpy as jnp
 
-        shape = (self.alloc.num_pages, self.page_size, self.kv_heads,
-                 self._head_dim)
+        # a token's KV heads side by side on the minor axis: with
+        # K_kv * D a multiple of 128 the chip keeps this row-major with
+        # no lane padding, which is what the programs' scatters and the
+        # paged kernel address (ops/pallas/paged_attention.py)
+        shape = (self.alloc.num_pages, self.page_size,
+                 self.kv_heads * self._head_dim)
         if self.kv_dtype == "int8":
             # int8 payload + per-page-per-KV-head fp32 absmax scales
             # (gpt._quant_scatter resets a fresh page's scale before

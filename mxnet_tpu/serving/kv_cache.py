@@ -1,9 +1,12 @@
 """Paged KV-cache allocator: fixed-size blocks, block tables, free-list,
 per-page refcounts.
 
-The device-side page pools (``[num_pages, page_size, K_kv, D]`` per
-layer, owned by the serving engine and donated through every decode
-step) are dumb storage; THIS object is the authority over which
+The device-side page pools (``[num_pages, page_size, K_kv * D]`` per
+layer: a token's KV heads side by side, so that with ``K_kv * D`` a
+multiple of 128 the chip keeps them row-major with no lane padding,
+the layout the programs' scatters and the paged kernel address; owned
+by the serving engine and donated through every decode step) are dumb
+storage; THIS object is the authority over which
 physical page belongs to whom.  Design follows the vLLM/"Ragged Paged
 Attention" memory model (PAPERS.md, arXiv 2604.15464):
 

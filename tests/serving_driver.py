@@ -52,10 +52,16 @@ def _ref(net, prompt, max_new):
 
 # -- kernel section --------------------------------------------------------
 
+def _pool(rng, n_pages, page, kv, d):
+    """One page pool as the engine stores it: a token's ``kv`` heads
+    of ``d`` side by side, ``[num_pages, page, K_kv * D]``."""
+    return rng.randn(n_pages, page, kv * d).astype(np.float32)
+
+
 def _paged_setup(rng, s, h, d, page, n_pages, mp, ctx_lens):
     q = rng.randn(s, h, d).astype(np.float32)
-    kp = rng.randn(n_pages, page, h, d).astype(np.float32)
-    vp = rng.randn(n_pages, page, h, d).astype(np.float32)
+    kp = _pool(rng, n_pages, page, h, d)
+    vp = _pool(rng, n_pages, page, h, d)
     # distinct physical pages per slot, deliberately non-contiguous
     perm = rng.permutation(n_pages - 1) + 1
     bt = np.zeros((s, mp), np.int32)
@@ -104,8 +110,10 @@ def check_kernel_vs_dense_flash():
                                       ctx_lens)
     out = np.asarray(paged_attention(q, kp, vp, bt, ctx))
     for i, L in enumerate(ctx_lens):
-        ks = np.concatenate([kp[p] for p in bt[i]], axis=0)[:L]
-        vs = np.concatenate([vp[p] for p in bt[i]], axis=0)[:L]
+        ks = np.concatenate([kp[p] for p in bt[i]],
+                            axis=0)[:L].reshape(L, h, d)
+        vs = np.concatenate([vp[p] for p in bt[i]],
+                            axis=0)[:L].reshape(L, h, d)
         kd = jnp.asarray(ks.transpose(1, 0, 2)[None])
         vd = jnp.asarray(vs.transpose(1, 0, 2)[None])
         qd = jnp.asarray(q[i][None, :, None, :])        # [1, H, 1, D]
@@ -137,8 +145,8 @@ def check_kernel_multi_vs_reference():
             (2, 4, 1, 8, 4, 12, 4, 3,
              [[7, 8, 9], [15, 16, 0]])):
         q = rng.randn(s, n_q, h, d).astype(np.float32)
-        kp = rng.randn(n_pages, page, kv, d).astype(np.float32)
-        vp = rng.randn(n_pages, page, kv, d).astype(np.float32)
+        kp = _pool(rng, n_pages, page, kv, d)
+        vp = _pool(rng, n_pages, page, kv, d)
         perm = rng.permutation(n_pages - 1) + 1
         bt = np.zeros((s, mp), np.int32)
         k = 0
@@ -317,8 +325,8 @@ def check_kernel_gqa_vs_reference():
             (3, 4, 1, 8, 4, 12, 4, [13, 0, 16]),
             (2, 6, 3, 16, 8, 10, 2, [9, 16])):
         q = rng.randn(s, h, d).astype(np.float32)
-        kp = rng.randn(n_pages, page, kv, d).astype(np.float32)
-        vp = rng.randn(n_pages, page, kv, d).astype(np.float32)
+        kp = _pool(rng, n_pages, page, kv, d)
+        vp = _pool(rng, n_pages, page, kv, d)
         perm = rng.permutation(n_pages - 1) + 1
         bt = np.zeros((s, mp), np.int32)
         k = 0
@@ -343,7 +351,7 @@ def check_gqa_engine_self_consistent(net):
     others = [rng.randint(0, VOCAB, (l,)).astype(np.int32)
               for l in (9, 2, 13)]
     solo = _engine(net, kv_heads=1, record_logits=True)
-    assert solo._kv[0][0].shape[2] == 1
+    assert solo._kv[0][0].shape[2] == solo._head_dim      # K_kv 1
     ra = solo.submit(prompt_a, 8)
     solo.run_until_idle()
     churn = _engine(net, kv_heads=1, record_logits=True)

@@ -94,7 +94,7 @@ def test_paged_kernel_compiles(chip, kv_dtype, n_q, kv_heads):
     dt = {"fp32": jnp.float32, "bf16": jnp.bfloat16,
           "int8": jnp.int8}[kv_dtype]
     q = chip((SLOTS, n_q, HEADS, HEAD_DIM), jnp.float32)
-    pool = chip((PAGES, PAGE, kv_heads, HEAD_DIM), dt)
+    pool = chip((PAGES, PAGE, kv_heads * HEAD_DIM), dt)
     tables = chip((SLOTS, PAGES_PER_SEQ), jnp.int32)
     ctx = chip((SLOTS, n_q), jnp.int32)
     if kv_dtype == "int8":
@@ -106,6 +106,97 @@ def test_paged_kernel_compiles(chip, kv_dtype, n_q, kv_heads):
     else:
         compile_for_chip(paged.paged_attention_multi, q, pool, pool,
                          tables, ctx)
+
+
+# -- the engine's own programs: the pools keep one layout -----------------
+
+_ENGINE_PROGRAMS = {}
+
+
+def _engine_programs(kv_dtype, spec_k, monkeypatch):
+    """``{name: (fn, example shapes)}`` of a ``ServingEngine``'s decode
+    and prefill programs at GPT-2-medium widths, as the engine hands
+    them to its compiler: ``_compile`` is replaced by a recorder, so
+    nothing is compiled for the CPU.  Two layers, a small vocabulary
+    and a 128-token prefill keep a program's activations under one int8
+    pool, so only a pool-sized temporary can break the bound below."""
+    if (kv_dtype, spec_k) not in _ENGINE_PROGRAMS:
+        from mxnet_tpu.gluon.model_zoo import gpt
+        from mxnet_tpu.serving import ServingEngine
+        got = {}
+        monkeypatch.setattr(
+            ServingEngine, "_compile",
+            lambda self, name, fn, examples, extra:
+            got.__setitem__(name, (fn, examples)))
+        net = gpt.get_gpt(2, HEADS * HEAD_DIM, HEADS, vocab_size=512,
+                          max_len=1024 + spec_k)
+        net.initialize()
+        eng = ServingEngine(net, num_slots=SLOTS, page_size=PAGE,
+                            num_pages=PAGES, max_prefill_len=128,
+                            max_seq_len=1024, spec_k=spec_k,
+                            kv_dtype=kv_dtype)
+        assert eng._kv[0][0].shape == (PAGES, PAGE, HEADS * HEAD_DIM)
+        _ENGINE_PROGRAMS[kv_dtype, spec_k] = got
+    return _ENGINE_PROGRAMS[kv_dtype, spec_k]
+
+
+def _elements(shape_text):
+    return int(np.prod([int(n) for n in shape_text.split(",") if n]))
+
+
+@pytest.mark.parametrize("program", ["decode", "spec_decode", "prefill"])
+@pytest.mark.parametrize("kv_dtype", ["bf16", "fp32", "int8"])
+def test_engine_program_keeps_the_pools_in_one_layout(
+        chip, monkeypatch, kv_dtype, program):
+    """The layout the chip keeps a ``[num_pages, page, K_kv * D]`` pool
+    in between programs is the one the scatter writes and the paged
+    kernel reads: row-major, every pool updated in place, no pool-sized
+    ``copy`` and no pool-sized temporary.  (A pool with a KV-head axis
+    of its own before a D of 64 is kept pages-minor-most; each program
+    then converted every pool to row-major and back, 85% of the serving
+    cells' device time before PR 25.)"""
+    import re
+    fn, examples = _engine_programs(
+        kv_dtype, 4 if program == "spec_decode" else 0, monkeypatch)[
+            "prefill" if program == "prefill" else "decode"]
+    pools = jax.tree_util.tree_leaves(examples[1])
+    # matmuls as the chip runs them: the suite's fp32 precision is for
+    # numeric checks on the CPU, and here it would hold every weight a
+    # second time, split into bf16 parts
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+            *jax.tree_util.tree_map(lambda a: chip(a.shape, a.dtype),
+                                    examples)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text or program == "prefill"
+    pool_elements = PAGES * PAGE * HEADS * HEAD_DIM
+    # a layout conversion is a `copy`, alone or as the root of a fusion
+    # the compiler names after it.  `copy-start` / `copy-done` move an
+    # array between memory spaces and keep its layout: the compiler
+    # prefetches this test's 33 MB int8 pools into VMEM at spec_k 4,
+    # which a deployment's pool (94 MB at 5,711 pages) is too large for
+    copies = [line.strip()[:160] for line in text.splitlines()
+              for m in [re.match(r"\s*(?:ROOT )?%(\S+) = \(?\w+"
+                                 r"\[([\d,]*)\]\S* ([\w-]+)\(", line)]
+              if m and m.group(3) not in ("copy-start", "copy-done")
+              and (m.group(3) == "copy" or m.group(1).startswith("copy"))
+              and _elements(m.group(2)) >= pool_elements]
+    assert not copies, copies
+    # wherever the program names an array of a pool's shape (arguments,
+    # results, every instruction between), it is row-major
+    layouts = re.findall(r"\w+\[%d,%d,%d\](\{[^}]*\})"
+                         % (PAGES, PAGE, HEADS * HEAD_DIM), text)
+    header = text.split("\n", 1)[0]
+    assert header.count("[%d,%d,%d]{2,1,0" % (PAGES, PAGE,
+                                              HEADS * HEAD_DIM)) \
+        == 2 * len([a for a in pools if a.ndim == 3]), header[:400]
+    assert layouts and all(l.startswith("{2,1,0") for l in layouts), \
+        sorted(set(layouts))
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes \
+        < pool_elements * pools[0].dtype.itemsize, mem
+    assert mem.alias_size_in_bytes == sum(
+        int(np.prod(a.shape)) * a.dtype.itemsize for a in pools), mem
 
 
 @pytest.mark.parametrize("head_dim,packed", [(64, False), (64, True),

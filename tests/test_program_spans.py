@@ -349,7 +349,7 @@ def _kernel_jaxpr(kernel, monkeypatch):
     if kernel == "paged_decode":
         rs = np.random.RandomState(0)
         q = jnp.asarray(rs.randn(2, 2, 16), jnp.float32)
-        pages = jnp.asarray(rs.randn(6, 8, 2, 16), jnp.float32)
+        pages = jnp.asarray(rs.randn(6, 8, 2 * 16), jnp.float32)
         tables = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
         return jax.make_jaxpr(lambda q, kp, vp: paged.paged_attention(
             q, kp, vp, tables, jnp.asarray([9, 5], jnp.int32)))(

@@ -214,6 +214,55 @@ def test_paged_attention_kernel():
     assert "SERVING_KERNEL_OK" in _run_driver("kernel")
 
 
+@pytest.mark.parametrize("case", ["mha_decode", "gqa_verify"])
+@pytest.mark.parametrize("kv_dtype", ["fp32", "bf16"])
+def test_paged_kernel_bit_identical_to_the_4d_pool_kernel(kv_dtype, case):
+    """The kernel on the stored ``[num_pages, page, K_kv * D]`` pools is
+    the kernel the parent of PR 25 ran on pools with a KV-head axis of
+    their own: same mathematics, same op order for each head, fp32
+    accumulation.  ``tests/data/paged_kernel_parent.npz`` holds inputs
+    and outputs of commit be26b86's ``paged_attention_multi`` under the
+    interpreter in this suite's configuration (x64 on, fp32 matmuls):
+    one query position per slot at ``K_kv == H`` with an empty slot, and
+    four with per-position contexts at ``K_kv == H / 2``.  The pools it
+    read are kept in the stored shape (the same values, heads side by
+    side), bf16 ones as their bits."""
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.pallas.paged_attention import (
+        paged_attention, paged_attention_multi)
+    with np.load(os.path.join(REPO, "tests", "data",
+                              "paged_kernel_parent.npz")) as z:
+        prefix = "%s.%s." % (kv_dtype, case)
+        rec = {k[len(prefix):]: z[k] for k in z.files
+               if k.startswith(prefix)}
+
+    kp, vp = (jnp.asarray(rec[k].view(jnp.bfloat16) if kv_dtype == "bf16"
+                          else rec[k]) for k in ("k_pages", "v_pages"))
+    assert kp.ndim == 3
+    out = np.asarray(paged_attention_multi(
+        rec["q"], kp, vp, rec["block_tables"], rec["context_lens"]))
+    assert out.dtype == rec["out"].dtype
+    assert out.tobytes() == rec["out"].tobytes()
+    if rec["q"].shape[1] == 1:
+        one = np.asarray(paged_attention(
+            rec["q"][:, 0], kp, vp, rec["block_tables"],
+            rec["context_lens"][:, 0]))
+        assert one.tobytes() == rec["out"][:, 0].tobytes()
+
+
+def test_paged_kernel_refuses_a_pool_that_is_not_flat():
+    from mxnet_tpu.ops.pallas.paged_attention import (
+        paged_attention, paged_attention_reference)
+    q = np.zeros((2, 4, 16), np.float32)
+    tables, ctx = np.ones((2, 2), np.int32), np.ones(2, np.int32)
+    for pool in (np.zeros((4, 8, 4, 16), np.float32),    # 4-D
+                 np.zeros((4, 8, 40), np.float32),       # not k * D
+                 np.zeros((4, 8, 3 * 16), np.float32)):  # 4 % 3 heads
+        for fn in (paged_attention, paged_attention_reference):
+            with pytest.raises(ValueError):
+                fn(q, pool, pool, tables, ctx)
+
+
 def test_serving_engine_invariants():
     """Engine == dense generate at mixed lengths (greedy-vs-today
     bit-identity, prefix cache at its default ON); EOS early-leave;

@@ -583,8 +583,9 @@ def run_gqa(net, pool_pages=13):
     s, d, page, n_pages, mp = 5, 16, 8, 16, 4
     kv = n_heads // 2
     q = rng.randn(s, n_heads, d).astype(np.float32)
-    kp = rng.randn(n_pages, page, kv, d).astype(np.float32)
-    vp = rng.randn(n_pages, page, kv, d).astype(np.float32)
+    # pools as the engine stores them: [num_pages, page, K_kv * D]
+    kp = rng.randn(n_pages, page, kv * d).astype(np.float32)
+    vp = rng.randn(n_pages, page, kv * d).astype(np.float32)
     perm = rng.permutation(n_pages - 1) + 1
     ctx_lens = [29, 5, 0, 17, 32]
     bt = np.zeros((s, mp), np.int32)
@@ -663,16 +664,18 @@ def run_kvq(net, workload, reference_tokens, pool_pages=13):
     q = rng.randn(s, n_heads, d).astype(np.float32)
 
     def quantize(pool):
-        scale = (np.abs(pool).max(axis=(1, 3)) / 127.0).astype(
+        # a stored [n_pages, page, K_kv * D] pool, scaled per KV head
+        heads = pool.reshape(n_pages, page, n_heads, d)
+        scale = (np.abs(heads).max(axis=(1, 3)) / 127.0).astype(
             np.float32)                      # [n_pages, K_kv]
         qp = np.clip(np.round(
-            pool / np.maximum(scale, 1e-30)[:, None, :, None]),
+            heads / np.maximum(scale, 1e-30)[:, None, :, None]),
             -127, 127).astype(np.int8)
-        return qp, scale
+        return qp.reshape(pool.shape), scale
 
-    kq, ks = quantize(rng.randn(n_pages, page, n_heads, d)
+    kq, ks = quantize(rng.randn(n_pages, page, n_heads * d)
                       .astype(np.float32))
-    vq, vs = quantize(rng.randn(n_pages, page, n_heads, d)
+    vq, vs = quantize(rng.randn(n_pages, page, n_heads * d)
                       .astype(np.float32))
     perm = rng.permutation(n_pages - 1) + 1
     ctx_lens = [29, 5, 0, 17, 32]
