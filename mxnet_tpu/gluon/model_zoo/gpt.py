@@ -210,6 +210,17 @@ class GPTLM(HybridBlock):
             blk._cached_op = None
         self._cached_op = None
 
+    def serving_programs(self):
+        """What ``ServingEngine`` needs of a model, in one object: this
+        module's paged programs over K/V page pools in every layer."""
+        from ...serving.programs import ServingPrograms
+        return ServingPrograms(
+            n_heads=self.blocks._children[0].attn._num_heads,
+            max_len=self._max_len, decode_params=decode_params,
+            decode_step=paged_decode_step,
+            spec_decode_step=paged_spec_decode_step,
+            prefill=paged_prefill)
+
     def hybrid_forward(self, F, tokens, segments=None, wte=None,
                        wpe=None):
         t = tokens.shape[1]

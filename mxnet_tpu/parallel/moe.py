@@ -6,6 +6,13 @@ dispatch: top-k gating with a capacity bound produces a dispatch tensor,
 one all_to_all moves token slots to their expert's device, each device runs
 its local experts as one batched matmul (MXU-friendly — no gather loops),
 and a second all_to_all brings results home for the weighted combine.
+
+Beside it, the expert layer of a SHARE (:func:`grouped_topk_route`,
+:func:`held_experts_ffn`): top-k over ALL experts with no capacity and
+no dropped token, computed for the contiguous range of experts this
+chip holds, at a cost that follows the tokens each held expert got.  It
+is what expert parallelism asks of one chip; the exchange that would
+bring other chips' tokens here is not part of it.
 """
 from __future__ import annotations
 
@@ -146,3 +153,128 @@ class MoELayer:
         return moe_apply(x, params["gate_w"], params["w1"], params["b1"],
                          params["w2"], params["b2"], mesh=mesh, axis=axis,
                          capacity_factor=self.capacity_factor)
+
+
+# ---------------------------------------------------------------------------
+# the share of one chip: route over all experts, compute the held ones
+# ---------------------------------------------------------------------------
+
+def grouped_topk_route(x, router_w, router_b, n_group, topk_group, k,
+                       scale):
+    """Sigmoid router with group-limited top-k (DeepSeek-V3 form).
+
+    ``x`` [T, C]; ``router_w`` [C, E]; ``router_b`` [E] is the expert
+    bias that steers SELECTION only.  Scores ``s = sigmoid(x W)`` in
+    float32; the ``E`` experts form ``n_group`` equal groups, a group's
+    score is the sum of its top 2 ``s + b``, the best ``topk_group``
+    groups are kept and the top ``k`` experts taken inside them.  The
+    weights are ``s`` (without ``b``) of the chosen, normalised to sum
+    1, times ``scale``.
+
+    Returns ``(experts int32 [T, k], weights float32 [T, k])``.  No
+    token is dropped: every token gets exactly ``k`` experts whatever
+    the imbalance.
+    """
+    t = x.shape[0]
+    e = router_w.shape[1]
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))
+    sel = scores + router_b.astype(jnp.float32)
+    per = sel.reshape(t, n_group, e // n_group)
+    group_score = lax.top_k(per, 2)[0].sum(-1)              # [T, G]
+    kept = jnp.zeros((t, n_group), bool).at[
+        jnp.arange(t)[:, None],
+        lax.top_k(group_score, topk_group)[1]].set(True)
+    masked = jnp.where(kept[:, :, None], per, -jnp.inf).reshape(t, e)
+    experts = lax.top_k(masked, k)[1].astype(jnp.int32)
+    w = jnp.take_along_axis(scores, experts, axis=-1)
+    return experts, w / w.sum(-1, keepdims=True) * scale
+
+
+def expert_tiles(local_expert, num_held, tile_rows):
+    """Lay ``M`` assignments out for :func:`moe_gmm`: sorted by held
+    expert, each expert's rows padded to whole tiles of ``tile_rows``.
+
+    ``local_expert`` int32 [M]: the held expert's index, or ``num_held``
+    for an assignment that goes to an expert held elsewhere (it gets no
+    row).  Returns ``(dest [M] — the assignment's row, ``rows`` for
+    none; tile_expert [rows // tile_rows]; n_valid [1]; counts
+    [num_held]; rows)`` with ``rows`` the static size of the layout,
+    enough for every assignment to land on one expert.
+    """
+    m = local_expert.shape[0]
+    rows = -(-(m + num_held * (tile_rows - 1)) // tile_rows) * tile_rows
+    counts = jnp.zeros(num_held + 1, jnp.int32).at[local_expert].add(1)
+    counts = counts[:num_held]
+    padded = -(-counts // tile_rows) * tile_rows
+    tile_end = jnp.cumsum(padded) // tile_rows              # [E]
+    order = jnp.argsort(local_expert, stable=True)
+    sorted_e = local_expert[order]
+    at = jnp.minimum(sorted_e, num_held - 1)
+    rank = jnp.arange(m, dtype=jnp.int32) \
+        - (jnp.cumsum(counts) - counts)[at]
+    dest_sorted = jnp.where(
+        sorted_e < num_held,
+        (jnp.cumsum(padded) - padded)[at] + rank, rows)
+    dest = jnp.zeros(m, jnp.int32).at[order].set(
+        dest_sorted.astype(jnp.int32))
+    n_valid = tile_end[-1:]
+    tiles = jnp.arange(rows // tile_rows, dtype=jnp.int32)
+    # a tile past the last valid one names that one's expert, so the
+    # kernel fetches nothing for it
+    tile_expert = jnp.searchsorted(
+        tile_end, jnp.minimum(tiles, jnp.maximum(n_valid - 1, 0)),
+        side="right")
+    return (dest, jnp.minimum(tile_expert, num_held - 1).astype(jnp.int32),
+            n_valid.astype(jnp.int32), counts, rows)
+
+
+def held_experts_ffn(x, experts, weights, gu_w, down_w, first,
+                     tile_rows=None):
+    """The held experts' part of ``sum_e w_e down_e(silu(gate_e x) *
+    up_e x)`` for tokens ``x`` [T, C] routed by
+    :func:`grouped_topk_route`.
+
+    ``gu_w`` [E_held, C, 2F] (gate columns, then up), ``down_w``
+    [E_held, F, C]: the matrices of experts ``first .. first + E_held``
+    of the router's range.  Terms of experts held elsewhere are left
+    out; the weights stay as normalised over all ``k``.  Returns
+    ``(y float32 [T, C], stats)`` with ``stats`` the step's counts as
+    float32 scalars: held experts hit, local assignments, and the most
+    tokens one held expert got.
+    """
+    from ..ops.pallas.grouped_matmul import moe_gmm
+
+    t, c = x.shape
+    k = experts.shape[1]
+    num_held, _, two_f = gu_w.shape
+    m = t * k
+    if tile_rows is None:
+        # few rows an expert (decode): the smallest bf16 tile.  A prompt
+        # gives a held expert ~16 rows: 32-row tiles keep the padded
+        # layout (m + held * (tile_rows - 1) rows) near m, and a tile
+        # reads its expert's weights once whatever its height
+        tile_rows = 16 if m <= 2048 else 32
+    local = experts.reshape(m) - first
+    is_local = (local >= 0) & (local < num_held)
+    dest, tile_expert, n_valid, counts, rows = expert_tiles(
+        jnp.where(is_local, local, num_held).astype(jnp.int32),
+        num_held, tile_rows)
+    token = jnp.arange(m, dtype=jnp.int32) // k
+    x_rows = jnp.zeros((rows, c), gu_w.dtype).at[dest].set(
+        x.astype(gu_w.dtype)[token], mode="drop")
+    # float32 between the two matmuls and after them: the only rounding
+    # to the stored type is of each matmul's input, as in a dense MLP
+    gu = moe_gmm(x_rows, gu_w, tile_expert, n_valid, tile_rows,
+                 out_dtype=jnp.float32)
+    act = (jax.nn.silu(gu[:, :two_f // 2]) * gu[:, two_f // 2:]) \
+        .astype(gu_w.dtype)
+    y_rows = moe_gmm(act, down_w, tile_expert, n_valid, tile_rows,
+                     out_dtype=jnp.float32)
+    y = jnp.where(is_local[:, None], y_rows[jnp.minimum(dest, rows - 1)],
+                  0.0) * weights.reshape(m, 1)
+    stats = {"experts_hit": (counts > 0).sum().astype(jnp.float32),
+             "local_assignments": counts.sum().astype(jnp.float32),
+             "max_tokens_per_expert": counts.max().astype(jnp.float32)}
+    return y.reshape(t, k, c).sum(1), stats

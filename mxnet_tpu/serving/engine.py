@@ -134,6 +134,8 @@ published as ``serving.cost.{decode,prefill}.*`` gauges — joined by
 """
 from __future__ import annotations
 
+import collections
+import functools
 import itertools
 import os
 import time
@@ -149,6 +151,7 @@ from .. import watchdog as _watchdog
 from ..base import MXNetError
 from .kv_cache import PagedKVAllocator, SCRATCH_PAGE, normalize_kv_dtype
 from .prefix_cache import PrefixCache
+from .programs import KVPages, LatentPages, SlotState
 from .scheduler import (CANCELLED, ContinuousBatchingScheduler, EXPIRED,
                         FAILED, FINISHED, QUEUED, RUNNING,
                         SamplingParams, VERDICT_ABANDONED,
@@ -184,6 +187,26 @@ def _env_float(name):
     except ValueError:
         return None
     return v if v > 0 else None
+
+
+def _fetch_async(*arrays):
+    """Start the device-to-host copy of every array that is one (None
+    and host values pass): the ``np.asarray`` / ``int()`` that follows
+    then finds the bytes on their way and waits once, not once an
+    array."""
+    for a in arrays:
+        start = getattr(a, "copy_to_host_async", None)
+        if start is not None:
+            start()
+
+
+@functools.lru_cache(maxsize=None)
+def _zeros_program(shape, dtype):
+    """A jitted zeros of one shape, compiled once however many layers
+    ask for it.  Every call returns a FRESH XLA-owned buffer."""
+    import jax
+    import jax.numpy as jnp
+    return jax.jit(lambda: jnp.zeros(shape, dtype))
 
 
 def ngram_draft(context, k, max_n=3):
@@ -222,7 +245,9 @@ def ngram_draft(context, k, max_n=3):
 
 
 class ServingEngine:
-    """Continuous-batching greedy-decode server over a model-zoo GPTLM.
+    """Continuous-batching greedy-decode server over a model-zoo net
+    that answers ``serving_programs()`` (serving/programs.py: its
+    programs and each layer's cache kind).
 
     ``num_slots`` decode slots, a shared pool of ``num_pages`` KV pages
     of ``page_size`` tokens; prompts are padded to ``max_prefill_len``
@@ -233,18 +258,29 @@ class ServingEngine:
 
     ``record_logits=True`` keeps every request's per-token logits rows
     (tests bit-check them across occupancy changes); off in production.
+
+    ``decode_ahead=n`` keeps ``n`` more decode dispatches on the device
+    than the step reads (SERVING.md section 3): they, and an admission's
+    prefill, go out before the tokens they follow are read.  Same
+    tokens, no device idle between programs.
     """
 
     def __init__(self, net, num_slots=4, page_size=16, num_pages=None,
                  max_prefill_len=32, max_seq_len=None, eos_id=None,
                  record_logits=False, slo=None, default_deadline_s=None,
                  kv_heads=None, prefix_cache=None, spec_k=None,
-                 spec_drafter=None, kv_dtype=None):
-        from ..gluon.model_zoo import gpt as _gpt
-
-        self._gpt = _gpt
+                 spec_drafter=None, kv_dtype=None, decode_ahead=False):
+        # everything model-shaped comes from this one object: the
+        # programs and, layer by layer, the kind of cache they keep
+        self._model = net.serving_programs()
         self._net = net
-        self._n_heads = net.blocks._children[0].attn._num_heads
+        self._n_heads = self._model.n_heads
+        max_len = self._model.max_len
+        paged_kv = self._model.cache_kinds is None
+        if not paged_kv and kv_heads is not None:
+            raise ValueError(
+                "kv_heads regroups paged K/V heads; this model keeps "
+                "latent or per-slot state caches")
         # grouped-query serving (ISSUE 15): K_kv <= H KV heads shrink
         # the page pools H/K_kv-fold -> proportionally more resident
         # sequences for the same pool bytes.  Explicit arg wins; env
@@ -272,7 +308,12 @@ class ServingEngine:
             # dtype is one field of the general policy
             kv_dtype = kv_dtype.kv_dtype
         self.kv_dtype = normalize_kv_dtype(kv_dtype)
-        self._p = _gpt.decode_params(net, kv_heads=self.kv_heads)
+        if not paged_kv and self.kv_dtype == "int8":
+            raise ValueError(
+                "int8 pages are defined for paged K/V pools only: a "
+                "latent row or a recurrent state has no per-page scale "
+                "here (use bf16 or fp32)")
+        self._p = self._model.decode_params(net, kv_heads=self.kv_heads)
         self._n_layers = len(self._p["layers"])
         self._units = int(self._p["wte"].shape[1])
         self._vocab = int(self._p["wte"].shape[0])
@@ -281,11 +322,10 @@ class ServingEngine:
         self.page_size = int(page_size)
         self.max_prefill_len = int(max_prefill_len)
         self.max_seq_len = int(max_seq_len if max_seq_len is not None
-                               else net._max_len)
-        if self.max_seq_len > net._max_len:
+                               else max_len)
+        if self.max_seq_len > max_len:
             raise ValueError("max_seq_len %d exceeds the model's "
-                             "max_len %d" % (self.max_seq_len,
-                                             net._max_len))
+                             "max_len %d" % (self.max_seq_len, max_len))
         if self.max_prefill_len > self.max_seq_len:
             raise ValueError("max_prefill_len > max_seq_len")
         # speculative decoding (ISSUE 16): up to ``spec_k`` host-drafted
@@ -300,16 +340,48 @@ class ServingEngine:
         self.spec_k = int(spec_k)
         if self.spec_k < 0:
             raise ValueError("spec_k must be >= 0")
+        if self.spec_k and not paged_kv:
+            raise ValueError(
+                "speculative decoding rolls rejected drafts back by "
+                "masking pages; a per-slot recurrent state cannot be "
+                "rolled back, so spec_k must be 0 for this model")
         if self.spec_k and \
-                self.max_seq_len + self.spec_k > net._max_len:
+                self.max_seq_len + self.spec_k > max_len:
             raise ValueError(
                 "speculative decoding needs max_seq_len + spec_k <= "
                 "the model's max_len (draft positions run past the "
                 "last committed token): %d + %d > %d — lower "
                 "max_seq_len or spec_k"
-                % (self.max_seq_len, self.spec_k, net._max_len))
+                % (self.max_seq_len, self.spec_k, max_len))
         self._drafter = (spec_drafter if spec_drafter is not None
                          else ngram_draft)
+        # ``decode_ahead`` = n: a step sends decode dispatches until
+        # 1 + n are unread, THEN waits for the oldest, so the device
+        # finds its next program queued while the host fetches, emits
+        # and admits.  The tokens and keys a dispatch follows stay on
+        # the device (the program selects them over the host's rows); a
+        # request whose last token is on its way sits a dispatch out; an
+        # admission's prefill joins the same queue and its first token
+        # is read with the decode that follows it.  Tokens are the same;
+        # what moves: an admission's programs run after the n already sent,
+        # and an early stop (EOS, cancel, deadline) costs its slot up
+        # to n unread decodes.  0: the engine as it was, program for
+        # program.
+        self._decode_ahead = int(decode_ahead)
+        if self._decode_ahead < 0:
+            raise ValueError("decode_ahead must be >= 0")
+        if self._decode_ahead and (self.spec_k
+                                   or self.kv_dtype == "int8"):
+            raise ValueError(
+                "decode_ahead sends a step's successor before its "
+                "tokens are read: a speculative step's accepted length "
+                "and an int8 step's repair decide the successor's "
+                "inputs on the host, so spec_k must be 0 and kv_dtype "
+                "fp32 or bf16")
+        #: dispatches sent and not read yet, oldest first (each a record
+        #: of ``_send_decode`` / ``_send_prefill``); empty between steps
+        #: unless ``decode_ahead``
+        self._unread = collections.deque()
         # draft positions may spill past max_seq_len by up to spec_k
         # tokens: the per-sequence page budget covers the worst case so
         # a draft write can never land outside the request's own pages
@@ -323,14 +395,33 @@ class ServingEngine:
         self.eos_id = None if eos_id is None else int(eos_id)
         self._record_logits = bool(record_logits)
 
-        self.alloc = PagedKVAllocator(num_pages, self.page_size,
-                                      kv_dtype=self.kv_dtype)
+        # one kind of cache a layer (serving/programs.py); a model that
+        # names none keeps paged K/V pools everywhere
+        self._kinds = self._model.cache_kinds or tuple(
+            KVPages(self.kv_heads, self._head_dim)
+            for _ in range(self._n_layers))
+        self._slot_state = any(isinstance(k, SlotState)
+                               for k in self._kinds)
+        self.alloc = PagedKVAllocator(
+            num_pages, self.page_size, kv_dtype=self.kv_dtype,
+            slot_state_bytes=self.state_bytes_per_slot)
         # refcounted prefix caching (ISSUE 15): on by default
         # (MXTPU_SERVE_PREFIX_CACHE=0 / prefix_cache=False disables).
         # Admission maps a prompt's longest page-aligned cached prefix
         # into the block table by reference and prefills only the
         # suffix — system-prompt-heavy traffic turns shared pages into
         # a direct admission-capacity and TTFT multiplier.
+        # A cached prefix is a list of pages; a recurrent layer's
+        # prefix is a state, and a latent row is written by one prefill
+        # program from position 0: prefix reuse is OFF for such a model
+        # (asking for it is an error; snapshots are ROADMAP's item).
+        if not paged_kv:
+            if prefix_cache:
+                raise ValueError(
+                    "the prefix cache shares K/V pages; this model "
+                    "keeps latent or per-slot state caches, whose "
+                    "prefix is not a list of pages")
+            prefix_cache = False
         if prefix_cache is None:
             prefix_cache = os.environ.get(
                 "MXTPU_SERVE_PREFIX_CACHE", "1") not in ("0", "off", "")
@@ -400,6 +491,13 @@ class ServingEngine:
         self.cost = {}
 
         self._kv = self._init_pages()
+        #: what the last prefill / decode dispatch returned beside its
+        #: tokens, still on the device: ``(logits, aux)`` of a model
+        #: whose programs report ``aux`` (None otherwise)
+        self.last_prefill = self.last_decode = None
+        #: running sums of the counts the programs report
+        #: (``ServingPrograms.decode_stats``), by program
+        self.stat_totals = {"decode": {}, "prefill": {}}
         self.decode_steps = 0
         self.prefills = 0
         # scale-poison repairs per resident request (rid -> count): the
@@ -443,7 +541,7 @@ class ServingEngine:
         hot-swap entry point: a GQA engine needs the same K/V head
         pooling applied to the incoming weights, or swap_params would
         rightly reject the shape mismatch)."""
-        return self._gpt.decode_params(net, kv_heads=self.kv_heads)
+        return self._model.decode_params(net, kv_heads=self.kv_heads)
 
     # -- device state ------------------------------------------------------
     def _init_pages(self):
@@ -459,37 +557,72 @@ class ServingEngine:
         import jax
         import jax.numpy as jnp
 
-        # a token's KV heads side by side on the minor axis: with
-        # K_kv * D a multiple of 128 the chip keeps this row-major with
-        # no lane padding, which is what the programs' scatters and the
-        # paged kernel address (ops/pallas/paged_attention.py)
+        if self.kv_dtype != "int8":
+            return [self._init_cache(kind) for kind in self._kinds]
+        # int8 payload + per-page-per-KV-head fp32 absmax scales
+        # (gpt._quant_scatter resets a fresh page's scale before
+        # writing, so the zero init is never load-bearing)
         shape = (self.alloc.num_pages, self.page_size,
                  self.kv_heads * self._head_dim)
-        if self.kv_dtype == "int8":
-            # int8 payload + per-page-per-KV-head fp32 absmax scales
-            # (gpt._quant_scatter resets a fresh page's scale before
-            # writing, so the zero init is never load-bearing)
-            sshape = (self.alloc.num_pages, self.kv_heads)
-            mk = jax.jit(lambda: (jnp.zeros(shape, jnp.int8),
-                                  jnp.zeros(sshape, jnp.float32)))
-            out = []
-            for _ in range(self._n_layers):
-                kc, ks = mk()
-                vc, vs = mk()
-                out.append((kc, vc, ks, vs))
-            return out
-        dt = jnp.bfloat16 if self.kv_dtype == "bf16" else jnp.float32
-        mk = jax.jit(lambda: jnp.zeros(shape, dt))
-        return [(mk(), mk()) for _ in range(self._n_layers)]
+        sshape = (self.alloc.num_pages, self.kv_heads)
+        mk = jax.jit(lambda: (jnp.zeros(shape, jnp.int8),
+                              jnp.zeros(sshape, jnp.float32)))
+        out = []
+        for _ in range(self._n_layers):
+            kc, ks = mk()
+            vc, vs = mk()
+            out.append((kc, vc, ks, vs))
+        return out
+
+    def _init_cache(self, kind):
+        """One layer's cache of a declared kind, as fresh XLA-owned
+        buffers: ``(k, v)`` pools, one latent pool, or the per-slot
+        arrays (one row a slot and a scratch row, which a weight-swap
+        canary writes)."""
+        dt = "bfloat16" if self.kv_dtype == "bf16" else "float32"
+
+        def zeros(shape, dtype):
+            return _zeros_program(tuple(shape), str(dtype))()
+
+        pages = (self.alloc.num_pages, self.page_size)
+        if isinstance(kind, KVPages):
+            # a token's KV heads side by side on the minor axis: with
+            # K_kv * D a multiple of 128 the chip keeps this row-major
+            # with no lane padding, which is what the programs' scatters
+            # and the paged kernel address
+            shape = pages + (kind.heads * kind.head_dim,)
+            return (zeros(shape, dt), zeros(shape, dt))
+        if isinstance(kind, LatentPages):
+            return (zeros(pages + (kind.width,), dt),)
+        return tuple(zeros((self.num_slots + 1,) + tuple(shape),
+                           dtype or dt)
+                     for _, shape, dtype in kind.arrays)
 
     @property
     def kv_bytes_per_token(self):
-        """All-layer KV-cache bytes one committed token occupies under
-        this engine's ``kv_dtype`` (per-page scale overhead amortized
-        over the page) — the SERVING.md §2d sizing unit."""
-        return (self._n_layers
-                * self.alloc.page_bytes(self.kv_heads, self._head_dim)
-                / float(self.page_size))
+        """All-layer paged-cache bytes one committed token occupies
+        under this engine's ``kv_dtype`` (per-page scale overhead
+        amortized over the page) — the SERVING.md §2d sizing unit.  A
+        per-slot state costs no bytes a token (``state_bytes_per_slot``)."""
+        per_page = 0
+        for kind in self._kinds:
+            if isinstance(kind, KVPages):
+                per_page += self.alloc.page_bytes(kind.heads,
+                                                  kind.head_dim)
+            elif isinstance(kind, LatentPages):
+                per_page += self.alloc.latent_page_bytes(kind.width)
+        return per_page / float(self.page_size)
+
+    @property
+    def state_bytes_per_slot(self):
+        """All-layer bytes of per-slot recurrent state one resident
+        sequence holds, whatever its length."""
+        item = 4 if self.kv_dtype == "fp32" else 2
+        return sum(
+            int(_np.prod(shape)) * (_np.dtype(dtype).itemsize
+                                    if dtype else item)
+            for kind in self._kinds if isinstance(kind, SlotState)
+            for _, shape, dtype in kind.arrays)
 
     # -- program construction ---------------------------------------------
     def _config_hash(self):
@@ -517,12 +650,17 @@ class ServingEngine:
             # differ — pool dtypes, int8's scale pools, and the int8
             # programs' extra finite-mask output)
             h += "|kvq:%s" % self.kv_dtype
+        if self._model.cache_kinds is not None:
+            # declared cache kinds re-key; paged-K/V models keep theirs
+            h += "|kinds:%r|%s" % (self._kinds, self._model.config_key)
+        if self._decode_ahead:
+            h += "|ahead"
         return h
 
     def _build_programs(self):
         import jax
 
-        gpt = self._gpt
+        gpt = self._model
         n_heads = self._n_heads
         # int8 engines (ISSUE 20) append a per-slot finite mask over
         # the step's logits to the decode outputs: the divergence guard
@@ -545,15 +683,28 @@ class ServingEngine:
             def decode(p, kv_pages, tokens, positions, active,
                        draft_len, block_tables, temps, top_ks, top_ps,
                        keys):
-                out = gpt.paged_spec_decode_step(
+                out = gpt.spec_decode_step(
                     p, tokens, positions, active, draft_len, kv_pages,
                     block_tables, n_heads,
                     sampling=(temps, top_ks, top_ps, keys))
                 return out + (_finite(out[0]),) if quant else out
+        elif self._decode_ahead:
+            # a slot CARRIED from the dispatch before takes that
+            # dispatch's token and key, which never left the device
+            def decode(p, kv_pages, tokens, positions, active,
+                       block_tables, temps, top_ks, top_ps, keys,
+                       carried, prev_tokens, prev_keys):
+                import jax.numpy as jnp
+                tokens = jnp.where(carried, prev_tokens, tokens)
+                keys = jnp.where(carried[:, None], prev_keys, keys)
+                return gpt.decode_step(
+                    p, tokens, positions, active, kv_pages,
+                    block_tables, n_heads,
+                    sampling=(temps, top_ks, top_ps, keys))
         else:
             def decode(p, kv_pages, tokens, positions, active,
                        block_tables, temps, top_ks, top_ps, keys):
-                out = gpt.paged_decode_step(
+                out = gpt.decode_step(
                     p, tokens, positions, active, kv_pages,
                     block_tables, n_heads,
                     sampling=(temps, top_ks, top_ps, keys))
@@ -565,12 +716,38 @@ class ServingEngine:
         # branch — bit-identical to the pre-prefix-cache prefill; only
         # hits pay the attention over the gathered prefix.  Samples the
         # request's FIRST token under its params.
-        def prefill(p, kv_pages, tokens, prompt_len, prefix_len,
-                    bt_row, cow_src, cow_dst, temp, top_k, top_p, key):
-            return gpt.paged_prefill(
-                p, tokens, prompt_len, prefix_len, bt_row, cow_src,
-                cow_dst, kv_pages, n_heads,
-                sampling=(temp, top_k, top_p, key))
+        # a model with per-slot state is told which slot it admits into
+        if self._decode_ahead:
+            # the first token and key also join the device's per-slot
+            # rows, which the next decode takes its carried slots from
+            slot_state = self._slot_state
+
+            def prefill(p, kv_pages, tokens, prompt_len, prefix_len,
+                        bt_row, cow_src, cow_dst, temp, top_k, top_p,
+                        key, slot, row_tokens, row_keys):
+                out = gpt.prefill(
+                    p, tokens, prompt_len, prefix_len, bt_row, cow_src,
+                    cow_dst, kv_pages, n_heads,
+                    sampling=(temp, top_k, top_p, key),
+                    **({"slot": slot} if slot_state else {}))
+                return out + ((row_tokens.at[slot].set(out[1]),
+                               row_keys.at[slot].set(out[2])),)
+        elif self._slot_state:
+            def prefill(p, kv_pages, tokens, prompt_len, prefix_len,
+                        bt_row, cow_src, cow_dst, temp, top_k, top_p,
+                        key, slot):
+                return gpt.prefill(
+                    p, tokens, prompt_len, prefix_len, bt_row, cow_src,
+                    cow_dst, kv_pages, n_heads,
+                    sampling=(temp, top_k, top_p, key), slot=slot)
+        else:
+            def prefill(p, kv_pages, tokens, prompt_len, prefix_len,
+                        bt_row, cow_src, cow_dst, temp, top_k, top_p,
+                        key):
+                return gpt.prefill(
+                    p, tokens, prompt_len, prefix_len, bt_row, cow_src,
+                    cow_dst, kv_pages, n_heads,
+                    sampling=(temp, top_k, top_p, key))
 
         def sds(x):
             return jax.ShapeDtypeStruct(x.shape, x.dtype)
@@ -602,6 +779,10 @@ class ServingEngine:
                          jax.ShapeDtypeStruct((s,), i32),
                          jax.ShapeDtypeStruct((s,), f32),
                          jax.ShapeDtypeStruct((s, 2), u32))
+        if self._decode_ahead:
+            decode_ex += (jax.ShapeDtypeStruct((s,), _np.bool_),
+                          jax.ShapeDtypeStruct((s,), i32),
+                          jax.ShapeDtypeStruct((s, 2), u32))
         samp_ex = (jax.ShapeDtypeStruct((), f32),
                    jax.ShapeDtypeStruct((), i32),
                    jax.ShapeDtypeStruct((), f32),
@@ -613,6 +794,11 @@ class ServingEngine:
                       jax.ShapeDtypeStruct((mp,), i32),
                       jax.ShapeDtypeStruct((), i32),
                       jax.ShapeDtypeStruct((), i32)) + samp_ex
+        if self._slot_state or self._decode_ahead:
+            prefill_ex += (jax.ShapeDtypeStruct((), i32),)
+        if self._decode_ahead:
+            prefill_ex += (jax.ShapeDtypeStruct((s,), i32),
+                           jax.ShapeDtypeStruct((s, 2), u32))
         extra = self._config_hash()
         self._decode = self._compile("decode", decode, decode_ex, extra)
         self._prefill = self._compile("prefill", prefill, prefill_ex,
@@ -1065,8 +1251,10 @@ class ServingEngine:
         diagnosable stall, not a silent hang); an injected
         ``serve.prefill.error`` fails THAT request deterministically —
         typed ``prefill_error`` verdict, slot + every reserved page
-        released, never requeued — and the loop moves on."""
-        placed = []
+        released, never requeued — and the loop moves on.  Returns the
+        tokens produced (with ``decode_ahead`` none yet: the prefills
+        wait in ``_unread``)."""
+        produced = 0
         with _telemetry.span("serve.admit", "serving") as sp:
             admitted = self.sched.admit()
             sp.set(admitted=len(admitted))
@@ -1100,13 +1288,20 @@ class ServingEngine:
                     trace=req.trace, prompt=int(req.prompt.size),
                     prefix_len=req.prefix_len,
                     queue_wait_us=int(req.queue_wait_s * 1e6)):
-                self._prefill_one(req)
-            placed.append(req)
-        return placed
+                sent = self._send_prefill(req)
+                if self._decode_ahead:
+                    # read when its turn comes (``_step``)
+                    self._unread.append(sent)
+                else:
+                    produced += self._emit_prefill(sent)
+        return produced
 
-    def _prefill_one(self, req):
-        """The prefill dispatch of one admitted request, its first-token
-        readback and the bookkeeping that commits it."""
+    def _send_prefill(self, req):
+        """The prefill dispatch of one admitted request; nothing is
+        waited for.  With ``decode_ahead`` the program also sets the
+        first token and the key in the device's per-slot rows, as the
+        newest unread dispatch left them, and the next decode takes
+        them from there.  Returns the dispatch's record."""
         with _watchdog.guard("serve.prefill"):
             with _telemetry.stamp_span("serve_prefill.dispatch") as disp:
                 samp = self._arm_slot_sampling(req)
@@ -1116,24 +1311,41 @@ class ServingEngine:
                 # dense branch runs
                 suffix = req.prompt[req.prefix_len:]
                 toks[:suffix.size] = suffix
-                logits, first, new_key, self._kv = self._prefill(
-                    self._p, self._kv, toks,
-                    _np.int32(req.prompt.size),
-                    _np.int32(req.prefix_len),
+                logits, first, new_key, stats, rows = self._run_prefill(
+                    toks, req.prompt.size, req.prefix_len,
                     self.sched.block_tables[req.slot].copy(),
-                    _np.int32(req.cow_src if req.cow_src is not None
-                              else SCRATCH_PAGE),
-                    _np.int32(req.cow_dst if req.cow_dst is not None
-                              else SCRATCH_PAGE),
-                    *samp)
+                    req.cow_src if req.cow_src is not None
+                    else SCRATCH_PAGE,
+                    req.cow_dst if req.cow_dst is not None
+                    else SCRATCH_PAGE, samp, req.slot)
+                _fetch_async(first, new_key, stats)
+        return {"reqs": [req], "logits": logits, "first": first,
+                "new_key": new_key, "stats": stats, "rows": rows,
+                "disp": disp}
+
+    def _emit_prefill(self, sent):
+        """Wait for one prefill's first token and do the bookkeeping
+        that commits it.  Returns the tokens emitted."""
+        req, disp = sent["reqs"][0], sent["disp"]
+        with _watchdog.guard("serve.prefill"):
             with _telemetry.stamp_span("serve_prefill.sync") as sync:
-                first = int(first)          # device sync
-        t0, t1, t2 = disp.t0, disp.t1, sync.t1
+                first = int(sent["first"])          # device sync
+                if sent["stats"] is not None:
+                    self._note_stats("prefill", _np.asarray(sent["stats"]))
+        # a prefill read after its step: its two phases are noted back
+        # to back, each at its own length
+        t1 = sync.t0 if self._decode_ahead else disp.t1
+        t0, t2 = t1 - (disp.t1 - disp.t0), sync.t1
+        self.prefills += 1
+        _telemetry.counter("serving.prefills").inc()
+        if req.done:
+            # it left (cancel, deadline) while its prefill was unread
+            return 0
         # prefix/prefill-token accounting AFTER the dispatch landed: a
         # prefill that failed (fault above) must not count tokens that
         # were never prefilled
         self._note_prefix_admission(req)
-        self._keys[req.slot] = _np.asarray(new_key, _np.uint32)
+        self._keys[req.slot] = _np.asarray(sent["new_key"], _np.uint32)
         if self._prefix is not None:
             # register the prompt's full pages under their content keys
             # — ONLY now, after the prefill landed: a failed prefill
@@ -1153,11 +1365,47 @@ class ServingEngine:
         # _note_token so a finish-on-first-token (max_new=1) orders
         # token -> verdict in the trace
         _telemetry.note_request_event(req.trace, "token", t_ns=t2)
-        self.prefills += 1
-        _telemetry.counter("serving.prefills").inc()
         self._note_token(req, first,
-                         _np.asarray(logits) if self._record_logits
-                         else None)
+                         _np.asarray(sent["logits"])
+                         if self._record_logits else None)
+        return 1
+
+    def _device_rows(self):
+        """The per-slot ``(tokens, keys)`` the newest unread dispatch
+        left on the device; the host's own with nothing unread."""
+        if self._unread:
+            return self._unread[-1]["rows"]
+        return (_np.zeros(self.num_slots, _np.int32), self._keys.copy())
+
+    def _run_prefill(self, toks, prompt_len, prefix_len, bt_row, cow_src,
+                     cow_dst, samp, slot):
+        """One dispatch of the prefill program; the caches come back
+        donated-through.  A model with per-slot state also gets the
+        slot (``num_slots`` is the scratch row), and so does the
+        ``decode_ahead`` program, with the device's per-slot rows.
+        Returns ``(logits, first token, new key, the program's counts
+        or None, the rows with this slot's set or None)``, all still on
+        the device."""
+        args = (toks, _np.int32(prompt_len), _np.int32(prefix_len),
+                bt_row, _np.int32(cow_src), _np.int32(cow_dst)) \
+            + tuple(samp)
+        if self._slot_state or self._decode_ahead:
+            args += (_np.int32(slot),)
+        if self._decode_ahead:
+            args += tuple(self._device_rows())
+        out = self._prefill(self._p, self._kv, *args)
+        rows = None
+        if self._decode_ahead:
+            *out, rows = out
+        stats = None
+        if self._model.has_aux:
+            # the program's own report of the dispatch (routing), kept
+            # on the device for whoever asks (:attr:`last_prefill`)
+            *out, aux = out
+            self.last_prefill = (out[0], aux)
+            stats = aux["stats"]
+        logits, first, new_key, self._kv = out
+        return logits, first, new_key, stats, rows
 
     def _note_token(self, req, token, logits_row=None):
         now = time.perf_counter()
@@ -1213,11 +1461,13 @@ class ServingEngine:
                 self._poison_page_scale()
             self._expire_deadlines()
             self.sweep_streams()
-        placed = self._admit_and_prefill()
-        # every placed request produced exactly one token in its prefill
-        produced = len(placed)
+        # every placed request produces exactly one token in its prefill
+        produced = self._admit_and_prefill()
         running = self.sched.running
         if not running:
+            # what is unread has nobody left to read it
+            while self._unread:
+                produced += self._emit(self._unread.popleft())
             if produced:
                 _watchdog.renew(self._lease, step=self.decode_steps,
                                 phase="serve_step")
@@ -1238,47 +1488,123 @@ class ServingEngine:
         if self.spec_k:
             return produced + self._spec_decode_once(running)
 
+        # this step's successors go out before its own tokens are
+        # waited for: the device finds them queued when this one ends
+        unread = self._unread
+        while sum("nxt" in d for d in unread) <= self._decode_ahead:
+            sent = self._send_decode(running)
+            if sent is None:
+                break
+            unread.append(sent)
+        # ONE decode dispatch is read a step.  A prefill sent before it
+        # is read with it, once the decode's tokens are there: the host
+        # never sits waiting on a prefill whose successor is queued
+        prefills = []
+        while unread and "nxt" not in unread[0]:
+            prefills.append(unread.popleft())
+        if unread:
+            return produced + self._emit_decode(unread.popleft(), prefills)
+        return produced + sum(self._emit_prefill(d) for d in prefills)
+
+    def _emit(self, sent):
+        return (self._emit_decode if "nxt" in sent
+                else self._emit_prefill)(sent)
+
+    def _send_decode(self, running):
+        """Pack and dispatch ONE decode step for ``running`` and start
+        its results' way home; nothing is waited for.  A request in an
+        unread dispatch (``decode_ahead`` only) continues from the token
+        on the device, as many positions on as dispatches hold it,
+        unless its last token by count is among them.  Returns the
+        dispatch's record, or None with nobody to advance."""
         s = self.num_slots
+        unread = collections.Counter(
+            r.rid for d in self._unread for r in d["reqs"])
         with _telemetry.span("serve.decode.pack", "serving",
                              live=len(running)):
             tokens = _np.zeros(s, _np.int32)
             positions = _np.zeros(s, _np.int32)
             active = _np.zeros(s, _np.bool_)
+            carried = _np.zeros(s, _np.bool_)
+            reqs = []
             for req in running:
-                tokens[req.slot] = req.tokens[-1]
+                n = len(req.tokens) + unread[req.rid]
+                if n >= req.max_new:
+                    continue
+                if unread[req.rid]:
+                    carried[req.slot] = True
+                else:
+                    tokens[req.slot] = req.tokens[-1]
                 # context already in pages: prompt + generated-but-last;
                 # the last generated token is what this step feeds in,
                 # at position prompt_len + (n_generated - 1)
-                positions[req.slot] = \
-                    req.prompt.size + len(req.tokens) - 1
+                positions[req.slot] = req.prompt.size + n - 1
                 active[req.slot] = True
+                reqs.append(req)
+            if not reqs:
+                return None
             args = (tokens, positions, active,
                     self.sched.block_tables.copy(), self._temps.copy(),
                     self._top_ks.copy(), self._top_ps.copy(),
                     self._keys.copy())
+            if self._decode_ahead:
+                args += (carried,) + tuple(self._device_rows())
 
         with _telemetry.stamp_span("serve_step.dispatch") as disp:
             res = self._decode(self._p, self._kv, *args)
+            aux = None
+            if self._model.has_aux:
+                # the step's own counts come back with its tokens: one
+                # small vector, fetched after the token sync
+                *res, aux = res
             if self.kv_dtype == "int8":
                 logits, nxt, new_keys, self._kv, ok_dev = res
             else:
                 logits, nxt, new_keys, self._kv = res
                 ok_dev = None
+            # everything the host reads back from this step starts its
+            # way home now, together: each blocking fetch would
+            # otherwise pay its own round trip after the program ends
+            _fetch_async(nxt, new_keys, aux and aux["stats"], ok_dev)
+        return {"reqs": reqs, "logits": logits, "nxt": nxt,
+                "new_keys": new_keys, "rows": (nxt, new_keys), "aux": aux,
+                "ok_dev": ok_dev, "disp": disp}
+
+    def _emit_decode(self, sent, prefills=()):
+        """Wait for one dispatch's tokens and hand each to its request
+        (one that left since the dispatch went out is passed over),
+        after the first tokens of ``prefills``, which ran before it.
+        Returns the tokens emitted."""
+        disp, aux, ok_dev = sent["disp"], sent["aux"], sent["ok_dev"]
         with _telemetry.stamp_span("serve_step.sync") as sync:
-            nxt = _np.asarray(nxt)           # device sync barrier
+            nxt = _np.asarray(sent["nxt"])   # device sync barrier
+            if aux is not None:
+                self.last_decode = (sent["logits"], aux)
+                self._note_stats("decode", _np.asarray(aux["stats"]))
+        first = sum(self._emit_prefill(d) for d in prefills)
+        running = [r for r in sent["reqs"] if not r.done]
         with _telemetry.span("serve.decode.emit", "serving") as emit:
             # per-slot PRNG state advances FUNCTIONALLY inside the
             # donated program; the host copy is the only carry between
             # steps (np.array, not asarray: a jax-backed view is
             # read-only and admission writes per-slot rows)
             keys_prev = self._keys
-            self._keys = _np.array(new_keys, _np.uint32)
+            self._keys = _np.array(sent["new_keys"], _np.uint32)
+            if self._decode_ahead:
+                # a slot admitted since the dispatch went out holds its
+                # prefill's key: only the advanced slots' rows are news
+                rows = [r.slot for r in running]
+                keys_prev[rows] = self._keys[rows]
+                self._keys = keys_prev
             victims = ()
             if ok_dev is not None:
                 okm = _np.asarray(ok_dev)
                 victims = tuple(r for r in running if not okm[r.slot])
-            _telemetry.note_train_step(disp.t0, disp.t1, sync.t1,
-                                       where="serve_step")
+            # a dispatch sent ahead ended a step ago: its two phases
+            # are noted back to back, each at its own length
+            t1 = sync.t0 if self._decode_ahead else disp.t1
+            _telemetry.note_train_step(t1 - (disp.t1 - disp.t0), t1,
+                                       sync.t1, where="serve_step")
             # ONE batched ``tokens`` event per decode step naming every
             # advanced trace (all residents share the step's sync stamp
             # anyway) — per-token tracing at flight-recorder cost; the
@@ -1294,10 +1620,10 @@ class ServingEngine:
             self.decode_steps += 1
             _watchdog.renew(self._lease, step=self.decode_steps,
                             phase="serve_step")
-            logits_np = _np.asarray(logits) if self._record_logits \
-                else None
+            logits_np = _np.asarray(sent["logits"]) \
+                if self._record_logits else None
             made = 0
-            for req in list(running):
+            for req in running:
                 if req in victims:
                     continue
                 self._note_token(
@@ -1310,7 +1636,7 @@ class ServingEngine:
                 _watchdog.release(self._lease)
             self._publish_gauges()
             emit.set(tokens=made)
-        return produced + made
+        return first + made
 
     # -- speculative decoding (ISSUE 16) -----------------------------------
     def _draft_for(self, req):
@@ -1538,23 +1864,54 @@ class ServingEngine:
             samp = (_np.float32(0), _np.int32(0), _np.float32(0),
                     _np.zeros(2, _np.uint32))
             with _watchdog.guard("serve.prefill"):
-                _logits, _first, _key, self._kv = self._prefill(
-                    self._p, self._kv, toks, _np.int32(ctx.size),
-                    _np.int32(0),
+                self._run_prefill(
+                    toks, ctx.size, 0,
                     self.sched.block_tables[req.slot].copy(),
-                    _np.int32(SCRATCH_PAGE), _np.int32(SCRATCH_PAGE),
-                    *samp)
+                    SCRATCH_PAGE, SCRATCH_PAGE, samp, req.slot)
             _telemetry.counter("serving.kv.scale_repairs").inc()
             _telemetry.note_request_event(
                 req.trace, "kv_repair",
                 args={"replica": self.trace_tag, "rid": req.rid,
                       "repairs": n, "context": int(ctx.size)})
 
+    def _note_stats(self, program, values):
+        """Counters and gauges from the counts one dispatch returned
+        with its tokens (``ServingPrograms.decode_stats``;
+        OBSERVABILITY.md section 9): sums as ``serving.moe.<name>``
+        counters (both programs), the decode step's per-layer means as
+        gauges."""
+        doc = dict(zip(self._model.decode_stats,
+                       (int(round(float(v))) for v in values)))
+        totals = self.stat_totals[program]
+        for name, v in doc.items():
+            totals[name] = totals.get(name, 0) + v
+            _telemetry.counter("serving.moe.%s" % name).inc(v)
+        if program != "decode":
+            return
+        layers = doc.get("expert_layers")
+        if layers and doc.get("assignments"):
+            _telemetry.gauge("serving.moe.local_share").set(
+                doc["local_assignments"] / doc["assignments"])
+            _telemetry.gauge("serving.moe.experts_hit_per_layer").set(
+                doc["experts_hit"] / layers)
+            _telemetry.gauge("serving.moe.max_tokens_per_expert").set(
+                doc["max_tokens_per_expert"] / layers)
+            _telemetry.gauge("serving.moe.mean_tokens_per_expert").set(
+                doc["local_assignments"] / doc["held_experts"])
+
     def _publish_gauges(self):
         _telemetry.gauge("serving.batch_occupancy").set(
             self.sched.occupancy)
         _telemetry.gauge("serving.kv_pages_free").set(
             self.alloc.free_pages)
+        if self._model.cache_kinds is not None:
+            live = self.sched.occupancy
+            _telemetry.gauge("serving.state.live_slots").set(
+                live if self._slot_state else 0)
+            _telemetry.gauge("serving.state.live_bytes").set(
+                self.alloc.state_bytes(live))
+            _telemetry.gauge("serving.latent.live_pages").set(
+                self.alloc.used_pages)
 
     def run_until_idle(self, max_steps=100000):
         """Drive step() until queue and slots are empty (tests and batch
@@ -1647,10 +2004,9 @@ class ServingEngine:
         samp = (_np.float32(0), _np.int32(0), _np.float32(0),
                 _np.zeros(2, _np.uint32))
         with _telemetry.span("serving.swap_canary", cat="serving"):
-            logits, _first, _key, self._kv = self._prefill(
-                self._p, self._kv, toks, _np.int32(1),
-                _np.int32(0), bt, _np.int32(SCRATCH_PAGE),
-                _np.int32(SCRATCH_PAGE), *samp)
+            logits = self._run_prefill(
+                toks, 1, 0, bt, SCRATCH_PAGE, SCRATCH_PAGE, samp,
+                self.num_slots)[0]
             row = _np.asarray(logits)       # device sync
         if not _np.isfinite(row).all():
             raise MXNetError(
@@ -1690,6 +2046,7 @@ class ServingEngine:
             "kv_heads": self.kv_heads,
             "kv_dtype": self.kv_dtype,
             "kv_bytes_per_token": round(self.kv_bytes_per_token, 3),
+            "state_bytes_per_slot": self.state_bytes_per_slot,
             "prefix_cached_pages": (None if self._prefix is None
                                     else self._prefix.cached_pages),
             "shared_pages": self.alloc.shared_pages,

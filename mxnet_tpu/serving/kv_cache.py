@@ -70,7 +70,8 @@ def normalize_kv_dtype(kv_dtype):
 
 
 class PagedKVAllocator:
-    def __init__(self, num_pages, page_size, kv_dtype=None):
+    def __init__(self, num_pages, page_size, kv_dtype=None,
+                 slot_state_bytes=0):
         if num_pages < 2:
             raise ValueError("num_pages must be >= 2 (page 0 is the "
                              "reserved scratch page)")
@@ -84,6 +85,11 @@ class PagedKVAllocator:
         #: capacity math (scheduler reservations, serve_report, bench)
         #: has ONE authority for what a page costs.
         self.kv_dtype = normalize_kv_dtype(kv_dtype)
+        #: bytes of per-slot recurrent state (all layers) a resident
+        #: sequence holds beside its pages; 0 for a model without such
+        #: layers.  Slots are the scheduler's to hand out; the byte
+        #: count lives here so that capacity has one authority.
+        self.slot_state_bytes = int(slot_state_bytes)
         # LIFO free list, scratch page excluded.  Reversed so the first
         # allocations hand out low page ids (stable, test-friendly).
         self._free = list(range(self.num_pages - 1, 0, -1))
@@ -112,6 +118,16 @@ class PagedKVAllocator:
         item, scale_rows = _KV_DTYPES[self.kv_dtype]
         b = 2 * self.page_size * int(kv_heads) * int(head_dim) * item
         return b + 2 * scale_rows * int(kv_heads) * 4
+
+    def latent_page_bytes(self, width):
+        """Bytes ONE physical page of a LATENT pool costs in one layer:
+        ``page_size`` rows of ``width`` values that serve as key and
+        value at once (never int8: the engine refuses that)."""
+        return self.page_size * int(width) * self.kv_itemsize
+
+    def state_bytes(self, slots):
+        """Bytes of per-slot state ``slots`` resident sequences hold."""
+        return int(slots) * self.slot_state_bytes
 
     def scale_bytes(self, kv_heads):
         """Scale-pool bytes per page (both pools; 0 unless int8)."""

@@ -199,6 +199,88 @@ def test_engine_program_keeps_the_pools_in_one_layout(
         int(np.prod(a.shape)) * a.dtype.itemsize for a in pools), mem
 
 
+# -- latent pages and per-slot state side by side ---------------------------
+
+_HYBRID_PROGRAMS = {}
+
+
+def _hybrid_engine_programs(monkeypatch):
+    """``{name: (fn, example shapes)}`` of the engine's programs over
+    the hybrid KDA / MLA / routed-expert decoder at its PUBLISHED widths
+    and the benchmark cell's engine sizes (128 slots, 64-token pages,
+    a 1024-token prefill, 5,120 pages), on three of its layers (KDA +
+    dense, KDA + experts, MLA + experts; 128 of 512 experts held).
+    Neither weights nor caches are made: the net hands the engine
+    shapes, and ``_compile`` / ``_init_cache`` are recorders."""
+    if not _HYBRID_PROGRAMS:
+        from mxnet_tpu.gluon.model_zoo import ling3
+        from mxnet_tpu.serving import ServingEngine
+        net = ling3.ling3_flash_vl(layers=[1, 4, 5])
+        programs = net.serving_programs()
+        programs.decode_params = lambda net, kv_heads=None: \
+            ling3.param_tree(net.cfg, lambda path, shape:
+                             jax.ShapeDtypeStruct(shape, jnp.bfloat16))
+        monkeypatch.setattr(net, "serving_programs", lambda: programs,
+                            raising=False)
+        monkeypatch.setattr(
+            ServingEngine, "_compile",
+            lambda self, name, fn, examples, extra:
+            _HYBRID_PROGRAMS.__setitem__(name, (fn, examples)))
+        make = ServingEngine._init_cache
+        monkeypatch.setattr(
+            ServingEngine, "_init_cache",
+            lambda self, kind: jax.eval_shape(lambda: make(self, kind)))
+        eng = ServingEngine(net, num_slots=128, page_size=64,
+                            num_pages=5120, max_prefill_len=1024,
+                            max_seq_len=5120, kv_dtype="bf16", spec_k=0,
+                            decode_ahead=2)
+        assert [tuple(a.shape) for a in eng._kv[2]] == [(5120, 64, 640)]
+        assert eng._kv[0][0].shape == (129, 32, 128, 128)
+    return _HYBRID_PROGRAMS
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_hybrid_engine_program_updates_every_cache_in_place(
+        chip, monkeypatch, program):
+    """Both kinds of cache are updated where they lie: the per-slot
+    recurrent state (``kda_step`` aliases it; a prefill writes one
+    slot's rows) and the paged latent pool (a 576-value row padded to
+    640 lanes keeps the pool row-major).  No cache-sized ``copy``, no
+    cache-sized temporary, every cache aliased; the decode program
+    holds the three kernels by name."""
+    import re
+    fn, examples = _hybrid_engine_programs(monkeypatch)[program]
+    caches = jax.tree_util.tree_leaves(examples[1])
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+            *jax.tree_util.tree_map(lambda a: chip(a.shape, a.dtype),
+                                    examples)).compile()
+    text = compiled.as_text()
+    if program == "decode":
+        # 2 KDA layers, 1 MLA layer, 2 expert layers of two matmuls
+        assert text.count("tpu_custom_call") >= 7
+    state_elements = 129 * 32 * 128 * 128
+    copies = [line.strip()[:160] for line in text.splitlines()
+              for m in [re.match(r"\s*(?:ROOT )?%(\S+) = \(?\w+"
+                                 r"\[([\d,]*)\]\S* ([\w-]+)\(", line)]
+              if m and m.group(3) not in ("copy-start", "copy-done")
+              and (m.group(3) == "copy" or m.group(1).startswith("copy"))
+              and _elements(m.group(2)) >= state_elements // 2]
+    assert not copies, copies
+    layouts = re.findall(r"\w+\[5120,64,640\](\{[^}]*\})", text)
+    assert layouts and all(l.startswith("{2,1,0") for l in layouts), \
+        sorted(set(layouts))
+    mem = compiled.memory_analysis()
+    # a 1024-token prompt's own activations (the MLA layer's 32 x 1024
+    # x 1024 scores alone are 134 MB) stay under ONE layer's state
+    assert mem.temp_size_in_bytes < state_elements * 4 // (
+        1 if program == "prefill" else 2), mem
+    # (the chip pads the 3-row convolution history to its tile: a
+    # little more is aliased than the arrays' own bytes)
+    assert mem.alias_size_in_bytes >= sum(
+        int(np.prod(a.shape)) * a.dtype.itemsize for a in caches), mem
+
+
 @pytest.mark.parametrize("head_dim,packed", [(64, False), (64, True),
                                              (128, False)])
 def test_flash_fwd_bwd_compiles(chip, head_dim, packed):
