@@ -516,6 +516,15 @@ class ServingEngine:
             8 * self.alloc.kv_itemsize)
         _telemetry.gauge("serving.kv.bytes_per_token").set(
             self.kv_bytes_per_token)
+        #: pages the paged kernel reads a block, for the block_fill
+        #: gauge (None: the model's decode does not run that kernel)
+        self._pages_per_block = None
+        if paged_kv:
+            from ..ops.pallas.paged_attention import pages_per_block
+            pool = self._kv[0][0]
+            self._pages_per_block = pages_per_block(
+                self.page_size, pool.shape[2], pool.dtype,
+                self.max_pages_per_seq)
 
     @staticmethod
     def _env_sampling():
@@ -1543,6 +1552,7 @@ class ServingEngine:
                 reqs.append(req)
             if not reqs:
                 return None
+            self._note_block_fill(positions[active])
             args = (tokens, positions, active,
                     self.sched.block_tables.copy(), self._temps.copy(),
                     self._top_ks.copy(), self._top_ps.copy(),
@@ -1710,6 +1720,7 @@ class ServingEngine:
             if drafted:
                 _telemetry.counter("serving.spec.draft_tokens").inc(
                     drafted)
+            self._note_block_fill((positions[:, 0] + draft_len)[active])
             args = (tokens, positions, active, draft_len,
                     self.sched.block_tables.copy(), self._temps.copy(),
                     self._top_ks.copy(), self._top_ps.copy(),
@@ -1898,6 +1909,19 @@ class ServingEngine:
                 doc["max_tokens_per_expert"] / layers)
             _telemetry.gauge("serving.moe.mean_tokens_per_expert").set(
                 doc["local_assignments"] / doc["held_experts"])
+
+    def _note_block_fill(self, last_pos):
+        """``serving.paged.block_fill`` of the decode step being sent:
+        live pages over the pages of the blocks the paged kernel
+        enters, from each live slot's last key position (the host's own
+        numbers: nothing is fetched)."""
+        if self._pages_per_block is None or not last_pos.size:
+            return
+        pages = last_pos // self.page_size + 1
+        blocks = -(-pages // self._pages_per_block)
+        _telemetry.gauge("serving.paged.block_fill").set(
+            float(pages.sum()) / (int(blocks.sum())
+                                  * self._pages_per_block))
 
     def _publish_gauges(self):
         _telemetry.gauge("serving.batch_occupancy").set(

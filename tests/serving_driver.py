@@ -299,6 +299,10 @@ def check_dispatch_contract_and_telemetry(net):
     assert c["serving.tokens"] == 6 + 3 + 9
     assert rep["gauges"]["serving.batch_occupancy"] == 0  # drained
     assert rep["gauges"]["serving.kv_pages_free"] == eng.alloc.free_pages
+    # the last decode step held one request at key position 9: two live
+    # 8-token pages of the one 4-page block the paged kernel entered
+    assert eng._pages_per_block == 4
+    assert rep["gauges"]["serving.paged.block_fill"] == 0.5
     hists = rep["histograms"]
     assert hists["serving.ttft"]["count"] == 3
     assert hists["serving.tpot"]["count"] == 18 - 3

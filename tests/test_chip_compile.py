@@ -108,6 +108,59 @@ def test_paged_kernel_compiles(chip, kv_dtype, n_q, kv_heads):
                          tables, ctx)
 
 
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for v in eqn.params.values():
+            inner = getattr(v, "jaxpr", v)
+            if hasattr(inner, "eqns"):
+                yield from _pallas_calls(inner)
+
+
+def _tiled_bytes(shape, dtype):
+    """Bytes of a VMEM array: the two minor dimensions padded to the
+    dtype's tile (8 x 128 words of 32 bits)."""
+    item = np.dtype(dtype).itemsize
+    shape = (1, 1) + tuple(shape)
+    sub = 8 * 4 // item
+    return int(np.prod(shape[:-2])) * -(-shape[-2] // sub) * sub \
+        * -(-shape[-1] // 128) * 128 * item
+
+
+def test_paged_kernel_at_the_serving_cell_stays_in_scoped_vmem(chip):
+    """The kernel at the shape ``gpt2m-serve-backlog`` and ``-chat`` run
+    it at: 128 slots, 64 pages a sequence, 5,711 bf16 pages of 16
+    tokens, one query position.  It asks for no VMEM limit of its own,
+    so Mosaic holds it to the default scoped 16 MiB; what the call
+    keeps there (its scratch, and two buffers of every blocked operand)
+    is counted from the call itself and has to leave three quarters of
+    that free, so the pages a block (``pages_per_block``) cannot
+    outgrow it unseen when a pool is wider or a page longer."""
+    args = (chip((128, 1, HEADS, HEAD_DIM), jnp.float32),
+            chip((5711, PAGE, HEADS * HEAD_DIM), jnp.bfloat16),
+            chip((5711, PAGE, HEADS * HEAD_DIM), jnp.bfloat16),
+            chip((128, 64), jnp.int32), chip((128, 1), jnp.int32))
+    compile_for_chip(paged.paged_attention_multi, *args)
+    (call,) = _pallas_calls(
+        jax.make_jaxpr(paged.paged_attention_multi)(*args).jaxpr)
+    assert call.params["compiler_params"]["mosaic_tpu"] \
+        .vmem_limit_bytes is None
+    mapping = call.params["grid_mapping"]
+    assert mapping.grid == (128,)
+    scratch = [v.aval for v in call.params["jaxpr"].invars[
+        -mapping.num_scratch_operands:]]
+    held = sum(_tiled_bytes(a.shape, a.dtype) for a in scratch
+               if "vmem" in str(a))
+    blocks = [m.transformed_block_aval for m in mapping.block_mappings]
+    held += sum(2 * _tiled_bytes(a.shape, a.dtype) for a in blocks
+                if "any" not in str(a))
+    ppb = paged.pages_per_block(PAGE, HEADS * HEAD_DIM, jnp.bfloat16, 64)
+    assert ppb * PAGE == 256
+    # the four page buffers are nearly all of it
+    assert 4 * ppb * PAGE * HEADS * HEAD_DIM * 2 <= held < 4 * 2 ** 20, held
+
+
 # -- the engine's own programs: the pools keep one layout -----------------
 
 _ENGINE_PROGRAMS = {}

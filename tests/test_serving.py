@@ -216,17 +216,19 @@ def test_paged_attention_kernel():
 
 @pytest.mark.parametrize("case", ["mha_decode", "gqa_verify"])
 @pytest.mark.parametrize("kv_dtype", ["fp32", "bf16"])
-def test_paged_kernel_bit_identical_to_the_4d_pool_kernel(kv_dtype, case):
-    """The kernel on the stored ``[num_pages, page, K_kv * D]`` pools is
-    the kernel the parent of PR 25 ran on pools with a KV-head axis of
-    their own: same mathematics, same op order for each head, fp32
-    accumulation.  ``tests/data/paged_kernel_parent.npz`` holds inputs
-    and outputs of commit be26b86's ``paged_attention_multi`` under the
+def test_paged_kernel_reproduces_the_page_at_a_time_kernel(kv_dtype, case):
+    """The block kernel computes what the kernel it replaced computed:
+    ``tests/data/paged_kernel_parent.npz`` holds inputs and outputs of
+    commit be26b86's ``paged_attention_multi`` (one 16-token page a grid
+    cell, a matmul pair a head, online softmax page by page) under the
     interpreter in this suite's configuration (x64 on, fp32 matmuls):
     one query position per slot at ``K_kv == H`` with an empty slot, and
-    four with per-position contexts at ``K_kv == H / 2``.  The pools it
-    read are kept in the stored shape (the same values, heads side by
-    side), bf16 ones as their bits."""
+    four with per-position contexts at ``K_kv == H / 2``.  Same
+    mathematics, fp32 accumulation, another order of the sums: within
+    2e-6 on fp32 pools and on bf16 ones (kept as their bits; the three
+    bf16 pieces of the query and of the weights multiply them
+    exactly).  One query position through ``paged_attention`` is the
+    same launch, byte for byte."""
     import jax.numpy as jnp
     from mxnet_tpu.ops.pallas.paged_attention import (
         paged_attention, paged_attention_multi)
@@ -242,12 +244,170 @@ def test_paged_kernel_bit_identical_to_the_4d_pool_kernel(kv_dtype, case):
     out = np.asarray(paged_attention_multi(
         rec["q"], kp, vp, rec["block_tables"], rec["context_lens"]))
     assert out.dtype == rec["out"].dtype
-    assert out.tobytes() == rec["out"].tobytes()
+    assert np.abs(out - rec["out"]).max() < 2e-6
     if rec["q"].shape[1] == 1:
         one = np.asarray(paged_attention(
             rec["q"][:, 0], kp, vp, rec["block_tables"],
             rec["context_lens"][:, 0]))
-        assert one.tobytes() == rec["out"][:, 0].tobytes()
+        assert one.tobytes() == out[:, 0].tobytes()
+
+
+def _block_edge_case(kv_dtype, n_q, kv_heads, heads, d, page, ppb):
+    """Inputs that stand on every edge of a ``ppb``-page block, clean
+    (for the oracle) and as the engine may leave them (for the kernel).
+
+    Slots, in launch order: empty; a context that ends exactly on a
+    block edge; empty; one token past the edge; inside the first page;
+    a draft cut short (later positions empty); two and a half blocks,
+    which with ``max_pages = 3 * ppb - 1`` ends in a block the table
+    does not fill.  Dirty: every block-table entry past a slot's
+    context is garbage (out of range, negative, or a page of NaN /
+    NaN scales), and the rows of each last page past the context are
+    NaN (fp32, bf16) or 127 (int8)."""
+    import jax.numpy as jnp
+    rng = np.random.RandomState(7 + n_q + kv_heads)
+    t = ppb * page
+    max_pages = 3 * ppb - 1
+    last = [0, t, 0, t + 1, 3, 2 * page + 5, 2 * t + t // 2 + 3]
+    s_n = len(last)
+    ctx = np.zeros((s_n, n_q), np.int32)
+    for s, c in enumerate(last):
+        ctx[s] = np.maximum(c - (n_q - 1) + np.arange(n_q), 0)
+    if n_q > 2:
+        ctx[5, 2:] = 0                      # rows past the draft length
+    width = kv_heads * d
+    need = [-(-int(c.max()) // page) for c in ctx]
+    n_pages = sum(need) + 3
+    perm = rng.permutation(n_pages - 3) + 1
+    poison = n_pages - 2                    # pages n_pages-2, n_pages-1
+    q = rng.randn(s_n, n_q, heads, d).astype(np.float32)
+    clean_bt = np.zeros((s_n, max_pages), np.int32)
+    dirty_bt = np.empty((s_n, max_pages), np.int32)
+    dirty_bt[:] = rng.choice([poison, poison + 1, 2 ** 30, -5, 10 ** 6],
+                             (s_n, max_pages))
+    at = 0
+    for s in range(s_n):
+        clean_bt[s, :need[s]] = perm[at:at + need[s]]
+        dirty_bt[s, :need[s]] = perm[at:at + need[s]]
+        at += need[s]
+    scales = {}
+    if kv_dtype == "int8":
+        pools = [rng.randint(-127, 128, (n_pages, page, width))
+                 .astype(np.int8) for _ in range(2)]
+        scales = {n: (rng.rand(n_pages, kv_heads) / 64 + 1e-3)
+                  .astype(np.float32) for n in ("k_scales", "v_scales")}
+    else:
+        pools = [rng.randn(n_pages, page, width).astype(np.float32)
+                 for _ in range(2)]
+    dirty_pools = [a.copy() for a in pools]
+    dirty_scales = {n: a.copy() for n, a in scales.items()}
+    bad = 127 if kv_dtype == "int8" else np.nan
+    for a in dirty_pools:
+        a[poison:] = bad
+        for s in range(s_n):
+            tail = int(ctx[s].max()) % page
+            if tail:
+                a[clean_bt[s, need[s] - 1], tail:] = bad
+    for a in dirty_scales.values():
+        a[poison:] = np.nan
+    as_pool = (lambda a: jnp.asarray(a, jnp.bfloat16)) \
+        if kv_dtype == "bf16" else jnp.asarray
+    clean = (q, as_pool(pools[0]), as_pool(pools[1]), clean_bt, ctx)
+    dirty = (q, as_pool(dirty_pools[0]), as_pool(dirty_pools[1]),
+             dirty_bt, ctx)
+    return clean, scales, dirty, dirty_scales
+
+
+@pytest.mark.parametrize("block_tokens", [None, 32])
+@pytest.mark.parametrize("kv_heads", [8, 2])
+@pytest.mark.parametrize("n_q", [1, 5])
+@pytest.mark.parametrize("kv_dtype", ["fp32", "bf16", "int8"])
+def test_paged_kernel_on_every_edge_of_a_block(monkeypatch, kv_dtype, n_q,
+                                               kv_heads, block_tokens):
+    """The block kernel against the jnp oracle within 2e-6, in every
+    page format, at one and five query positions, with ``K_kv = H`` and
+    ``H / 4``: contexts that end exactly on a block edge, one token
+    past it, inside the first page and at 0 (zeros out), a table whose
+    ``max_pages`` is no multiple of the block, empty slots between live
+    ones (each cell starts its successor's first copies), and garbage
+    wherever the kernel must not look (``_block_edge_case``): a page
+    past the context is never read, and what a read page holds past the
+    context never reaches the output, NaN included.  Once at the block
+    the kernel derives (256 tokens of 16-token pages) and once steered
+    to 32 tokens of 8-token pages, where a block is no whole lane tile."""
+    import importlib
+    paged = importlib.import_module("mxnet_tpu.ops.pallas.paged_attention")
+    heads, d, page = 8, 32, 16
+    if block_tokens:
+        monkeypatch.setattr(paged, "_BLOCK_TOKENS", block_tokens)
+        d, page = 16, 8
+    import jax.numpy as jnp
+    ppb = paged.pages_per_block(
+        page, kv_heads * d, {"fp32": jnp.float32, "bf16": jnp.bfloat16,
+                             "int8": jnp.int8}[kv_dtype])
+    assert ppb * page == (block_tokens or 256)
+    clean, scales, dirty, dirty_scales = _block_edge_case(
+        kv_dtype, n_q, kv_heads, heads, d, page, ppb)
+    assert clean[3].shape[1] % ppb
+    ref = np.asarray(paged.paged_attention_multi_reference(
+        *clean, **scales))
+    assert np.isfinite(ref).all()
+    for args, sc in ((clean, scales), (dirty, dirty_scales)):
+        out = np.asarray(paged.paged_attention_multi(*args, **sc))
+        assert np.isfinite(out).all()
+        assert np.abs(out - ref).max() < 2e-6
+        assert (out[clean[4] == 0] == 0).all()
+    if n_q == 1:
+        one = np.asarray(paged.paged_attention(
+            dirty[0][:, 0], *dirty[1:4], dirty[4][:, 0], **dirty_scales))
+        assert one.tobytes() == out[:, 0].tobytes()
+
+
+@pytest.mark.parametrize("page,width,kv_dtype,max_pages,want", [
+    (16, 1024, "bf16", 64, 16),     # the benchmark's pools: 256 tokens
+    (16, 1024, "fp32", 64, 16),
+    (16, 1024, "int8", 64, 16),
+    (16, 4096, "bf16", 64, 8),      # 32 heads of 128: the VMEM budget
+    (16, 8192, "fp32", 64, 2),
+    (64, 640, "bf16", 80, 4),       # longer pages, fewer of them
+    (512, 1024, "bf16", 8, 1),      # a page is never split
+    (16, 1024, "bf16", 3, 3),       # nor a sequence's table outrun
+    (8, 128, "fp32", None, 32),
+])
+def test_pages_per_block_follows_the_shapes(page, width, kv_dtype,
+                                            max_pages, want):
+    """``P`` comes from the page size, the pool's width and dtype and
+    the table's length, against a VMEM budget: no caller picks it."""
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.pallas.paged_attention import (
+        _BLOCK_VMEM_BYTES, pages_per_block)
+    dt = {"fp32": jnp.float32, "bf16": jnp.bfloat16,
+          "int8": jnp.int8}[kv_dtype]
+    got = pages_per_block(page, width, dt, max_pages)
+    assert got == want
+    assert got == 1 or 4 * got * page * width * jnp.dtype(dt).itemsize \
+        <= _BLOCK_VMEM_BYTES
+
+
+@pytest.mark.parametrize("n_kv,rows,d,pieces,want", [
+    (16, 1, 64, 3, 16),     # decode: every head in one matmul (48 rows)
+    (16, 5, 64, 3, 8),      # verify at spec_k 4: eight heads, 120 rows
+    (16, 5, 64, 1, 16),     # the same on fp32 pools: one piece
+    (4, 4, 64, 3, 4),       # grouped-query decode, H / 4
+    (4, 20, 64, 3, 2),      # and verify: one 128-lane tile of heads
+    (16, 17, 64, 3, 2),     # spec_k 16
+    (8, 8, 128, 3, 4),      # D 128: any count of heads is whole tiles
+    (3, 1, 16, 3, 3),       # widths the interpreter alone sees
+    (2, 40, 16, 3, 2),
+])
+def test_heads_per_group_follows_the_shapes(n_kv, rows, d, pieces, want):
+    """How many KV heads one block-diagonal matmul scores: all while
+    their query rows stay under an MXU tile, else whole 128-lane tiles
+    of heads; from static shapes, never a flag."""
+    from mxnet_tpu.ops.pallas.paged_attention import _heads_per_group
+    hb = _heads_per_group(n_kv, rows, d, pieces)
+    assert hb == want and n_kv % hb == 0
+    assert hb == n_kv or (hb * d) % 128 == 0
 
 
 def test_paged_kernel_refuses_a_pool_that_is_not_flat():
