@@ -139,11 +139,15 @@ def test_dealt_blocks_hold_the_whole_distribution():
 def test_traffic_clips_and_alignment(mix_name):
     mix = load("traffic", mix_name + ".json")
     page = 16
+    # a request has to fit the engines it is offered to
+    longest = min(
+        load("workloads", w["name"] + ".json")["engine"]["max_seq_len"]
+        for w in BENCH["workloads"] if w["traffic"] == mix_name)
     rows = take(trafficgen.requests(mix, 11, 50257, page, 4), 2 * mix["pool"])
     for due, prompt, max_new in rows:
         assert 1 <= prompt.size <= mix["prompt_max_total"]
         assert 1 <= max_new <= mix["output"]["max"]
-        assert prompt.size + max_new <= 1024
+        assert prompt.size + max_new <= longest
         assert prompt.dtype == np.int32 and 0 <= prompt.min() \
             and prompt.max() < 50257
     dues = [r[0] for r in rows]
@@ -234,6 +238,13 @@ def test_everything_named_resolves_to_a_file():
                    and w["name"] in m.get("workloads", CELLS)) >= 1
         assert any(w["name"] in m.get("workloads", CELLS)
                    for m in BENCH["per_layer"])
+    # a backlog is one kind of deployment: its cells set the engine's
+    # host loop up alike (SERVING.md section 3)
+    ahead = {w["name"]: load("workloads", w["name"] + ".json")["engine"]
+             .get("decode_ahead", 0) for w in BENCH["workloads"]
+             if load("traffic", w["traffic"] + ".json").get(
+                 "arrivals", {}).get("process") == "backlog"}
+    assert len(set(ahead.values())) == 1, ahead
     for m in BENCH["per_layer"]:
         spec = load("layer_metrics", m["name"] + ".json")
         assert os.path.exists(os.path.join(
