@@ -228,6 +228,7 @@ def main():
             rec_iter.reset()
             return next(rec_iter)
 
+    accs = []
     for step in range(args.steps):
         batch = next_batch(step)
         t0 = time.time()
@@ -241,6 +242,7 @@ def main():
             pred = cls_prob.argmax(axis=1)
             acc = (pred[mask[:, :]] == cls_target[mask]).mean() \
                 if mask.any() else 0.0
+            accs.append(acc)
             print("step %d anchor-cls acc %.3f (%.2fs)"
                   % (step, acc, time.time() - t0))
     # final detection sanity: run the detect head
@@ -251,7 +253,11 @@ def main():
         best = det[b, det[b, :, 1].argmax()]
         print("  img%d:" % b, best)
     if args.steps >= 100:
-        assert acc > 0.75, "SSD anchor classification failed to learn"
+        # the last five samples, not the one batch that happens to be
+        # sampled last: a batch of 8 moves the accuracy by 0.05
+        last = float(np.mean(accs[-5:]))
+        print("mean anchor-cls acc of the last 5 samples %.3f" % last)
+        assert last > 0.75, "SSD anchor classification failed to learn"
     print("SSD OK")
 
 

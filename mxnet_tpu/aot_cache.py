@@ -101,7 +101,7 @@ DEFAULT_JAX_CACHE_DIR = os.path.join(
 
 def enable_persistent_cache():
     """Place jax's persistent compilation cache before the first
-    compile (entry scripts call this: chip_smoke.py, bench.py,
+    compile (entry scripts call this: chip_smoke.py,
     tools/serve_worker.py).  Where the environment sets
     ``JAX_COMPILATION_CACHE_DIR`` jax already uses it and nothing is
     set in code; otherwise the cache goes to

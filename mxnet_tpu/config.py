@@ -45,18 +45,6 @@ _register("MXTPU_WORKER_RANK", 0, int,
 _register("MXTPU_ATTENTION_IMPL", "", str,
           "'flash' forces the Pallas attention kernel, 'xla' the jnp "
           "online-softmax path; empty auto-selects (flash on TPU).")
-_register("MXTPU_FLASH_BWD", "split", str,
-          "flash-attention backward: 'split' = separate dq and dk/dv "
-          "kernels (measured round-3 baseline), 'fused' = single-pass "
-          "kernel sharing the s/dp matmuls (1.4x backward FLOP cut; "
-          "measured 5-8% slower than split, PERF.md).")
-_register("MXTPU_FLASH_BWD_DQ_BYTES", 1 << 30, int,
-          "HBM cap for the fused backward's fp32 dq-partial buffer; the "
-          "k axis is chunked to stay under it (unbounded it grows "
-          "quadratically with T).  Falls back to 'split' when one "
-          "k-block slot exceeds the budget OR the budget would need "
-          ">16 sequential chunks — so a too-small budget silently "
-          "benchmarks split, not fused.")
 _register("MXNET_CPU_WORKER_NTHREADS", 1, int,
           "host-side worker threads for the Python image pipeline "
           "(image/image.py); the native pipeline uses "
@@ -75,35 +63,6 @@ _register("MXTPU_CHECKPOINT_FORMAT", "binary", str,
           "'binary' writes reference-compatible V2 .params files "
           "(ndarray/serialization.py); 'npz' writes the rounds-1/2 "
           "container. Loading auto-detects either.")
-# bench knobs (bench.py) — documented here, read there
-_register("BENCH_BATCH", 128, int, "bench.py: per-step batch size.")
-_register("BENCH_STEPS", 20, int, "bench.py: timed steps.")
-_register("BENCH_WARMUP", 3, int, "bench.py: warmup steps.")
-_register("BENCH_IMAGE", 224, int,
-          "bench.py: image edge length (default 299 for inception_v3).")
-_register("BENCH_DTYPE", "bfloat16", str,
-          "bench.py: bfloat16|float32 (default bfloat16 on TPU).")
-_register("BENCH_MODE", "", str,
-          "bench.py: '' = model-zoo training throughput (BENCH_NETWORK "
-          "selects the net); 'attention' = flash attention TFLOP/s "
-          "micro-benchmark; 'pipeline' = native input pipeline img/s.")
-_register("BENCH_COST_ANALYSIS", 0, int,
-          "bench.py: 1 = FLOPs from XLA cost analysis (a second, AOT "
-          "compile) instead of the analytic count.")
-_register("BENCH_NETWORK", "resnet50_v1", str,
-          "bench.py: model_zoo network to train (resnet18/34/50/101/"
-          "152_v1, inception_v3, alexnet, vgg16, densenet121, "
-          "squeezenet1_0); per-network K80 baselines from the reference "
-          "README drive vs_baseline.")
-_register("BENCH_PROFILE", "", str,
-          "bench.py: directory to write a jax.profiler trace of the "
-          "timed loop (tensorboard-compatible); empty disables.")
-_register("BENCH_PIPE_THREADS", 8, int,
-          "bench.py pipeline mode: decode/augment thread-pool size.")
-_register("BENCH_PIPE_IMAGES", 2000, int,
-          "bench.py pipeline mode: synthetic .rec image count.")
-_register("BENCH_PIPE_EPOCHS", 3, int,
-          "bench.py pipeline mode: timed epochs over the .rec.")
 
 #: reference knobs with no counterpart here, and where the concern went.
 #: (docs/how_to/env_var.md names; listed so migrating users can grep.)

@@ -148,14 +148,6 @@ Sites wired in this package:
                           re-poll at the SAME cursor recovers exactly
                           the tokens the dropped reply carried — no
                           gap, no duplicate (ISSUE 19).
-- ``serve.client.vanish`` a streaming client goes silent mid-stream
-                          (its poller loop stops polling, the process
-                          lives on): after ``MXTPU_SERVE_ABANDON_S``
-                          without a poll the engine reclaims the
-                          request with the typed ``abandoned`` verdict
-                          — slot + KV pages released, conservation
-                          audit green — so a vanished client can never
-                          pin the pool to the end of ``max_new``.
 
 The ``*.slow`` DELAY sites are per-event and bounded (the run limps,
 correctly); the ``*.stall``/``kv.hang`` sites simulate HANGS — they

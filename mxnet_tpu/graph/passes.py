@@ -59,7 +59,7 @@ _OFF_VALUES = ("0", "off", "none", "false")
 _PASSES = {}
 _warned_unknown = set()
 
-#: the most recent optimize() report (graph_probe / debugging)
+#: the most recent optimize() report (debugging)
 _last_report = None
 
 

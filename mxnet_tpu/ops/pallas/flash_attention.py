@@ -277,7 +277,7 @@ def _bwd_block_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     mask, scale):
     """Shared backward block math for one (q-block, k-block) pair:
     recompute p = exp(S − lse) under ``mask`` and ds = p·(dO·Vᵀ − Δ).
-    All three backward kernels (dq, dk/dv, fused) consume these; the
+    Both backward kernels (dq, dk/dv) consume these; the
     explicit p zeroing handles rows whose lse is the padding sentinel
     (exp(−inf − (−inf)) would be 1)."""
     q = q_ref[0].astype(jnp.float32)
@@ -397,221 +397,6 @@ def _bwd_dkv_kernel(*refs, scale, causal, tq_true, has_seg=False):
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _bwd_fused_kernel(*refs, scale, causal, tq_true, tk_true, k_base=0,
-                      has_seg=False):
-    """Fused backward: one grid pass (bh, k-blocks, q-blocks) computes
-    dq, dk AND dv.  Per (q,k) block pair the split kernels spend 7 MXU
-    matmuls (s and dp are computed twice); fusing shares them — 5
-    matmuls/pair, a 1.4x FLOP cut on the backward (the PERF.md §6 gap).
-
-    dk/dv accumulate in VMEM scratch across the sequential q sweep.  dq
-    blocks would be revisited once per outer k step, NON-consecutively —
-    which no TPU-grid accumulator expresses soundly (output revisits
-    don't reload, and input/output aliases snapshot their input) — so
-    each (k,q) step writes its dq contribution to its own fp32 partial
-    slot and the caller reduces over the nk axis.  Extra HBM traffic is
-    O(nk·Tq·D) written + read once, the same volume the split dq kernel
-    re-read k/v with.  The caller bounds that partial buffer by chunking
-    the k axis (``k_base`` is this call's absolute k offset, so the
-    causal/bounds masks stay exact across chunks)."""
-    pl = _pl()
-    if has_seg:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qs_ref,
-         ks_ref, dq_ref, dk_ref, dv_ref, dk_acc, dv_acc) = refs
-    else:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-         dq_ref, dk_ref, dv_ref, dk_acc, dv_acc) = refs
-        qs_ref = ks_ref = None
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
-    nq = pl.num_programs(2)
-    bk = k_ref.shape[1]
-    bq = q_ref.shape[1]
-    k_off = k_base + ki * bk
-    q_off = qi * bq
-
-    @pl.when(qi == 0)
-    def _init_kv():
-        dk_acc[...] = jnp.zeros_like(dk_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
-
-    # every slot is written exactly once; fully-skipped causal pairs
-    # still need their zero
-    dq_ref[0, 0] = jnp.zeros_like(dq_ref[0, 0])
-
-    def _accumulate():
-        # both bounds masks: padded q rows must not touch dk/dv, padded
-        # k columns must not touch dq (belt over the zero-pad brace)
-        mask = _q_bounds_mask(q_off, bq, bk, tq_true)
-        mask &= _kv_bounds_mask(k_off, bq, bk, tk_true)
-        if causal:
-            mask &= _causal_mask(q_off, k_off, bq, bk)
-        if has_seg:
-            mask &= _segment_mask(qs_ref, ks_ref)
-        p, ds, q, k, do = _bwd_block_p_ds(q_ref, k_ref, v_ref, do_ref,
-                                          lse_ref, delta_ref, mask, scale)
-        dv_acc[...] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dk_acc[...] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        dq_ref[0, 0] = jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-
-    if causal:
-        pl.when(q_off + bq - 1 >= k_off)(_accumulate)
-    else:
-        _accumulate()
-
-    @pl.when(qi == nq - 1)
-    def _emit():
-        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
-
-
-def _dq_partial_budget():
-    """HBM byte cap for the fused backward's dq partial buffer
-    (MXTPU_FLASH_BWD_DQ_BYTES, default in the config registry).
-    Unbounded, the buffer is O(nk·B·H·Tq·D) fp32 — quadratic in T —
-    which at T=32k B1 H8 D128 block 512 would be ~8.6 GB, most of a
-    v5e's 16 GB HBM."""
-    from mxnet_tpu import config
-    return int(config.flag("MXTPU_FLASH_BWD_DQ_BYTES"))
-
-
-#: Past this many k-chunks the fused path falls back to split: each
-#: chunk is a separately-traced pallas_call (compile size grows with the
-#: count) and re-reads all of q/do/lse/delta, eroding the shared-matmul
-#: FLOP win the fusion exists for.
-_MAX_DQ_CHUNKS = 16
-
-
-def _flash_bwd_fused(res, g, scale, causal, block_q, block_k, h=1):
-    """Single-pass fused backward; dq comes out as fp32 partials reduced
-    by XLA after the kernel.  The k axis is chunked so at most
-    ``MXTPU_FLASH_BWD_DQ_BYTES`` of partials exist at once: each chunk
-    runs the fused kernel over its k-blocks (dk/dv for those blocks come
-    out final; dq contributions are reduced and accumulated across
-    chunks).  Falls back to split when even one k-block's partial slot
-    exceeds the budget (no memory advantage left) or when the budget
-    would need more than _MAX_DQ_CHUNKS sequential kernel launches
-    (compile size and q/do re-reads erode the fusion win)."""
-    pl = _pl()
-    q, k, v, out, lse, qseg, kseg = _unpack_res(res)
-    do = g
-    bh, tq, d = q.shape
-    tk = k.shape[1]
-    dv_dim = v.shape[2]
-    block_q = min(block_q, tq)
-    block_k = min(block_k, tk)
-
-    # regime check BEFORE any padding/delta work so the fallback path
-    # computes nothing it throws away
-    tqp = -(-tq // block_q) * block_q
-    tkp = -(-tk // block_k) * block_k
-    nk = tkp // block_k
-    slot_bytes = bh * tqp * d * 4
-    chunk_nk = min(nk, _dq_partial_budget() // slot_bytes)
-    if chunk_nk < 1 or -(-nk // chunk_nk) > _MAX_DQ_CHUNKS:
-        return _flash_bwd_split(res, g, scale, causal, block_q, block_k,
-                                h=h)
-
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1, keepdims=True)
-    qp = _pad_to(q, 1, block_q)
-    dop = _pad_to(do, 1, block_q)
-    lsep = _pad_to(lse, 1, block_q)
-    deltap = _pad_to(delta, 1, block_q)
-    kp = _pad_to(k, 1, block_k)
-    vp = _pad_to(v, 1, block_k)
-    has_seg = qseg is not None
-    qsegp = _pad_to_val(qseg, 1, block_q, -2) if has_seg else None
-    ksegp = _pad_to_val(kseg, 1, block_k, -1) if has_seg else None
-
-    def _fused_call(kc, vc, ksegc, nk_c, k_base):
-        in_specs = [
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, dv_dim), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, dv_dim), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, j, 0)),
-        ]
-        operands = [qp, kc, vc, dop, lsep, deltap]
-        if has_seg:
-            specs, ops = _seg_operands(qsegp, ksegc, block_q, block_k,
-                                       h, 2)
-            in_specs += specs
-            operands += ops
-        return _pallas_call(
-            functools.partial(_bwd_fused_kernel, scale=scale,
-                              causal=causal, tq_true=tq, tk_true=tk,
-                              k_base=k_base, has_seg=has_seg),
-            operands,
-            name="flash_bwd_fused",
-            grid=(bh, nk_c, tqp // block_q),
-            in_specs=in_specs,
-            out_specs=[
-                pl.BlockSpec((1, 1, block_q, d),
-                             lambda b, i, j: (i, b, j, 0)),
-                pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((1, block_k, dv_dim),
-                             lambda b, i, j: (b, i, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((nk_c, bh, tqp, d), jnp.float32),
-                jax.ShapeDtypeStruct((bh, nk_c * block_k, d), k.dtype),
-                jax.ShapeDtypeStruct((bh, nk_c * block_k, dv_dim),
-                                     v.dtype),
-            ],
-            scratch_shapes=[_scratch((block_k, d)),
-                            _scratch((block_k, dv_dim))],
-        )
-
-    dq_acc = None
-    dk_chunks, dv_chunks = [], []
-    for start in range(0, nk, chunk_nk):
-        nk_c = min(chunk_nk, nk - start)
-        lo, hi = start * block_k, (start + nk_c) * block_k
-        if dq_acc is not None:
-            # chunk kernels share no data, so without this barrier XLA's
-            # scheduler could run them concurrently and keep several
-            # dq_parts buffers live at once — the byte cap must bound
-            # PEAK HBM, so chunk i+1 is made to depend on chunk i's
-            # reduced dq
-            qp, dq_acc = lax.optimization_barrier((qp, dq_acc))
-        dq_parts, dk_c, dv_c = _fused_call(
-            kp[:, lo:hi], vp[:, lo:hi],
-            ksegp[:, lo:hi] if has_seg else None, nk_c, k_base=lo)
-        dq_c = dq_parts.sum(axis=0)
-        dq_acc = dq_c if dq_acc is None else dq_acc + dq_c
-        dk_chunks.append(dk_c)
-        dv_chunks.append(dv_c)
-    dq = dq_acc[:, :tq].astype(q.dtype)
-    dk = (dk_chunks[0] if len(dk_chunks) == 1
-          else jnp.concatenate(dk_chunks, axis=1))
-    dv = (dv_chunks[0] if len(dv_chunks) == 1
-          else jnp.concatenate(dv_chunks, axis=1))
-    return dq, dk[:, :tk], dv[:, :tk]
-
-
-def _bwd_impl():
-    """MXTPU_FLASH_BWD=fused|split.  Default split: measured 5-8%
-    faster than fused on the chip at both shapes tried (PERF.md §6)."""
-    from mxnet_tpu import config
-    return config.flag("MXTPU_FLASH_BWD")
-
-
-def _flash_bwd(res, g, scale, causal, block_q, block_k, h=1):
-    if _bwd_impl() == "fused":
-        return _flash_bwd_fused(res, g, scale, causal, block_q, block_k,
-                                h=h)
-    return _flash_bwd_split(res, g, scale, causal, block_q, block_k,
-                            h=h)
-
-
 def _unpack_res(res):
     """(q, k, v, out, lse[, qseg, kseg]) -> 7-tuple with None segs."""
     if len(res) == 7:
@@ -620,7 +405,7 @@ def _unpack_res(res):
     return q, k, v, out, lse, None, None
 
 
-def _flash_bwd_split(res, g, scale, causal, block_q, block_k, h=1):
+def _flash_bwd(res, g, scale, causal, block_q, block_k, h=1):
     pl = _pl()
     q, k, v, out, lse, qseg, kseg = _unpack_res(res)
     do = g

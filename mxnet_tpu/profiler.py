@@ -158,7 +158,8 @@ class _timed(object):
 # equivalent health metric is "how many compiled programs did this batch
 # dispatch, and did any of them recompile".  The fused fit path targets
 # exactly ONE dispatch per steady-state step (vs N params + 1 today), and
-# these counters are how bench.py / tools/perf_probe/steptrace.py prove it.
+# these counters are how tests/test_fused_step.py and
+# tools/perf_probe/steptrace.py prove it.
 _step_lock = threading.Lock()
 _dispatch_count = 0
 _compile_count = 0
@@ -262,7 +263,7 @@ def instrument(fn, first_call_compiles=True):
     compiled — an AOT executable deserialized from the warm-start cache
     (executor.make_fit_step): its first call dispatches without
     compiling, and charging a phantom compile would hide exactly the
-    warm-vs-cold signal BENCH_MODE=restart measures.
+    warm-vs-cold signal tests/test_aot_warmstart.py reads.
 
     Steady-state recompiles — the cache key silently missing after
     warmup, the exact failure the 1-compile contract exists to catch —

@@ -105,12 +105,13 @@ Capacity multipliers (ISSUE 15):
   kernels; scores/softmax/output stay fp32).  Composes
   multiplicatively with GQA and prefix sharing.  Quantized greedy
   streams are pinned to THEMSELVES across churn/hot-swap/failover —
-  NOT bit-identical to fp32 (run_kvq's token-match-rate and
-  kernel-vs-oracle gates pin the error).  int8 decode carries a
+  NOT bit-identical to fp32 (the kernel-vs-oracle tolerance and
+  ``check_kvq_greedy_match_rate_vs_fp`` in tests/serving_driver.py
+  pin the error).  int8 decode carries a
   per-slot finite mask — the divergence guard behind the
   ``serve.kv.scale_poison`` drill (victims re-prefill in place).
-  Telemetry: ``serving.kv.{dtype,bytes_per_token,quant_error}``
-  gauges + ``serving.kv.scale_repairs``;
+  Telemetry: ``serving.kv.{dtype,bytes_per_token}`` gauges +
+  ``serving.kv.scale_repairs``;
 - **per-request sampling decode** — temperature/top-k/top-p as
   per-SLOT program inputs plus a seeded per-slot PRNG key advanced
   functionally inside the donated step: same (seed, params, prompt) ->
@@ -127,10 +128,7 @@ advanced trace (hot-path: a single tuple append, same discipline as the
 flight recorder), a ``swap`` pause event naming the resident traces it
 interrupted, and exactly one terminal ``verdict`` event (``final`` when
 this engine owns the trace).  ``serving.goodput`` counts tokens on
-requests that COMPLETED within deadline (vs raw ``serving.tokens``),
-and the compiled decode/prefill programs' ``cost_analysis`` is
-published as ``serving.cost.{decode,prefill}.*`` gauges — joined by
-``tools/perf_probe/serve_report.py`` into flops-and-bytes-per-token.
+requests that COMPLETED within deadline (vs raw ``serving.tokens``).
 """
 from __future__ import annotations
 
@@ -299,7 +297,7 @@ class ServingEngine:
         # sharing.  Quantized greedy streams are pinned to THEMSELVES
         # across churn/hot-swap/failover — bit-identity to the fp32
         # path is explicitly NOT the law (a kernel-vs-oracle tolerance
-        # and the run_kvq token-match-rate gate pin the error instead).
+        # and a token-match-rate test pin the error instead).
         # Explicit arg wins; env opt-in via MXTPU_SERVE_KV_DTYPE.
         if kv_dtype is None:
             kv_dtype = os.environ.get("MXTPU_SERVE_KV_DTYPE") or None
@@ -486,10 +484,6 @@ class ServingEngine:
         #: checkpoint epoch currently serving (set by swap_params; the
         #: periodic serving status line carries it)
         self.weights_epoch = None
-        #: per-program compile-time cost attribution (flops / bytes per
-        #: execution), best-effort from the backend's cost_analysis
-        self.cost = {}
-
         self._kv = self._init_pages()
         #: what the last prefill / decode dispatch returned beside its
         #: tokens, still on the device: ``(logits, aux)`` of a model
@@ -839,7 +833,6 @@ class ServingEngine:
             key = _aot.cache_key("serve_" + name, examples, extra=extra)
             memo = _aot.memo_get(key)
             if memo is not None:
-                self._capture_cost(name, memo)
                 return _profiler.instrument(memo,
                                             first_call_compiles=False)
             if _aot.enabled():
@@ -848,7 +841,6 @@ class ServingEngine:
                     compiled, var, _meta = loaded
                     from .. import watchdog as _watchdog
                     _watchdog.note_warm_start()
-                    self._capture_cost(name, compiled)
                     if var == _aot.VARIANT_DONATED:
                         _aot.memo_put(key, compiled)
                         return _profiler.instrument(
@@ -864,7 +856,6 @@ class ServingEngine:
             with _telemetry.span("serving.compile", cat="serving"):
                 with _aot.bypass_persistent_cache():
                     compiled = mk_jit().lower(*examples).compile()
-            self._capture_cost(name, compiled)
             _aot.memo_put(key, compiled)
             if _aot.enabled():
                 _aot.spawn_variant_store(mk_jit, examples, key,
@@ -883,37 +874,6 @@ class ServingEngine:
                 type(e).__name__, e)
             return _profiler.instrument(
                 _aot.donation_cache_guard(mk_jit()))
-
-    def _capture_cost(self, name, compiled):
-        """Best-effort compile-time cost attribution of one serving
-        program (the executor._analyze_compiled move, serving flavor):
-        flops / bytes-accessed PER EXECUTION from the backend's own
-        accounting, published as ``serving.cost.<prog>.*`` gauges and
-        kept on ``self.cost`` — serve_report joins these with the
-        measured token counters into flops-and-bytes-per-token, the
-        objective the ROADMAP-item-2 autotuner optimizes.  A backend or
-        cache tier that reports nothing yields nothing, never an
-        error."""
-        try:
-            ca = compiled.cost_analysis()
-            if isinstance(ca, (list, tuple)):
-                ca = ca[0] if ca else {}
-            if not ca:
-                return
-            doc = {}
-            for key, field in (("flops", "flops"),
-                               ("bytes accessed", "bytes_accessed"),
-                               ("transcendentals", "transcendentals")):
-                v = ca.get(key)
-                if v is not None:
-                    doc[field] = float(v)
-                    _telemetry.gauge(
-                        "serving.cost.%s.%s" % (name, field)).set(
-                        float(v))
-            if doc:
-                self.cost[name] = doc
-        except Exception:
-            pass
 
     # -- request intake ----------------------------------------------------
     def submit(self, prompt, max_new, deadline_s=None, trace=None,
@@ -1234,8 +1194,8 @@ class ServingEngine:
 
     def _note_prefix_admission(self, req):
         """The prefix-cache accounting for one admission (hit/miss
-        split, shared-page and COW counters, prefilled-token counter —
-        the quantity the BENCH_MODE=serve prefix contract bounds)."""
+        split, shared-page and COW counters, prefilled-token counter:
+        ``check_prefix_sharing_and_cow`` pins a hit to its suffix)."""
         suffix = int(req.prompt.size) - req.prefix_len
         _telemetry.counter("serving.prefill_tokens").inc(suffix)
         if self._prefix is None:
@@ -2102,7 +2062,6 @@ class ServingEngine:
                          else False),
             "slo": (self._slo.state() if self._slo is not None
                     else None),
-            "cost": self.cost or None,
         }
 
     # -- convenience -------------------------------------------------------

@@ -30,7 +30,7 @@ least-loaded live replica.  The survivability contract:
   fresh replica on failover (the PR-6 elastic replace move).  With a
   shared AOT cache / in-process memo the replacement comes up warm: 0
   foreground compiles before its first token (asserted by
-  ``BENCH_MODE=serve``'s degraded-mode contract);
+  tests/serving_surv_driver.py ``section_router``);
 - **fencing** (ISSUE 17) — every placement is stamped with the
   target's incarnation and the slot's fencing epoch; a failover bumps
   the victim slot's epoch and enrolls the abandoned handles in a

@@ -943,8 +943,7 @@ class StreamLoader:
                 # the FIRST wait of an iteration covers ramp-up —
                 # startup, not steady state (the steptrace warmup
                 # convention); it gets its own phase so the p99 of
-                # io.queue_wait states the steady-state starvation
-                # contract BENCH_MODE=stream asserts
+                # io.queue_wait states steady-state starvation
                 _telemetry.observe_phase(
                     "io.pool_spinup" if first_wait else "io.queue_wait",
                     dt)

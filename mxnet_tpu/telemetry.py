@@ -79,8 +79,7 @@ OBSERVABILITY.md is the metric-name / span-name / schema contract.
 Env vars: ``MXTPU_TELEMETRY``, ``MXTPU_POSTMORTEM_DIR``,
 ``MXTPU_FLIGHT_RECORDER_STEPS`` (ring size, default 64),
 ``MXTPU_REQUEST_TRACE_EVENTS`` (request-event ring size, default 8192),
-``MXTPU_TELEMETRY_OFF=1`` (disable hot-path recording; the A/B side of
-``BENCH_MODE=telemetry``'s overhead measurement).
+``MXTPU_TELEMETRY_OFF=1`` (disable hot-path recording).
 """
 from __future__ import annotations
 
@@ -127,8 +126,7 @@ _DISABLED = os.environ.get("MXTPU_TELEMETRY_OFF", "0") == "1"
 
 def set_enabled(flag):
     """Toggle hot-path recording (spans, per-step records).  Registry
-    objects stay queryable either way; BENCH_MODE=telemetry flips this
-    to measure the always-on overhead against a dark run."""
+    objects stay queryable either way."""
     global _DISABLED
     _DISABLED = not flag
 
@@ -500,7 +498,7 @@ _perf_base = time.perf_counter_ns()
 # of _PENDING_MAX (or on any read).  Batching exists for the <1%-of-a-
 # fused-step budget: folding touches a dozen Python objects, and doing
 # that once per 128 steps with hot caches costs a fraction of doing it
-# per step cold (BENCH_MODE=telemetry measures the result).
+# per step cold.
 _pending_steps = []
 _PENDING_MAX = 128
 _drain_lock = threading.Lock()
@@ -738,8 +736,7 @@ def mint_trace():
 def note_request_event(trace, event, t_ns=None, args=None):
     """Record one request-lifecycle event.  Hot-path discipline matches
     :func:`note_train_step`: one tuple append, everything else deferred
-    to the batched drain (``BENCH_MODE=serve`` asserts the per-decode-
-    step budget).  ``trace=""`` marks an engine-scope event (a hot-swap
+    to the batched drain.  ``trace=""`` marks an engine-scope event (a hot-swap
     pause naming the resident traces in ``args``); ``t_ns`` is a
     ``perf_counter_ns`` stamp (defaults to now — pass the step's
     existing stamp on hot paths to skip the clock read)."""

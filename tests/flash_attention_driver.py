@@ -161,9 +161,9 @@ def check_op_and_layer_flash():
 
 
 def check_segment_packing():
-    """Sequence-packing mask (segment_ids): fwd and both backward
-    implementations match the masked oracle, causal and not, including
-    a padding segment and odd lengths."""
+    """Sequence-packing mask (segment_ids): forward and backward match
+    the masked oracle, causal and not, including a padding segment and
+    odd lengths."""
     for causal in (False, True):
         b, h, t, d = 2, 2, 64, 16
         q, k, v = (_rand((b, h, t, d), i + 60) for i in range(3))
@@ -177,22 +177,16 @@ def check_segment_packing():
                                         segment_ids=seg)
         assert np.abs(np.asarray(out) - np.asarray(ref)).max() < 2e-5
         tgt = _rand((b, h, t, d), 69)
-        for bwd in ("split", "fused"):
-            os.environ["MXTPU_FLASH_BWD"] = bwd
-            try:
-                g_f = jax.grad(lambda q, k, v: jnp.sum((flash_attention(
-                    q, k, v, causal=causal, segment_ids=seg, block_q=32,
-                    block_k=32) - tgt) ** 2), argnums=(0, 1, 2))(q, k, v)
-            finally:
-                os.environ.pop("MXTPU_FLASH_BWD", None)
-            g_r = jax.grad(
-                lambda q, k, v: jnp.sum((flash_attention_reference(
-                    q, k, v, causal=causal, segment_ids=seg) - tgt) ** 2),
-                argnums=(0, 1, 2))(q, k, v)
-            for gf, gr, name in zip(g_f, g_r, "qkv"):
-                err = np.abs(np.asarray(gf) - np.asarray(gr)).max()
-                assert err < 5e-4, ("seg grad d%s" % name, causal, bwd,
-                                    err)
+        g_f = jax.grad(lambda q, k, v: jnp.sum((flash_attention(
+            q, k, v, causal=causal, segment_ids=seg, block_q=32,
+            block_k=32) - tgt) ** 2), argnums=(0, 1, 2))(q, k, v)
+        g_r = jax.grad(
+            lambda q, k, v: jnp.sum((flash_attention_reference(
+                q, k, v, causal=causal, segment_ids=seg) - tgt) ** 2),
+            argnums=(0, 1, 2))(q, k, v)
+        for gf, gr, name in zip(g_f, g_r, "qkv"):
+            err = np.abs(np.asarray(gf) - np.asarray(gr)).max()
+            assert err < 5e-4, ("seg grad d%s" % name, causal, err)
     # odd length, 3 segments
     q, k, v = (_rand((1, 1, 48, 16), i + 80) for i in range(3))
     seg = jnp.asarray(np.repeat([0, 1, 2], 16)[None].astype(np.int32))
@@ -236,80 +230,12 @@ def check_ring_segments():
         assert err < 5e-4, ("ring seg grad d%s" % name, err)
 
 
-def check_fused_chunked():
-    """The fused backward bounds its dq-partial HBM by chunking the k
-    axis (MXTPU_FLASH_BWD_DQ_BYTES).  Gradients must stay exact across
-    chunk boundaries — causal k_base offsets, segment masks, odd-length
-    cross-attention — and the path must provably degrade to split when
-    even one slot overflows the budget."""
-    b, h, t, d = 1, 2, 96, 16
-    slot = b * h * t * d * 4  # one k-block's dq partial slot, fp32
-    q, k, v, tgt = (_rand((b, h, t, d), i + 90) for i in range(4))
-    seg = jnp.asarray(np.repeat([0, 1, 7], 32)[None].astype(np.int32))
-    qx = _rand((1, 1, 40, 16), 95)
-    kx = _rand((1, 1, 72, 16), 96)
-    vx = _rand((1, 1, 72, 16), 97)
-    tx = _rand((1, 1, 40, 16), 98)
-
-    def grads(seg_ids, causal):
-        return jax.grad(lambda q, k, v: jnp.sum((flash_attention(
-            q, k, v, causal=causal, segment_ids=seg_ids, block_q=32,
-            block_k=32) - tgt) ** 2), argnums=(0, 1, 2))(q, k, v)
-
-    def grads_cross():
-        return jax.grad(lambda q, k, v: jnp.sum((flash_attention(
-            q, k, v, block_q=32, block_k=32) - tx) ** 2),
-            argnums=(0, 1, 2))(qx, kx, vx)
-
-    cases = [("plain", lambda: grads(None, False)),
-             ("causal", lambda: grads(None, True)),
-             ("seg-causal", lambda: grads(seg, True)),
-             ("cross-odd", grads_cross)]
-    # one k-block dq slot for the cross shape (tq=40 padded to 64):
-    # budgets below force chunking of its PADDED k axis (tk=72 -> 96,
-    # nk=3), the riskiest interaction (k_base + tk_true bounds mask
-    # across a chunk boundary)
-    slot_x = 1 * 1 * 64 * 16 * 4
-    os.environ["MXTPU_FLASH_BWD"] = "split"
-    try:
-        want = {name: fn() for name, fn in cases}
-        os.environ["MXTPU_FLASH_BWD"] = "fused"
-        # nk=3 everywhere: slot/2*slot chunk the self-attn cases (3 and
-        # uneven 2+1), slot_x/2*slot_x chunk the cross case (the self
-        # cases then fall back to split — also exercised), 1<<30 is the
-        # single-call fast path, 1 the <1-slot split fallback
-        for budget in (slot, 2 * slot, slot_x, 2 * slot_x, 1 << 30, 1):
-            os.environ["MXTPU_FLASH_BWD_DQ_BYTES"] = str(budget)
-            for name, fn in cases:
-                for gf, gr, gname in zip(fn(), want[name], "qkv"):
-                    err = np.abs(np.asarray(gf) - np.asarray(gr)).max()
-                    assert err < 5e-4, ("chunked d%s" % gname, name,
-                                        budget, err)
-    finally:
-        os.environ.pop("MXTPU_FLASH_BWD", None)
-        os.environ.pop("MXTPU_FLASH_BWD_DQ_BYTES", None)
-
-
-def check_fused_backward():
-    """MXTPU_FLASH_BWD=fused runs the single-pass dq/dk/dv kernel; its
-    gradients must match the split kernels' and the reference —
-    including the padding, causal-skip, and ring paths."""
-    os.environ["MXTPU_FLASH_BWD"] = "fused"
-    try:
-        check_grads()
-        check_grads_odd_lengths()
-        check_ring_flash()
-    finally:
-        os.environ.pop("MXTPU_FLASH_BWD", None)
-
-
 if __name__ == "__main__":
     jax.config.update("jax_default_matmul_precision", "float32")
     # two tiers (the PR-7 fast-sibling pattern, re-applied when the
     # tier-1 wall crowded the 870 s budget): `core` covers every kernel
     # entry point + the grad oracle in ~25 s; `extended` is the
-    # exhaustive ring / fused-backward / chunked-budget sweep (~160 s,
-    # driven by the slow test).
+    # exhaustive ring sweep, driven by the slow test.
     section = sys.argv[1] if len(sys.argv) > 1 else "core"
     if section in ("core", "all"):
         check_forward()
@@ -322,7 +248,5 @@ if __name__ == "__main__":
         print("FLASH_OK backend=%s" % jax.default_backend())
     if section in ("extended", "all"):
         check_ring_flash()
-        check_fused_backward()
-        check_fused_chunked()
         check_ring_segments()
         print("FLASH_EXTENDED_OK backend=%s" % jax.default_backend())

@@ -135,6 +135,19 @@ def main(run_dir):
     assert health["remote"]["health"]["engine"]["decode_steps"] > 0, \
         "the replacement never actually served"
 
+    # the operator's live matrix over the healed fleet, from status +
+    # telemetry_pull alone: one complete row for every live worker
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools", "perf_probe"))
+    import fleet_top
+    rows = fleet_top.collect_matrix(
+        fleet_top.discover_targets(run_dir), timeout_s=5.0)["rows"]
+    assert sorted(r["replica"] for r in rows) == \
+        ["slot%d" % s for s in SLOTS], rows
+    for r in rows:
+        assert r.get("up") and r.get("engine") and \
+            r.get("hb_rtt_ms") is not None, r
+
     telemetry.stop_emitter()
     with open(os.path.join(run_dir, "driver-report.json"), "w") as f:
         json.dump({"completed": len(rrs), "failovers": rt.failovers,

@@ -4,6 +4,7 @@ run in a process of its own by tests/test_serving.py.
 Usage: python serving_driver.py [kernel|engine|capacity|spec_sweep]
 Prints SERVING_<SECTION>_OK markers on success.
 """
+import contextlib
 import os
 import sys
 
@@ -36,6 +37,20 @@ def _idle_pages_ok(eng):
         (eng.alloc.used_pages, cached)
     if eng._prefix is not None:
         eng._prefix.assert_consistent()
+
+
+@contextlib.contextmanager
+def _one_program_a_step(eng):
+    """Inside the block every decode step and every prefill of ``eng``
+    is ONE dispatch, nothing else dispatches and nothing compiles."""
+    from mxnet_tpu import profiler
+    profiler.reset_step_stats()
+    d0, f0 = eng.decode_steps, eng.prefills
+    yield
+    stats = profiler.step_stats()
+    assert stats["compile_count"] == 0, stats
+    assert stats["dispatch_count"] == \
+        (eng.decode_steps - d0) + (eng.prefills - f0), stats
 
 
 def _net():
@@ -273,7 +288,10 @@ def check_oom_admission(net):
 
 def check_dispatch_contract_and_telemetry(net):
     """dispatches == decode_steps + prefills exactly, 0 steady-state
-    compiles across churn; serving telemetry populated."""
+    compiles across churn, with a collector's ``telemetry.pull_snapshot``
+    after every engine step (a pull never forces a dispatch or a
+    compile); serving telemetry populated, and on this unfaulted run
+    goodput == tokens == traced token events == tokens produced."""
     from mxnet_tpu import profiler, telemetry
     eng = _engine(net)
     rng = np.random.RandomState(5)
@@ -281,11 +299,22 @@ def check_dispatch_contract_and_telemetry(net):
     telemetry.reset()
     profiler.reset_step_stats()
     d0, p0 = eng.decode_steps, eng.prefills
+    cursor = {}
+
+    def step_and_pull():
+        nonlocal cursor
+        eng.step()
+        more = True
+        while more:             # chunked tail, as a collector loops
+            _doc, cursor, more = telemetry.pull_snapshot(
+                cursor.get("req_seq"), cursor.get("step_seq"))
+
     eng.submit(rng.randint(0, VOCAB, (7,)).astype(np.int32), 6)
-    eng.step()
+    step_and_pull()
     eng.submit(rng.randint(0, VOCAB, (12,)).astype(np.int32), 3)
     eng.submit(rng.randint(0, VOCAB, (2,)).astype(np.int32), 9)
-    eng.run_until_idle()
+    while not eng.sched.idle:
+        step_and_pull()
     stats = profiler.step_stats()
     decode_steps = eng.decode_steps - d0
     prefills = eng.prefills - p0
@@ -297,6 +326,8 @@ def check_dispatch_contract_and_telemetry(net):
     assert c["serving.requests"] == 3
     assert c["serving.prefills"] == 3
     assert c["serving.tokens"] == 6 + 3 + 9
+    assert c["serving.goodput"] == 18       # nothing expired or failed
+    assert telemetry.count_token_events(telemetry.request_events()) == 18
     assert rep["gauges"]["serving.batch_occupancy"] == 0  # drained
     assert rep["gauges"]["serving.kv_pages_free"] == eng.alloc.free_pages
     # the last decode step held one request at key position 9: two live
@@ -467,10 +498,12 @@ def check_prefix_sharing_and_cow(net):
 
 
 def check_prefix_cache_off_token_identity(net):
-    """Cache-off and cache-on engines emit IDENTICAL greedy tokens on a
-    shared-prefix workload (the 'greedy stays bit-identical to today'
-    pin: the cache changes capacity and prefill cost, never tokens),
-    and the cache-off engine leaves zero pages behind."""
+    """Cache-off and cache-on engines emit IDENTICAL tokens on a
+    shared-prefix workload, greedy and sampled alike (the cache changes
+    capacity and prefill cost, never tokens), and the cache-off engine
+    leaves zero pages behind."""
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.serving import SamplingParams
     rng = np.random.RandomState(11)
     sysp = rng.randint(0, VOCAB, (8,)).astype(np.int32)
     prompts = [np.concatenate([sysp, rng.randint(0, VOCAB, (l,))
@@ -482,6 +515,16 @@ def check_prefix_cache_off_token_identity(net):
     toks_on = on.generate(prompts, 6)
     toks_off = off.generate(prompts, 6)
     assert toks_on == toks_off, (toks_on, toks_off)
+    # the same prompts again, every other one sampled: these admissions
+    # HIT the pages the first round cached
+    samp = [None, SamplingParams(temperature=0.8, top_k=24, top_p=0.95,
+                                 seed=4001), None]
+    hits0 = telemetry.counter("serving.prefix.hits").value
+    samp_on = on.generate(prompts, 6, sampling=samp)
+    assert telemetry.counter("serving.prefix.hits").value >= hits0 + 3
+    samp_off = off.generate(prompts, 6, sampling=samp)
+    assert samp_on == samp_off, (samp_on, samp_off)
+    assert samp_on[1] != toks_on[1], "the sampled request ran greedy"
     assert off.alloc.used_pages == 0
     _idle_pages_ok(on)
 
@@ -514,19 +557,22 @@ def check_prefix_eviction_under_pressure(net):
 def check_sampling_laws(net):
     """Sampling-decode laws at the engine level: seeded reproducibility,
     greedy-equals-argmax (temp 0 and top_k 1), and per-request isolation
-    (a greedy resident's tokens are untouched by sampled neighbors)."""
+    (a greedy resident's tokens are untouched by sampled neighbors);
+    a request's sampling parameters are program INPUTS: with the prefix
+    cache on, new parameters cost no compile and no extra dispatch."""
     from mxnet_tpu.serving import SamplingParams
     rng = np.random.RandomState(13)
     p0 = rng.randint(0, VOCAB, (6,)).astype(np.int32)
     p1 = rng.randint(0, VOCAB, (9,)).astype(np.int32)
     eng = _engine(net)
+    assert eng._prefix is not None
     sp = SamplingParams(temperature=0.9, top_k=16, top_p=0.95, seed=3)
     a = eng.generate([p0], 6, sampling=sp)[0]
     b = eng.generate([p0], 6, sampling=sp)[0]
     assert a == b, "same seed+params must reproduce exactly"
-    c = eng.generate([p0], 6,
-                     sampling=SamplingParams(temperature=0.9, top_k=16,
-                                             top_p=0.95, seed=4))[0]
+    with _one_program_a_step(eng):
+        c = eng.generate([p0], 6, sampling=SamplingParams(
+            temperature=0.9, top_k=16, top_p=0.95, seed=4))[0]
     assert a != c, "different seeds produced identical 6-token runs"
     # top_k=1 at any temperature is argmax — equals the greedy engine
     greedy = eng.generate([p0], 6)[0]
@@ -599,6 +645,7 @@ def check_spec_greedy_laws(net):
     news = (8, 6, 7)
     dt0 = telemetry.counter("serving.spec.draft_tokens").value
     ac0 = telemetry.counter("serving.spec.accepted").value
+    rj0 = telemetry.counter("serving.spec.rejected").value
     handles = []
     for p, n in zip(prompts, news):
         handles.append(on.submit(p, n))
@@ -608,9 +655,16 @@ def check_spec_greedy_laws(net):
         assert h.tokens == _ref(net, p, n), (h.tokens, _ref(net, p, n))
     drafted = telemetry.counter("serving.spec.draft_tokens").value - dt0
     accepted = telemetry.counter("serving.spec.accepted").value - ac0
-    rejected = telemetry.counter("serving.spec.rejected").value
+    rejected = telemetry.counter("serving.spec.rejected").value - rj0
     assert drafted > 0 and accepted > 0, (drafted, accepted)
-    assert accepted <= drafted
+    # the counters reconcile: every draft is accepted or rejected, and
+    # every decode token is a slot's own step or an accepted draft the
+    # host did not have to drop
+    assert drafted == accepted + rejected, (drafted, accepted, rejected)
+    assert sum(news) - on.prefills == \
+        on.spec_slot_steps + accepted - on.spec_discarded, \
+        (news, on.prefills, on.spec_slot_steps, accepted,
+         on.spec_discarded)
     _idle_pages_ok(on)
     assert on.alloc.speculative_pages == 0
 
@@ -621,7 +675,10 @@ def check_spec_greedy_laws(net):
     probe, ref = _draftable_probe(net, rng, 10)
     off = _engine(net)
     d_on0, d_off0 = on.decode_steps, off.decode_steps
-    t_on = on.generate([probe], 10)[0]
+    # draft, verify and accept ride the ONE program: a draft length is
+    # a mask, never a shape
+    with _one_program_a_step(on):
+        t_on = on.generate([probe], 10)[0]
     t_off = off.generate([probe], 10)[0]
     assert t_on == t_off == ref
     steps_on = on.decode_steps - d_on0
@@ -770,13 +827,13 @@ def check_stream_cancel(net):
 
 
 def check_stream_abandon_reclaim(net):
-    """The ``serve.client.vanish`` drill at the engine: pollers fall
-    silent mid-stream, and after MXTPU_SERVE_ABANDON_S the sweep
+    """A client vanishes: its poller falls silent mid-stream while the
+    process lives on, and after MXTPU_SERVE_ABANDON_S the sweep
     reclaims the orphans with the typed ``abandoned`` verdict — pages
     back in the pool, conservation green, the still-polling survivor
     and the never-polled UNARY request both untouched."""
     import time as _time
-    from mxnet_tpu import fault, telemetry
+    from mxnet_tpu import telemetry
     rng = np.random.RandomState(21)
     prompts = [rng.randint(0, VOCAB, (5,)).astype(np.int32)
                for _ in range(3)]
@@ -792,29 +849,30 @@ def check_stream_abandon_reclaim(net):
     # reqs[0] and reqs[1] become STREAMS (polled); reqs[2] stays unary
     cursors = [0, 0]
     vanished = set()
-    fault.configure("serve.client.vanish:1")
-    try:
-        for step in range(40):
-            if all(r.done for r in reqs):
-                break
-            eng.step()
-            for i in (0, 1):
-                if i in vanished or reqs[i].done:
-                    continue
-                if i == 1 and step >= 2 and \
-                        fault.trigger("serve.client.vanish"):
-                    vanished.add(i)      # poller dies; process lives
-                    continue
-                reply = eng.poll(reqs[i].trace, cursor=cursors[i])
-                cursors[i] += len(reply["tokens"])
-            _time.sleep(0.02)            # real time ages last_poll_t
-    finally:
-        fault.reset()
+    for step in range(40):
+        if all(r.done for r in reqs):
+            break
+        eng.step()
+        for i in (0, 1):
+            if i in vanished or reqs[i].done:
+                continue
+            if i == 1 and step >= 2:
+                vanished.add(i)          # poller dies; process lives
+                continue
+            reply = eng.poll(reqs[i].trace, cursor=cursors[i])
+            cursors[i] += len(reply["tokens"])
+        _time.sleep(0.02)                # real time ages last_poll_t
     assert vanished == {1}
     assert reqs[1].done and reqs[1].verdict == "abandoned", \
         (reqs[1].state, reqs[1].verdict)
     assert telemetry.counter("serving.stream.abandoned").value > c0
     assert eng.snapshot()["stream"]["abandoned"] >= 1
+    # the default rule watching that counter fires (here, or at an
+    # earlier drain of the registry inside this check)
+    telemetry.check_alerts()
+    assert "orphan_reclaim" in [
+        e["args"]["rule"] for e in telemetry.request_events()
+        if e["event"] == "alert"]
     # the survivor poller and the unary request were NEVER reclaimed
     assert reqs[0].state == "finished" and reqs[0].tokens == refs[0]
     assert reqs[2].state == "finished" and reqs[2].tokens == refs[2], \
@@ -949,11 +1007,14 @@ def check_kvq_sampled_determinism_swap_failover(net, eng, plain):
     p0 = rng.randint(0, VOCAB, (6,)).astype(np.int32)
     p1 = rng.randint(0, VOCAB, (9,)).astype(np.int32)
     sp = SamplingParams(temperature=0.9, top_k=16, top_p=0.95, seed=5)
-    # churn: a greedy neighbor joins mid-flight
-    r = eng.submit(p0, 6, sampling=sp)
-    eng.step()
-    eng.submit(p1, 5)
-    eng.run_until_idle()
+    # churn: a greedy neighbor joins mid-flight.  Quantize-on-scatter
+    # and the kernel's dequant live INSIDE the one donated program: a
+    # dispatch a decode step and a prefill, no compile
+    with _one_program_a_step(eng):
+        r = eng.submit(p0, 6, sampling=sp)
+        eng.step()
+        eng.submit(p1, 5)
+        eng.run_until_idle()
     want = r.tokens
     assert eng.generate([p0], 6, sampling=sp)[0] == want
     # hot-swap with identical weights mid-decode: stream unchanged
@@ -1030,6 +1091,9 @@ def check_kvq_dtype_sweep(net):
         _idle_pages_ok(a)
         _idle_pages_ok(b)
     assert bpt["fp32"] > bpt["bf16"] > bpt["int8"], bpt
+    # the same pool bytes hold >= 1.8x the tokens in int8 as in bf16:
+    # the payload halves, a page's scale rows cost 8 * K_kv bytes
+    assert bpt["int8"] < bpt["bf16"] / 1.8, bpt
     # GQA x int8 composition: K_kv = H/2 halves the rows int8 already
     # quartered — bytes/token divides multiplicatively
     gqa8 = _engine(net, kv_dtype="int8", kv_heads=HEADS // 2, **kw)
@@ -1052,6 +1116,40 @@ def check_kvq_dtype_sweep(net):
             assert "kv_dtype" in str(exc)
     finally:
         del os.environ["MXTPU_SERVE_KV_DTYPE"]
+
+
+def check_kvq_greedy_match_rate_vs_fp():
+    """Quantized greedy is pinned to ITSELF; how far it drifts from the
+    fp path is pinned here as a count: on a seeded mix of 24 requests
+    (prompts 4-24, 8-24 new tokens; a 2-layer 128-wide net, pages of 8
+    so an absmax scale covers 8 rows) at least 99% of the int8
+    engine's greedy tokens are the fp32 engine's."""
+    np.random.seed(0)
+    mx.random.seed(0)
+    net = gpt.GPTLM(256, 2, 128, 4, max_len=64)
+    net.initialize()
+    rng = np.random.RandomState(7)
+    prompts, news = [], []
+    for _ in range(24):
+        rng.exponential(0.004)      # the mix's arrival draw, unused here
+        prompts.append(rng.randint(0, 256, int(rng.randint(4, 25)))
+                       .astype(np.int32))
+        news.append(int(rng.randint(8, 25)))
+    kw = dict(num_slots=8, page_size=8, max_prefill_len=32,
+              max_seq_len=48)
+    streams = {}
+    for dt in ("fp32", "int8"):
+        eng = _engine(net, kv_dtype=dt, **kw)
+        reqs = [eng.submit(p, n) for p, n in zip(prompts, news)]
+        eng.run_until_idle()
+        streams[dt] = [r.tokens for r in reqs]
+        _idle_pages_ok(eng)
+    total = sum(news)
+    matched = sum(a == b for got, want in zip(streams["int8"],
+                                              streams["fp32"])
+                  for a, b in zip(got, want))
+    assert sum(len(t) for t in streams["fp32"]) == total
+    assert matched >= 0.99 * total, (matched, total)
 
 
 def main(section):
@@ -1110,6 +1208,7 @@ def main(section):
         check_gqa_engine_self_consistent(net)
         check_gqa_capacity_multiplier(net)
         check_kvq_dtype_sweep(net)
+        check_kvq_greedy_match_rate_vs_fp()
         print("SERVING_CAPACITY_OK")
     if section in ("spec_sweep", "all"):
         net = _net()

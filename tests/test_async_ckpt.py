@@ -75,6 +75,29 @@ def test_async_save_roundtrips_and_latest_sees_it(tmp_path):
 
 
 @pytest.mark.fault
+def test_async_saves_in_the_loop_add_no_dispatch(tmp_path):
+    """A checkpoint an epoch inside a fused fit loop, the write
+    overlapping the steps that follow: the snapshot (host fetch + owned
+    copies) and the enqueue dispatch no compiled program, so the loop
+    stays at exactly 1.0 dispatch a step with 0 compiles."""
+    from mxnet_tpu import profiler
+    mod, batches = _make_module()
+    for b in batches:
+        mod.fit_step(b)
+    profiler.reset_step_stats()
+    for epoch in range(3):
+        for b in batches:
+            mod.fit_step(b)
+        mod.save_checkpoint(str(tmp_path / "ck"), epoch,
+                            save_optimizer_states=True)
+    stats = profiler.step_stats()
+    ckpt.flush_async()
+    assert stats["dispatch_count"] == 3 * len(batches), stats
+    assert stats["compile_count"] == 0, stats
+    assert CheckpointManager(str(tmp_path / "ck")).latest() == 2
+
+
+@pytest.mark.fault
 def test_snapshot_isolated_from_donated_buffers(tmp_path):
     """The queued snapshot must hold the params AS OF the save, even
     though the next fused steps donate (delete/reuse) the live buffers

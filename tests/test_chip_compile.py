@@ -166,14 +166,15 @@ def test_paged_kernel_at_the_serving_cell_stays_in_scoped_vmem(chip):
 _ENGINE_PROGRAMS = {}
 
 
-def _engine_programs(kv_dtype, spec_k, monkeypatch):
+def _engine_programs(kv_dtype, spec_k, monkeypatch, decode_ahead=0):
     """``{name: (fn, example shapes)}`` of a ``ServingEngine``'s decode
     and prefill programs at GPT-2-medium widths, as the engine hands
     them to its compiler: ``_compile`` is replaced by a recorder, so
     nothing is compiled for the CPU.  Two layers, a small vocabulary
     and a 128-token prefill keep a program's activations under one int8
     pool, so only a pool-sized temporary can break the bound below."""
-    if (kv_dtype, spec_k) not in _ENGINE_PROGRAMS:
+    key = (kv_dtype, spec_k, decode_ahead)
+    if key not in _ENGINE_PROGRAMS:
         from mxnet_tpu.gluon.model_zoo import gpt
         from mxnet_tpu.serving import ServingEngine
         got = {}
@@ -187,18 +188,21 @@ def _engine_programs(kv_dtype, spec_k, monkeypatch):
         eng = ServingEngine(net, num_slots=SLOTS, page_size=PAGE,
                             num_pages=PAGES, max_prefill_len=128,
                             max_seq_len=1024, spec_k=spec_k,
-                            kv_dtype=kv_dtype)
+                            kv_dtype=kv_dtype, decode_ahead=decode_ahead)
         assert eng._kv[0][0].shape == (PAGES, PAGE, HEADS * HEAD_DIM)
-        _ENGINE_PROGRAMS[kv_dtype, spec_k] = got
-    return _ENGINE_PROGRAMS[kv_dtype, spec_k]
+        _ENGINE_PROGRAMS[key] = got
+    return _ENGINE_PROGRAMS[key]
 
 
 def _elements(shape_text):
     return int(np.prod([int(n) for n in shape_text.split(",") if n]))
 
 
-@pytest.mark.parametrize("program", ["decode", "spec_decode", "prefill"])
-@pytest.mark.parametrize("kv_dtype", ["bf16", "fp32", "int8"])
+@pytest.mark.parametrize("kv_dtype,program", [
+    (kv_dtype, program) for kv_dtype in ("bf16", "fp32", "int8")
+    for program in ("decode", "spec_decode", "prefill")] + [
+    # what both backlog cells run: the programs of ``decode_ahead`` 2
+    ("bf16", "ahead_decode"), ("bf16", "ahead_prefill")])
 def test_engine_program_keeps_the_pools_in_one_layout(
         chip, monkeypatch, kv_dtype, program):
     """The layout the chip keeps a ``[num_pages, page, K_kv * D]`` pool
@@ -210,8 +214,9 @@ def test_engine_program_keeps_the_pools_in_one_layout(
     cells' device time before PR 25.)"""
     import re
     fn, examples = _engine_programs(
-        kv_dtype, 4 if program == "spec_decode" else 0, monkeypatch)[
-            "prefill" if program == "prefill" else "decode"]
+        kv_dtype, 4 if program == "spec_decode" else 0, monkeypatch,
+        decode_ahead=2 if program.startswith("ahead_") else 0)[
+            "prefill" if program.endswith("prefill") else "decode"]
     pools = jax.tree_util.tree_leaves(examples[1])
     # matmuls as the chip runs them: the suite's fp32 precision is for
     # numeric checks on the CPU, and here it would hold every weight a
@@ -221,7 +226,7 @@ def test_engine_program_keeps_the_pools_in_one_layout(
             *jax.tree_util.tree_map(lambda a: chip(a.shape, a.dtype),
                                     examples)).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" in text or program == "prefill"
+    assert "tpu_custom_call" in text or program.endswith("prefill")
     pool_elements = PAGES * PAGE * HEADS * HEAD_DIM
     # a layout conversion is a `copy`, alone or as the root of a fusion
     # the compiler names after it.  `copy-start` / `copy-done` move an

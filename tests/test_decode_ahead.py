@@ -20,11 +20,20 @@ PLAN = [(5, 4), (17, 1), (9, 12), (30, 3), (8, 9), (12, 2), (3, 7),
 def nets():
     g = gpt.gpt2_tiny()
     g.initialize(mx.init.Xavier())
-    return {"gpt2": g, "hybrid": ling3.ling3_tiny().init_seeded(7)}
+    # "gpt2-shared-prefix": the same net, served with the prefix cache
+    # on and prompts that share pages (what gpt2m-serve-backlog's engine
+    # is built with, and the chat cell's traffic hits)
+    return {"gpt2": g, "gpt2-shared-prefix": g,
+            "hybrid": ling3.ling3_tiny().init_seeded(7)}
 
 
-def prompt(n, seed):
-    return np.random.default_rng(seed).integers(0, 256, n).astype(np.int32)
+def prompt(n, seed, shared=False):
+    """``n`` seeded tokens; ``shared``: every prompt over 16 tokens opens
+    on the same two pages of 8."""
+    p = np.random.default_rng(seed).integers(0, 256, n).astype(np.int32)
+    if shared and n > 16:
+        p[:16] = prompt(16, 7)
+    return p
 
 
 def engine(net, ahead, **kw):
@@ -35,11 +44,12 @@ def engine(net, ahead, **kw):
     return ServingEngine(net, **args)
 
 
-def serve(net, ahead, sampled=False, between=None, **kw):
+def serve(net, ahead, sampled=False, between=None, shared=False, **kw):
     """PLAN through three slots (every slot reused), one request arriving
-    late; ``between(eng, reqs, step)`` runs in the gap after each step."""
-    eng = engine(net, ahead, **kw)
-    reqs = [eng.submit(prompt(n, 100 + i), new, trace="t%d" % i,
+    late; ``between(eng, reqs, step)`` runs in the gap after each step.
+    ``shared``: the prefix cache on, over prompts that share pages."""
+    eng = engine(net, ahead, prefix_cache=shared, **kw)
+    reqs = [eng.submit(prompt(n, 100 + i, shared), new, trace="t%d" % i,
                        sampling=SamplingParams(temperature=0.8, top_k=20,
                                                seed=i) if sampled else None)
             for i, (n, new) in enumerate(PLAN)]
@@ -53,22 +63,28 @@ def serve(net, ahead, sampled=False, between=None, **kw):
             between(eng, reqs, steps)
         assert steps < 200
     assert made == sum(len(r.tokens) for r in reqs)
-    assert eng.alloc.used_pages == 0 and eng.sched.occupancy == 0
+    assert eng.sched.occupancy == 0
+    if not shared:      # a cached prefix keeps its pages
+        assert eng.alloc.used_pages == 0
     return eng, reqs
 
 
 @pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "sampled"])
 @pytest.mark.parametrize("ahead", [1, 2])
-@pytest.mark.parametrize("model", ["gpt2", "hybrid"])
+@pytest.mark.parametrize("model", ["gpt2", "gpt2-shared-prefix", "hybrid"])
 def test_decode_ahead_gives_the_same_tokens_and_logits(nets, model, ahead,
                                                        sampled):
-    _, want = serve(nets[model], 0, sampled)
-    eng, got = serve(nets[model], ahead, sampled)
+    shared = model == "gpt2-shared-prefix"
+    _, want = serve(nets[model], 0, sampled, shared=shared)
+    eng, got = serve(nets[model], ahead, sampled, shared=shared)
     for w, g in zip(want, got):
         assert g.done and len(g.tokens) == g.max_new
         assert g.tokens == w.tokens
         assert np.array_equal(np.stack(g.logits_trace),
                               np.stack(w.logits_trace))
+        assert g.prefix_len == w.prefix_len
+    if shared:          # the cache was hit, not just on
+        assert sum(g.prefix_len for g in got) >= 32
     # every dispatch was read by somebody: a request whose last token
     # was on its way sat the ones sent ahead out
     assert not eng._unread

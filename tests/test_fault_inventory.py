@@ -7,7 +7,7 @@ complete drill surface, and (b) DRILLED — a site nothing exercises is
 a recovery path nothing proves.  This lint enumerates every site
 string passed to ``fault.trigger`` / ``check`` / ``stall_if`` /
 ``delay_if`` / ``exit_if`` / ``is_active`` across the runtime
-(``mxnet_tpu/``, ``tools/``, ``bench.py``) and asserts:
+(``mxnet_tpu/``, ``tools/``) and asserts:
 
 - every site in code has a row in the ROBUSTNESS.md §4 table;
 - every row in the table corresponds to a site in code (no stale
@@ -55,7 +55,7 @@ def _py_files(*roots):
 
 def sites_in_code():
     sites = {}
-    for path in _py_files("mxnet_tpu", "tools", "bench.py"):
+    for path in _py_files("mxnet_tpu", "tools"):
         with open(path, encoding="utf-8") as f:
             src = f.read()
         for m in _CALL_RE.finditer(src):

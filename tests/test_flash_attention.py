@@ -36,9 +36,6 @@ def test_flash_attention_kernels():
 
 @pytest.mark.slow
 def test_flash_attention_extended():
-    """Exhaustive tier: ring flash across the 8-device mesh, the fused
-    single-pass backward (re-running the grad suites under
-    MXTPU_FLASH_BWD=fused), chunked dq-budget sweeps, ring segment
-    masks — ~160 s of interpret-mode sweeps (the tier-1 wall's largest
-    single line item before the split)."""
+    """Exhaustive tier: ring flash across the 8-device mesh and ring
+    segment masks, interpret-mode sweeps too long for tier-1."""
     assert "FLASH_EXTENDED_OK" in _run_driver("extended")

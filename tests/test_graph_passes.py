@@ -97,9 +97,10 @@ def random_transformer_graph(seed):
         {"data": (2, T, C), "softmax_label": (2,)}
 
 
-def _bind_and_run(sym, shapes, passes, seed, train):
+def _bind_and_run(sym, shapes, passes, seed, train, data=None):
     """Bind under the given pipeline config, seed params identically,
-    run forward (+backward when train) — returns (outs, grads, exe)."""
+    run forward (+backward when train) — returns (outs, grads, exe).
+    ``data`` replaces the seeded normal input (token ids, say)."""
     with pipeline_env(passes):
         exe = sym.simple_bind(mx.cpu(), grad_req="write" if train
                               else "null", **shapes)
@@ -107,7 +108,8 @@ def _bind_and_run(sym, shapes, passes, seed, train):
     feeds = {}
     for name, arr in sorted(exe.arg_dict.items()):
         if name == "data":
-            feeds[name] = r.randn(*arr.shape).astype(np.float32)
+            feeds[name] = r.randn(*arr.shape).astype(np.float32) \
+                if data is None else data
         elif name.endswith("label"):
             feeds[name] = r.randint(0, 3, arr.shape).astype(np.float32)
         else:
@@ -161,6 +163,121 @@ def test_random_conv_graph_equivalent_train_with_grads(seed):
 def test_random_transformer_graph_equivalent(seed):
     sym, shapes = random_transformer_graph(seed)
     assert_equivalent(sym, shapes, seed=seed, train=True)
+
+
+def resnet_tower_graph(blocks=8, filters=16):
+    """Conv→BN→ReLU residual tower with a BN'd stem and a dense head:
+    every unit is the pattern the fuse pass targets."""
+    def unit(x, name, act=True):
+        x = mx.sym.Convolution(x, kernel=(3, 3), pad=(1, 1), no_bias=True,
+                               num_filter=filters, name=name + "_conv")
+        x = mx.sym.BatchNorm(x, fix_gamma=False, name=name + "_bn")
+        return mx.sym.Activation(x, act_type="relu",
+                                 name=name + "_relu") if act else x
+
+    net = unit(mx.sym.Variable("data"), "stem")
+    for i in range(blocks):
+        inner = unit(unit(net, "b%d_u1" % i), "b%d_u2" % i, act=False)
+        net = mx.sym.Activation(net + inner, act_type="relu",
+                                name="b%d_out" % i)
+    net = mx.sym.Pooling(net, global_pool=True, pool_type="avg",
+                         name="gap")
+    net = mx.sym.FullyConnected(net, num_hidden=64, name="head_fc")
+    net = mx.sym.Activation(net, act_type="relu", name="head_relu")
+    net = mx.sym.FullyConnected(net, num_hidden=10, name="logits")
+    return mx.sym.SoftmaxOutput(net, name="softmax"), \
+        {"data": (8, 3, 16, 16), "softmax_label": (8,)}
+
+
+def gpt_stack_graph(layers=4, units=64, heads=4, seq=128, vocab=128):
+    """Post-LN transformer stack whose causal mask is built
+    SYMBOLICALLY inside every block (arange → reshape → compare →
+    scale), the per-layer redundancy an op-by-op frontend emits: folding
+    evaluates each chain once at bind, CSE merges the copies, and
+    LayerNorm(x + sublayer) is the fused-epilogue pattern."""
+    d = units // heads
+
+    def causal_bias(name):
+        q = mx.sym.Reshape(mx.sym._arange(start=0, stop=seq,
+                                          name=name + "_qpos"),
+                           shape=(seq, 1))
+        k = mx.sym.Reshape(mx.sym._arange(start=0, stop=seq,
+                                          name=name + "_kpos"),
+                           shape=(1, seq))
+        return (mx.sym.broadcast_greater_equal(q, k) - 1.0) * 1e9
+
+    def head_rows(qkv, i):
+        return mx.sym.Reshape(
+            mx.sym.slice_axis(qkv, axis=0, begin=i, end=i + 1),
+            shape=(-1, seq, d))
+
+    def block(x, name):
+        qkv = mx.sym.FullyConnected(x, num_hidden=3 * units, flatten=False,
+                                    name=name + "_qkv")
+        qkv = mx.sym.transpose(
+            mx.sym.Reshape(qkv, shape=(-1, seq, 3, heads, d)),
+            axes=(2, 0, 3, 1, 4))
+        q, k, v = (head_rows(qkv, i) for i in range(3))
+        scores = mx.sym.batch_dot(q, k, transpose_b=True) * (d ** -0.5)
+        scores = mx.sym.broadcast_add(scores, causal_bias(name))
+        att = mx.sym.batch_dot(mx.sym.softmax(scores, axis=-1), v)
+        att = mx.sym.transpose(
+            mx.sym.Reshape(att, shape=(-1, heads, seq, d)),
+            axes=(0, 2, 1, 3))
+        att = mx.sym.FullyConnected(
+            mx.sym.Reshape(att, shape=(-1, seq, units)),
+            num_hidden=units, flatten=False, name=name + "_proj")
+        x = mx.sym.LayerNorm(x + att, name=name + "_ln1")
+        h = mx.sym.FullyConnected(x, num_hidden=4 * units, flatten=False,
+                                  name=name + "_fc1")
+        h = mx.sym.Activation(h, act_type="gelu", name=name + "_gelu")
+        h = mx.sym.FullyConnected(h, num_hidden=units, flatten=False,
+                                  name=name + "_fc2")
+        return mx.sym.LayerNorm(x + h, name=name + "_ln2")
+
+    h = mx.sym.Embedding(mx.sym.Variable("data"), input_dim=vocab,
+                         output_dim=units, name="wte")
+    pos = mx.sym.Embedding(mx.sym._arange(start=0, stop=seq,
+                                          name="pos_ids"),
+                           input_dim=seq, output_dim=units, name="wpe")
+    h = mx.sym.broadcast_add(h, mx.sym.expand_dims(pos, axis=0))
+    for i in range(layers):
+        h = block(h, "h%d" % i)
+    h = mx.sym.FullyConnected(h, num_hidden=vocab, flatten=False,
+                              name="lm_head")
+    return mx.sym.SoftmaxOutput(h, preserve_shape=True, name="softmax"), \
+        {"data": (2, seq), "softmax_label": (2, seq)}
+
+
+@pytest.mark.parametrize("builder,least_cut", [
+    (resnet_tower_graph, 0.15), (gpt_stack_graph, 0.14)])
+def test_pipeline_cuts_lowered_hlo_instructions(builder, least_cut):
+    """What the graph stage hands XLA: the eval forward of a conv tower
+    lowers to >= 15% fewer HLO instructions with the pipeline on (352 ->
+    299 under jax 0.9.0), that of a transformer stack to >= 14% fewer
+    (655 -> 563), counted in the module ``jit(...).lower()`` gives
+    before the backend optimizes; both compute the same outputs (1e-6
+    relative)."""
+    import re
+    import jax
+
+    sym, shapes = builder()
+    r = np.random.RandomState(3)
+    data = (r.randint(0, 128, shapes["data"]) if builder is gpt_stack_graph
+            else r.randn(*shapes["data"])).astype(np.float32)
+    count, outs = {}, {}
+    for passes in ("off", ""):
+        outs[passes], _, exe = _bind_and_run(sym, shapes, passes, 7, False,
+                                             data=data)
+        args = {k: v._data for k, v in exe.arg_dict.items()}
+        aux = {k: v._data for k, v in exe.aux_dict.items()}
+        plan, key = exe._plan, jax.random.PRNGKey(0)
+        text = jax.jit(lambda a, x: plan(a, x, key, False)[0]).lower(
+            args, aux).as_text()
+        count[passes] = len(re.findall(r"^\s+\S+ = ", text, re.M))
+    assert count[""] <= (1 - least_cut) * count["off"], count
+    for a, b in zip(outs["off"], outs[""]):
+        assert np.max(np.abs(a - b) / np.maximum(np.abs(a), 1e-6)) <= 1e-6
 
 
 @pytest.mark.parametrize("passname", ["fuse", "fold", "cse", "dce"])

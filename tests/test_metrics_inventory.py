@@ -7,7 +7,7 @@ an operator reading an ``alert`` event, a ``fleet_top`` column, or a
 pulled stream line must be able to look the name up in OBSERVABILITY.md
 and learn its type and meaning.  This lint enumerates every
 counter/gauge/histogram NAME LITERAL registered across the runtime
-(``mxnet_tpu/``, ``tools/``, ``bench.py``) and asserts:
+(``mxnet_tpu/``, ``tools/``) and asserts:
 
 - every metric name in code has a table row in OBSERVABILITY.md whose
   type cell says counter/gauge/histogram;
@@ -94,7 +94,7 @@ def metrics_in_code():
     """{normalized name: {(relpath, type), ...}} for every registered
     counter/gauge/histogram literal under the runtime roots."""
     out = {}
-    for path in _py_files("mxnet_tpu", "tools", "bench.py"):
+    for path in _py_files("mxnet_tpu", "tools"):
         with open(path, encoding="utf-8") as f:
             src = f.read()
         rel = os.path.relpath(path, REPO)

@@ -72,9 +72,9 @@ def test_predictor_reshape(tmp_path):
 
 
 def test_config_flag_resolution(monkeypatch):
-    assert config.flag("BENCH_BATCH") == 128
-    monkeypatch.setenv("BENCH_BATCH", "64")
-    assert config.flag("BENCH_BATCH") == 64
+    assert config.flag("MXTPU_NUM_WORKERS") == 1
+    monkeypatch.setenv("MXTPU_NUM_WORKERS", "64")
+    assert config.flag("MXTPU_NUM_WORKERS") == 64
     # alias name resolves too
     monkeypatch.setenv("MXTPU_PROFILER_AUTOSTART", "1")
     assert config.flag("MXNET_PROFILER_AUTOSTART") == 1

@@ -334,18 +334,12 @@ def _flash_grad(q, k, v):
         q, k, v, causal=True).sum(), argnums=(0, 1, 2))(q, k, v)
 
 
-def _kernel_jaxpr(kernel, monkeypatch):
+def _kernel_jaxpr(kernel):
     if kernel == "flash_fwd":
         return jax.make_jaxpr(lambda q, k, v: flash.flash_attention(
             q, k, v, causal=True))(*_qkv())
     if kernel in ("flash_bwd_dq", "flash_bwd_dkv"):
-        monkeypatch.setenv("MXTPU_FLASH_BWD", "split")
         return jax.make_jaxpr(_flash_grad)(*_qkv())
-    if kernel == "flash_bwd_fused":
-        monkeypatch.setenv("MXTPU_FLASH_BWD", "fused")
-        # shapes of its own: jax keeps the backward it traced for a
-        # shape, whichever kernel the flag chose then
-        return jax.make_jaxpr(_flash_grad)(*_qkv(t=128))
     if kernel == "paged_decode":
         rs = np.random.RandomState(0)
         q = jnp.asarray(rs.randn(2, 2, 16), jnp.float32)
@@ -362,10 +356,10 @@ def _kernel_jaxpr(kernel, monkeypatch):
 
 
 @pytest.mark.parametrize("kernel", [
-    "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_bwd_fused",
-    "paged_decode", "layer_norm"])
-def test_kernel_is_a_pallas_call_under_its_name(kernel, monkeypatch):
-    names = _pallas_names(_kernel_jaxpr(kernel, monkeypatch))
+    "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "paged_decode",
+    "layer_norm"])
+def test_kernel_is_a_pallas_call_under_its_name(kernel):
+    names = _pallas_names(_kernel_jaxpr(kernel))
     assert kernel in names, "%r not among pallas_call names %r" \
         % (kernel, sorted(names))
 

@@ -276,11 +276,7 @@ def _synthetic_tree(tmp_path, torn_journal=True):
         "identity": {"pid": 77},
         "counters": {"serving.tokens": 6, "serving.goodput": 5,
                      "serving.requests": 3},
-        "serving": [{"replica": "a", "decode_steps": 2, "prefills": 2,
-                     "cost": {"decode": {"flops": 100.0,
-                                         "bytes_accessed": 10.0},
-                              "prefill": {"flops": 50.0,
-                                          "bytes_accessed": 5.0}}}],
+        "serving": [{"replica": "a", "decode_steps": 2, "prefills": 2}],
         "req_events": evs,
         "final": True,
         "last_steps": [{"step": 0, "t_unix": 100.3, "dispatch_s": 0.01,
@@ -579,8 +575,6 @@ def test_serve_report_accounting_and_latency_split(tmp_path):
     assert acc["tokens"] == 6 and acc["traced_tokens"] == 6
     assert acc["tokens_match"]
     assert acc["goodput"] == 5
-    # cost join: (2 decode steps * 100 + 2 prefills * 50) / 6 tokens
-    assert acc["flops_per_token"] == pytest.approx(300.0 / 6)
     lat = rep["latency"]
     assert lat["completed"]["n"] == 2
     assert lat["expired_queue"]["n"] == 1

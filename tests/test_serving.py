@@ -443,9 +443,9 @@ def test_serving_engine_invariants():
     The fast ISSUE-19 streaming laws ride here as well: poll-cursor
     idempotence + chunk reassembly against the unary stream, the typed
     `cancelled` verdict (mid-decode, queued, idempotent — survivors
-    bit-identical, pages conserved), and the serve.client.vanish
-    abandon-sweep drill (typed `abandoned` verdict, unary requests
-    never reclaimed).
+    bit-identical, pages conserved), and the abandon sweep for a
+    client that stops polling (typed `abandoned` verdict, unary
+    requests never reclaimed).
     The fast ISSUE-20 quantized-KV laws complete the subprocess: int8
     pool/scale-pool shape + byte accounting with allocator conservation
     under churn, twin-engine int8 reproducibility, COW prefix reuse

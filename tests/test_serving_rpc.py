@@ -5,7 +5,7 @@ Everything here runs against STUB replicas behind a real
 transport, deadline, retry, idempotence and circuit-breaker laws are
 socket-level properties and must not pay an XLA compile to be pinned.
 Real-engine integration rides tests/test_serve_fleet.py (slow e2e via
-``tools/launch.py --serve``) and ``BENCH_MODE=serve``'s fleet drill.
+``tools/launch.py --serve``).
 
 Pinned laws:
 
@@ -751,6 +751,12 @@ def test_partition_fails_over_and_fences_the_zombie(tmp_path):
             rt.step()
             time.sleep(0.01)
         assert telemetry.counter("rpc.fenced_results").value >= 1
+        # the default rules saw it: the confirmation and the rejected
+        # write-back each fired an alert into the event stream
+        telemetry.check_alerts()
+        alerts = {e["args"]["rule"] for e in telemetry.request_events()
+                  if e["event"] == "alert"}
+        assert {"replica_fenced", "fenced_writeback"} <= alerts, alerts
         with open(journal) as f:
             lines = [json.loads(ln) for ln in f if ln.strip()]
         completes = [ln for ln in lines

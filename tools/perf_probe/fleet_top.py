@@ -26,7 +26,8 @@ row (SERVING.md §9):
   the pulled stream.
 
 Modes: ``--once`` prints one matrix and exits (``--json`` emits the
-raw rows — the drill/cron contract, asserted by ``BENCH_MODE=serve``);
+raw rows — the drill/cron contract; tests/serve_fleet_driver.py reads
+``collect_matrix`` over a live fleet);
 default is a watch loop every ``--interval`` seconds.  Cursors are
 held client-side, so watching costs each worker only its newly-drained
 events per refresh and never steals from the supervisor's collector.

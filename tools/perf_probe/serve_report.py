@@ -39,11 +39,9 @@ faithfully; THIS tool answers the fleet-level questions none can alone:
   token DELIVERED through ``poll``, the unary class measures the
   engine's emit stamp and its completion (the whole point of
   streaming is that the first number beats the last one);
-- **goodput and cost-per-token** — ``serving.goodput`` (tokens on
-  requests that completed within deadline) vs raw ``serving.tokens``,
-  joined with the compile-time ``serving.cost.*`` attribution of the
-  decode/prefill executables into measured flops-and-bytes-per-token —
-  the objective function the ROADMAP-item-2 autotuner optimizes;
+- **goodput** — ``serving.goodput`` (tokens on requests that completed
+  within deadline) vs raw ``serving.tokens``, and traced token events
+  reconciled with the counter;
 - **one merged chrome trace** (``--trace-out``) — pid = replica,
   tid = decode slot, one span per residency segment, token instants,
   flow arrows linking failover arcs across replicas, hot-swap pauses,
@@ -68,7 +66,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 import telemetry_report as _tr  # noqa: E402 (sibling module)
-from restart_probe import _pct  # noqa: E402 — shared percentile helper
 
 #: verdicts that are refusals (the request never held a slot here)
 REFUSAL_VERDICTS = ("shed", "draining", "no_live_replicas")
@@ -76,6 +73,13 @@ REFUSAL_VERDICTS = ("shed", "draining", "no_live_replicas")
 #: small ordinals; keep the synthetic ones far away)
 PROC_TRACK_BASE = 900
 SWAP_TID = 9990
+
+
+def _pct(sorted_vals, q):
+    if not sorted_vals:
+        return None
+    i = min(len(sorted_vals) - 1, int(round(q * (len(sorted_vals) - 1))))
+    return sorted_vals[i]
 
 
 # -- loading ---------------------------------------------------------------
@@ -388,7 +392,7 @@ def _phase_budget(r):
 
 
 def lifecycle_check(reqs):
-    """The trace laws (test-pinned, asserted by ``BENCH_MODE=serve``):
+    """The trace laws (pinned by tests/test_serve_report.py):
     every trace closes with EXACTLY ONE final verdict event, and that
     verdict is the trace's last event.  Returns the violation list
     (empty == lawful) and the set of open traces."""
@@ -700,9 +704,8 @@ def blame(reqs, slo_ttft=None):
 
 
 def accounting(data, reqs):
-    """Goodput vs raw tokens, traced-vs-counter reconciliation, and
-    flops/bytes-per-token from the compile-time cost attribution joined
-    with the measured execution counts."""
+    """Goodput vs raw tokens and the traced-vs-counter
+    reconciliation."""
     tokens = goodput = requests = dropped = scale_repairs = 0
     spec = {"draft_tokens": 0, "accepted": 0, "rejected": 0,
             "rollbacks": 0}
@@ -722,21 +725,6 @@ def accounting(data, reqs):
                        for s in data["status"].values())
     prefills = sum(s.get("prefills") or 0
                    for s in data["status"].values())
-    flops = bytes_ = 0.0
-    have_cost = False
-    for snap in data["status"].values():
-        cost = snap.get("cost") or {}
-        dec, pre = cost.get("decode") or {}, cost.get("prefill") or {}
-        if dec.get("flops") is not None:
-            have_cost = True
-            flops += (dec.get("flops", 0.0)
-                      * (snap.get("decode_steps") or 0)
-                      + pre.get("flops", 0.0)
-                      * (snap.get("prefills") or 0))
-            bytes_ += (dec.get("bytes_accessed", 0.0)
-                       * (snap.get("decode_steps") or 0)
-                       + pre.get("bytes_accessed", 0.0)
-                       * (snap.get("prefills") or 0))
     return {
         "tokens": tokens, "goodput": goodput, "requests": requests,
         "traced_tokens": traced,
@@ -744,10 +732,6 @@ def accounting(data, reqs):
         and not data["req_dropped"],
         "trace_dropped": dropped + data["req_dropped"],
         "goodput_fraction": (goodput / tokens) if tokens else None,
-        "flops_per_token": (flops / tokens) if have_cost and tokens
-        else None,
-        "bytes_per_token": (bytes_ / tokens) if have_cost and tokens
-        else None,
         "kv_scale_repairs": scale_repairs,
         "spec": spec if spec["draft_tokens"] else None,
         "acceptance_rate": (spec["accepted"] / spec["draft_tokens"]
@@ -864,7 +848,8 @@ def merged_trace(data, reqs):
 
 def analyze(run_dir, slo_ttft=None):
     """Load + reconstruct + judge: the structured fleet report
-    (``render`` prints it; ``BENCH_MODE=serve`` asserts on it)."""
+    (``render`` prints it; tests/test_serve_report.py and
+    tests/serving_surv_driver.py ``section_trace`` assert on it)."""
     data = load_serve(run_dir)
     reqs = build_requests(data["events"])
     violations, open_traces = lifecycle_check(reqs)
@@ -1074,7 +1059,7 @@ def render(rep, out=sys.stdout):
                   "without failover\n")
 
     acc = rep["accounting"]
-    out.write("\n-- goodput / cost --\n")
+    out.write("\n-- goodput --\n")
     out.write("  tokens=%d goodput=%d (%.1f%%)  traced=%d (%s)\n"
               % (acc["tokens"], acc["goodput"],
                  100.0 * (acc["goodput_fraction"] or 0.0),
@@ -1085,10 +1070,6 @@ def render(rep, out=sys.stdout):
         out.write("  kv quantization: %d scale-poison repair(s) — "
                   "victims re-prefilled on the finite guard (ISSUE "
                   "20)\n" % acc["kv_scale_repairs"])
-    if acc["flops_per_token"] is not None:
-        out.write("  cost per token: %.3g flops, %.3g bytes accessed "
-                  "(compile-time attribution x measured executions)\n"
-                  % (acc["flops_per_token"], acc["bytes_per_token"]))
     if acc.get("spec"):
         sp = acc["spec"]
         out.write("  spec decode: drafted=%d accepted=%d rejected=%d "
@@ -1106,7 +1087,7 @@ def main(argv=None):
     ap = argparse.ArgumentParser(
         description="Merge a serving fleet's artifacts (router journal "
         "+ replica streams + postmortems) into one report: request "
-        "lifecycles, failover arcs, SLO breach blame, goodput/cost, "
+        "lifecycles, failover arcs, SLO breach blame, goodput, "
         "merged chrome trace")
     ap.add_argument("run_dir", help="run dir holding the telemetry "
                     "tree (stream-slot*.jsonl, router-journal*.jsonl, "

@@ -10,7 +10,8 @@ Prints one JSON object: {"fused": {...}, "fused_async_ckpt": {...},
 "unfused": {...}} where each side carries steady-state
 dispatches_per_step, compile_count and step_time_ema_ms — the
 fused_async_ckpt trace runs a per-epoch MXTPU_ASYNC_CKPT=1 checkpoint
-inside the loop and asserts the save path adds zero dispatches.
+inside the loop (tests/test_async_ckpt.py asserts the save path adds
+zero dispatches).
 """
 import json
 import os
@@ -23,10 +24,9 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 def build_module(batch=64, dim=32, classes=4, hidden=64, depth=2,
                  n_batches=8, ctx=None, optimizer="sgd",
                  opt_params=(("learning_rate", 0.05), ("momentum", 0.9))):
-    """The probe family's MLP fit-loop fixture (restart_probe reuses it
-    with bigger sizes): ``depth-1`` hidden relu layers + a softmax
-    head.  ``ctx`` may be a device list — the BENCH_MODE=spmd probe
-    passes the whole 8-device host mesh."""
+    """The probes' and tests' MLP fit-loop fixture: ``depth-1`` hidden
+    relu layers + a softmax head.  ``ctx`` may be a device list —
+    ``run_spmd`` passes the whole 8-device host mesh."""
     import numpy as np
     import mxnet_tpu as mx
 
@@ -110,9 +110,8 @@ def run():
     # fused loop WITH async checkpointing live: a save per epoch, the
     # write overlapping the following steps.  The snapshot (host fetch +
     # owned copies) and enqueue must add ZERO compiled-program
-    # dispatches — the 1.0 dispatch/step contract is asserted on this
-    # trace exactly like the plain fused one (bench.py BENCH_MODE=
-    # steptrace hard-fails otherwise).
+    # dispatches (tests/test_async_ckpt.py
+    # test_async_saves_in_the_loop_add_no_dispatch).
     from mxnet_tpu import checkpoint as _ckpt
     mod3, _ = build_module()
     ckdir = tempfile.mkdtemp(prefix="steptrace-ckpt-")
@@ -137,16 +136,11 @@ def run():
         else:
             os.environ["MXTPU_ASYNC_CKPT"] = prev
         shutil.rmtree(ckdir, ignore_errors=True)
-    # the dispatch-rate contract itself (1.0/step, async saves in-loop)
-    # is asserted by bench.py BENCH_MODE=steptrace, same as the plain
-    # fused contract — one home per check
-
     # the telemetry layer must agree with the profiler's step counters:
     # every fused dispatch produced exactly one fit_step.dispatch /
     # fit_step.sync phase record and one flight-recorder entry (the 1.0
-    # dispatch/step contract, cross-checked against the new per-phase
-    # counters; bench.py BENCH_MODE=steptrace still hard-asserts the
-    # dispatch rate itself)
+    # dispatch/step contract, cross-checked against the per-phase
+    # counters; tests/test_fused_step.py asserts the dispatch rate)
     n = fused["steps"]
     for phase in ("fit_step.dispatch", "fit_step.sync"):
         got = fused["phase_counts"].get(phase, 0)
@@ -163,11 +157,12 @@ def run():
 
 
 def run_spmd(n_dev=8):
-    """BENCH_MODE=spmd body: the ZeRO-1 fused step on an n_dev host
-    mesh.  Returns per-step dispatch stats plus the sharded-state
-    economics (opt-state bytes per device vs total, the estimated
-    per-step collective bytes, fallback count) so bench.py can assert
-    the 1.0 dispatch/step and 1/N-state contracts."""
+    """The ZeRO-1 fused step on an n_dev host mesh (``STEPTRACE_SPMD=1``).
+    Returns per-step dispatch stats plus the sharded-state economics
+    (opt-state bytes per device vs total, the estimated per-step
+    collective bytes, fallback count); tests/test_module_spmd.py and
+    tests/test_job_report.py assert the 1.0 dispatch/step and
+    1/N-state contracts."""
     import jax
     import numpy as np
     import mxnet_tpu as mx
@@ -175,7 +170,7 @@ def run_spmd(n_dev=8):
 
     if jax.device_count() < n_dev:
         raise RuntimeError(
-            "BENCH_MODE=spmd needs %d devices (run under "
+            "steptrace.run_spmd needs %d devices (run under "
             "--xla_force_host_platform_device_count=%d or on real "
             "chips); have %d" % (n_dev, n_dev, jax.device_count()))
     prev = os.environ.get("MXTPU_ZERO")
@@ -218,7 +213,7 @@ def run_spmd(n_dev=8):
         # arrays' actual shard shapes: params/data/label/aux.  Together
         # with the 1/N state this is what the compiled program's
         # xla.memory.argument_bytes must agree with (±20%,
-        # BENCH_MODE=spmd) — the measured cross-check of the ZeRO-1
+        # tests/test_job_report.py) — the measured cross-check of the ZeRO-1
         # state economics (scalars/rng are a few tens of bytes, inside
         # the tolerance).
         expected_args = per_device

@@ -1,4 +1,4 @@
-"""BENCH_MODE=stream body: streaming ingest vs in-memory DataLoader.
+"""Streaming ingest beside the in-memory DataLoader, on the CPU.
 
 Builds ONE synthetic shard set (float32 feature vectors + labels packed
 as RecordIO records across several shards), then runs the same fused
@@ -10,19 +10,14 @@ MLP fit loop (steptrace.build_module's network) twice:
   ``mxnet_tpu.stream.StreamLoader``'s worker pool, re-iterated per
   epoch through the SAME device prefetcher.
 
-Contracts (bench.py BENCH_MODE=stream hard-fails on violation):
-
-- steady-state fused-step wall time from disk within
-  ``MXTPU_STREAM_BENCH_MAX_RATIO`` (default 1.10) of in-memory — the
-  decode pool must hide the decode behind compute;
-- ``io.queue_wait`` p99 bounded (< one in-memory step) — the consumer
-  is never starved in steady state;
-- exactly 1.0 dispatch/step and 0 steady-state recompiles — streaming
-  feeds the same donated program, changing nothing above the batch.
-
-The ratio is the median over alternating paired segments (the
-BENCH_MODE=telemetry methodology): on a shared CPU box an absolute
-single-shot comparison of ~0.3 ms steps is all scheduler noise.
+What it counts (``tests/test_stream.py`` asserts these on a small
+run): exactly 1.0 dispatch/step and 0 steady-state recompiles —
+streaming feeds the same donated program, changing nothing above the
+batch — every record read, none torn.  What it times is for reading by
+hand, a CPU time and no contract: the steady-state step from disk over
+the in-memory step (median over alternating paired segments; a
+single-shot comparison of ~0.3 ms steps is all scheduler noise) and
+the p99 of ``io.queue_wait`` beside one in-memory step.
 """
 import json
 import os
@@ -78,7 +73,7 @@ def _decode_batch(dim):
     return decode_batch
 
 
-def run(n_batches=None, pairs=None):
+def run(n_batches=64, pairs=9):
     import numpy as np  # noqa: F401 (decode closure)
     import steptrace as _steptrace
     import mxnet_tpu as mx
@@ -88,9 +83,6 @@ def run(n_batches=None, pairs=None):
     import tempfile
 
     batch, dim, classes = 64, 32, 4
-    n_batches = n_batches or max(
-        8, int(os.environ.get("BENCH_STREAM_BATCHES", "64")))
-    pairs = pairs or max(3, int(os.environ.get("BENCH_PAIRS", "9")))
 
     root = tempfile.mkdtemp(prefix="stream-probe-")
     try:
@@ -191,45 +183,10 @@ def run(n_batches=None, pairs=None):
             "io_records": rep["counters"].get("io.records", 0),
             "io_bytes": rep["counters"].get("io.bytes", 0),
             "io_torn_records": rep["counters"].get("io.torn_records", 0),
-            "max_ratio": float(os.environ.get(
-                "MXTPU_STREAM_BENCH_MAX_RATIO", "1.10")),
         }
     finally:
         shutil.rmtree(root, ignore_errors=True)
 
 
-def check(result):
-    """The hard contracts — one home, shared by BENCH_MODE=stream and
-    the tier-1 sibling test (which loosens max_ratio via env for noise
-    headroom, never the structural laws)."""
-    if result["dispatches_per_step"] != 1.0:
-        raise AssertionError(
-            "streaming fit loop dispatched %.3f programs/step "
-            "(contract: exactly 1.0 — the stream feeds the same donated "
-            "program)" % result["dispatches_per_step"])
-    if result["compile_count"] != 0:
-        raise AssertionError(
-            "streaming fit loop recompiled %d time(s) in steady state"
-            % result["compile_count"])
-    if result["io_queue_wait_p99_ms"] >= result["io_queue_wait_bound_ms"]:
-        raise AssertionError(
-            "io.queue_wait p99 %.3f ms >= one in-memory step %.3f ms: "
-            "the decode pool starves the consumer"
-            % (result["io_queue_wait_p99_ms"],
-               result["io_queue_wait_bound_ms"]))
-    if result["io_torn_records"]:
-        raise AssertionError(
-            "synthetic shard set produced %d torn records"
-            % result["io_torn_records"])
-    if result["ratio_stream_vs_mem"] > result["max_ratio"]:
-        raise AssertionError(
-            "steady-state streaming step %.4fx the in-memory step "
-            "(contract: <= %.2fx — decode must hide behind the worker "
-            "pool)" % (result["ratio_stream_vs_mem"],
-                       result["max_ratio"]))
-
-
 if __name__ == "__main__":
-    r = run()
-    check(r)
-    print(json.dumps(r))
+    print(json.dumps(run()))
