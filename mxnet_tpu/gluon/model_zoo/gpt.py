@@ -762,6 +762,53 @@ def _quant_scatter(pool, scales, phys, offs, rows, mask):
         q.reshape(r_n, n_kv * d).astype(pool.dtype)), s1
 
 
+def _page_scatter(pool, block_table_row, rows, start, n_rows):
+    """Write ONE slot's consecutive rows into a full-precision page
+    pool with one update a PAGE, not one a row.
+
+    ``pool``: [num_pages, page_size, K_kv * D]; ``rows``: [T, K_kv, D],
+    the slot's positions ``start .. start + T`` in order, of which the
+    first ``n_rows`` are real (both traced scalars); ``block_table_row``:
+    int32 [max_pages_per_seq].  The rows are laid out as the slot's
+    consecutive pages (shifted down by ``start % page_size`` into a
+    zeroed buffer of ``ceil((T + page_size - 1) / page_size)`` pages,
+    block ``j`` being the page that holds position ``(start //
+    page_size + j) * page_size``) and the pool takes one scatter of
+    whole pages: a page is a whole number of the chip's tiles, a row a
+    sublane of one, and the scatter costs by the update.
+
+    - a block with no real row (all past ``start + n_rows``, which
+      includes every block past the block table's end) goes to scratch
+      page 0, as zeros;
+    - the head page keeps its rows below ``start % page_size`` (the
+      prefix rows of a copy-on-write page): they are read back from the
+      pool and written again as they are;
+    - the tail page's rows past ``start + n_rows`` are written as
+      ZEROS: the page is this slot's alone, no kernel reads a row at or
+      past a slot's context, and the decode steps write them one by one.
+
+    Every real row lands bit for bit where ``pool.at[phys, offs].set``
+    put it.  Returns the new pool."""
+    import jax.numpy as jnp
+    from jax import lax
+    t, page_size = rows.shape[0], pool.shape[1]
+    shift = start % page_size
+    n_blocks = -(-(t + page_size - 1) // page_size)
+    block = jnp.arange(n_blocks)
+    pages = jnp.where(
+        block * page_size < shift + n_rows,
+        jnp.take(block_table_row, start // page_size + block, mode="clip"),
+        0)
+    x = jnp.where((jnp.arange(t) < n_rows)[:, None], rows.reshape(t, -1),
+                  0).astype(pool.dtype)
+    blocks = lax.dynamic_update_slice_in_dim(
+        jnp.zeros((n_blocks * page_size, x.shape[1]), pool.dtype),
+        x, shift, 0).reshape(n_blocks, page_size, -1)
+    head = jnp.where((jnp.arange(page_size) < shift)[:, None],
+                     pool[pages[0]], blocks[0])
+    return pool.at[pages].set(blocks.at[0].set(head))
+
+
 def _filter_logits_per_slot(logits, top_k, top_p):
     """Per-slot dynamic top-k / nucleus filtering (jit-compatible:
     sort-based, ``top_k``/``top_p`` are TRACED [S] arrays — per-request
@@ -1214,7 +1261,10 @@ def _prefill_rows(p, tokens, prompt_len, prefix_len, prefix_kv, n_heads):
 def paged_prefill(p, tokens, prompt_len, prefix_len, block_table_row,
                   cow_src, cow_dst, kv_pages, n_heads, sampling=None):
     """Admit one request: a single batched pass over its (padded)
-    prompt that scatters every position's K/V into the slot's pages and
+    prompt that writes every position's K/V into the slot's pages (a
+    whole page an update for full-precision pools, see
+    :func:`_page_scatter`; a row an update for int8 pools, whose scales
+    grow with what a page holds) and
     returns the last prompt position's logits — the first generated
     token costs one forward, not ``prompt_len`` decode steps.  Prefix-
     cache aware (ISSUE 15): only the un-cached SUFFIX of a prompt whose
@@ -1241,7 +1291,8 @@ def paged_prefill(p, tokens, prompt_len, prefix_len, block_table_row,
       top_p, key)`` for the request's first token.
 
     Pad positions (>= the suffix length) are masked out of attention
-    and their K/V is scattered to scratch page 0.  Only the attention
+    and their K/V goes to scratch page 0 or, inside the prompt's last
+    page, is written as zeros.  Only the attention
     math sits under the ``cond``, and the pools never enter it: the
     copy-on-write and the slot's page gather before it and the scatter
     after it touch the (donated) pools on the one path both branches
@@ -1285,6 +1336,7 @@ def paged_prefill(p, tokens, prompt_len, prefix_len, block_table_row,
                               prefix_kv, n_heads),
         lambda: _prefill_rows(p, tokens, prompt_len, 0, None, n_heads))
     suffix_len = prompt_len - prefix_len
+    # where each row lands, for the pools that take a row an update (int8)
     positions = prefix_len + jnp.arange(t_pad)
     valid = jnp.arange(t_pad) < suffix_len
     phys = jnp.where(valid, block_table_row[positions // page_size], 0)
@@ -1303,12 +1355,10 @@ def paged_prefill(p, tokens, prompt_len, prefix_len, block_table_row,
                                         v, valid)
                 new_pages.append((kc, vc, ks, vs))
             else:
-                kc, vc = entry
-                new_pages.append(
-                    (kc.at[phys, offs].set(
-                        k.reshape(t_pad, -1).astype(kc.dtype)),
-                     vc.at[phys, offs].set(
-                        v.reshape(t_pad, -1).astype(vc.dtype))))
+                new_pages.append(tuple(
+                    _page_scatter(pool, block_table_row, x, prefix_len,
+                                  suffix_len)
+                    for pool, x in zip(entry, (k, v))))
     with jax.named_scope("lm_head"):
         last = lax.dynamic_index_in_dim(h, suffix_len - 1, 0,
                                         keepdims=False)

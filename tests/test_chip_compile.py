@@ -255,6 +255,35 @@ def test_engine_program_keeps_the_pools_in_one_layout(
         < pool_elements * pools[0].dtype.itemsize, mem
     assert mem.alias_size_in_bytes == sum(
         int(np.prod(a.shape)) * a.dtype.itemsize for a in pools), mem
+    if program.endswith("prefill") and kv_dtype != "int8":
+        # a prefill writes each pool as the slot's consecutive PAGES: one
+        # scatter a pool, T_pad / page + 1 updates of a whole page (a
+        # mid-page prefix spills into one page more), not T_pad of a row
+        t_pad = examples[2].shape[0]
+        updates = _pool_scatter_updates(text)
+        assert updates == [(t_pad // PAGE + 1, PAGE, HEADS * HEAD_DIM)] \
+            * len(pools), updates
+
+
+def _pool_scatter_updates(text):
+    """The shape of the updates operand of every ``scatter`` into an
+    array of a pool's shape, from a compiled program's text."""
+    import re
+    lines = text.splitlines()
+    shapes = []
+    for at, line in enumerate(lines):
+        m = re.match(r"\s*(?:ROOT )?%%\S+ = \w+\[%d,%d,%d\]\S* scatter\("
+                     r"%%\S+, %%\S+, %%(\S+)\)"
+                     % (PAGES, PAGE, HEADS * HEAD_DIM), line)
+        if not m:
+            continue
+        # the operand is defined above the scatter, in its computation
+        defined = next(
+            d for l in reversed(lines[:at])
+            for d in [re.match(r"\s*%%%s = \w+\[([\d,]*)\]"
+                               % re.escape(m.group(1)), l)] if d)
+        shapes.append(tuple(int(n) for n in defined.group(1).split(",")))
+    return shapes
 
 
 # -- latent pages and per-slot state side by side ---------------------------

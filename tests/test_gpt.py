@@ -535,3 +535,171 @@ def test_gpt_generate_top_k_top_p():
     big = gpt.generate(net, prompt, 4, temperature=1.0, top_k=500,
                        seed=1)
     assert big.shape == (2, 7)
+
+
+# -- paged_prefill writes its K/V as whole pages --------------------------
+
+_PW_PAGE, _PW_TPAD, _PW_PAGES, _PW_MP = 8, 32, 24, 6   # max_seq_len 48
+
+
+@pytest.fixture(scope="module")
+def paged_net():
+    net = gpt.gpt2_tiny()
+    net.initialize(mx.init.Xavier())
+    return gpt.decode_params(net), net.serving_programs().n_heads
+
+
+def _row_scatter_prefill(p, tokens, prompt_len, prefix_len, bt, cow_src,
+                         cow_dst, kv_pages, n_heads):
+    """The oracle: the program as it was, one pool update a token ROW
+    (``kc.at[phys, offs].set(rows)``, pad rows to scratch page 0; int8
+    entries through ``_quant_scatter``, as they still go)."""
+    from mxnet_tpu.ops.pallas.paged_attention import dequant_pages
+    t_pad, page = tokens.shape[0], kv_pages[0][0].shape[1]
+    quantized = len(kv_pages[0]) == 4
+    kv_pages = [tuple(a.at[cow_dst].set(a[cow_src]) for a in entry)
+                for entry in kv_pages]
+    if quantized:
+        prefix_kv = [(dequant_pages(e[0][bt], e[2][bt]),
+                      dequant_pages(e[1][bt], e[3][bt])) for e in kv_pages]
+    else:
+        prefix_kv = [tuple(a[bt].astype(jnp.float32) for a in entry)
+                     for entry in kv_pages]
+    h, rows = jax.lax.cond(           # the program's own two branches
+        prefix_len > 0,
+        lambda: gpt._prefill_rows(p, tokens, prompt_len, prefix_len,
+                                  prefix_kv, n_heads),
+        lambda: gpt._prefill_rows(p, tokens, prompt_len, 0, None, n_heads))
+    positions = prefix_len + jnp.arange(t_pad)
+    valid = jnp.arange(t_pad) < prompt_len - prefix_len
+    phys = jnp.where(valid, bt[jnp.minimum(positions // page, len(bt) - 1)],
+                     0)
+    offs = positions % page
+    new_pages = []
+    for e, (k, v) in zip(kv_pages, rows):
+        if quantized:
+            kc, ks = gpt._quant_scatter(e[0], e[2], phys, offs, k, valid)
+            vc, vs = gpt._quant_scatter(e[1], e[3], phys, offs, v, valid)
+            new_pages.append((kc, vc, ks, vs))
+        else:
+            new_pages.append(tuple(
+                a.at[phys, offs].set(x.reshape(t_pad, -1).astype(a.dtype))
+                for a, x in zip(e, (k, v))))
+    return h[prompt_len - prefix_len - 1] @ p["wte"].T, new_pages
+
+
+def _paged_write_case(case):
+    """``prompt_len``, ``prefix_len``, the block table ``bt`` and ``cow``
+    (source, destination); the slot owns pages 3.. in order, pages 1, 2,
+    9 and 10 are other requests'."""
+    mine = list(range(3, 3 + _PW_MP))
+    miss = dict(prefix_len=0, bt=mine, cow=(0, 0))
+    if case.startswith("miss"):
+        return dict(miss, prompt_len={
+            "miss-1": 1, "miss-page-1": _PW_PAGE - 1, "miss-page": _PW_PAGE,
+            "miss-page+1": _PW_PAGE + 1, "miss-tpad-1": _PW_TPAD - 1,
+            "miss-tpad": _PW_TPAD}[case])
+    if case == "aligned-hit":          # two shared full pages, then its own
+        return dict(prompt_len=2 * _PW_PAGE + 11, prefix_len=2 * _PW_PAGE,
+                    bt=[1, 2] + mine[:_PW_MP - 2], cow=(0, 0))
+    if case in ("midpage-hit", "midpage-hit-short"):
+        # one shared page, then 5 rows of donor page 2 copied into page 3
+        prefix = _PW_PAGE + 5
+        return dict(prompt_len=prefix + (2 if case.endswith("short")
+                                         else 20), prefix_len=prefix,
+                    bt=[1] + mine[:_PW_MP - 1], cow=(2, 3))
+    if case == "past-max-seq-len":     # prefix_len + T_pad > max_seq_len
+        prefix = 3 * _PW_PAGE + 3
+        return dict(prompt_len=_PW_MP * _PW_PAGE, prefix_len=prefix,
+                    bt=[1, 2, 9] + mine[:_PW_MP - 3], cow=(10, 3))
+    if case == "nan-scratch":
+        return dict(miss, prompt_len=_PW_PAGE + 3, nan_scratch=True)
+    raise KeyError(case)
+
+
+_PW_CASES = ["miss-1", "miss-page-1", "miss-page", "miss-page+1",
+             "miss-tpad-1", "miss-tpad", "aligned-hit", "midpage-hit",
+             "midpage-hit-short", "past-max-seq-len", "nan-scratch"]
+
+
+def _paged_write_run(paged_net, case, dtype):
+    """One case through ``paged_prefill`` and through the oracle, over
+    pools full of seeded, distinct values (a row the program leaves
+    alone still holds them).  ``dtype`` None: int8 entries with their
+    per-page, per-head scales.  Returns ``(case, pools, (logits, new
+    pools), (oracle logits, oracle pools))``."""
+    p, n_heads = paged_net
+    c = _paged_write_case(case)
+    rng = np.random.default_rng(11)
+    shape = (_PW_PAGES, _PW_PAGE, p["wte"].shape[1])
+    pools = []
+    for _ in p["layers"]:
+        if dtype is None:
+            pools.append(tuple(
+                [jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+                 for _ in range(2)]
+                + [jnp.asarray(rng.uniform(0.01, 0.05, (_PW_PAGES, n_heads)),
+                               jnp.float32) for _ in range(2)]))
+        else:
+            pools.append(tuple(jnp.asarray(rng.normal(size=shape), dtype)
+                               for _ in range(2)))
+    if c.get("nan_scratch"):
+        pools = [tuple(a.at[0].set(jnp.nan) for a in e) for e in pools]
+    suffix = c["prompt_len"] - c["prefix_len"]
+    tokens = np.zeros(_PW_TPAD, np.int32)
+    tokens[:suffix] = np.random.default_rng(5).integers(1, 256, suffix)
+    args = (p, jnp.asarray(tokens), jnp.int32(c["prompt_len"]),
+            jnp.int32(c["prefix_len"]), jnp.asarray(c["bt"], jnp.int32),
+            jnp.int32(c["cow"][0]), jnp.int32(c["cow"][1]), pools, n_heads)
+    logits, _, new = jax.jit(gpt.paged_prefill, static_argnums=8)(*args)
+    return c, pools, (logits, new), jax.jit(
+        _row_scatter_prefill, static_argnums=8)(*args)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", _PW_CASES)
+def test_paged_prefill_writes_pages_like_the_row_scatter(paged_net, case,
+                                                         dtype):
+    """After ``paged_prefill`` every pool row at a position below
+    ``prompt_len`` is the row scatter's bit for bit, the copy-on-write
+    page keeps its prefix rows, the tail page's rows past the prompt are
+    zeros, and every page the slot does not own is untouched, scratch
+    page 0 apart."""
+    c, pools, (logits, new), (want_logits, want) = _paged_write_run(
+        paged_net, case, jnp.dtype(dtype))
+    prompt_len, prefix_len, bt = c["prompt_len"], c["prefix_len"], c["bt"]
+    assert np.isfinite(np.asarray(logits)).all()
+    np.testing.assert_array_equal(np.asarray(logits),
+                                  np.asarray(want_logits))
+    # what the slot writes: its own pages from the one holding position
+    # ``prefix_len`` to the one holding the prompt's last token
+    first = prefix_len // _PW_PAGE
+    written = bt[first:-(-prompt_len // _PW_PAGE)]
+    untouched = [i for i in range(1, _PW_PAGES) if i not in written]
+    assert c["cow"][0] == 0 or c["cow"][0] in untouched   # the donor page
+    assert all(page in untouched for page in bt[:first])  # shared pages
+    tail = prompt_len % _PW_PAGE
+    for layer, (got_e, want_e, old_e) in enumerate(zip(new, want, pools)):
+        for got, ref, old in zip(got_e, want_e, old_e):
+            assert got.dtype == old.dtype
+            got, ref, old = (np.asarray(a.astype(jnp.float32))
+                             for a in (got, ref, old))
+            for pos in range(prompt_len):
+                np.testing.assert_array_equal(
+                    got[bt[pos // _PW_PAGE], pos % _PW_PAGE],
+                    ref[bt[pos // _PW_PAGE], pos % _PW_PAGE],
+                    err_msg="layer %d position %d" % (layer, pos))
+            if tail:   # the tail page past the prompt: this slot's alone
+                assert not got[bt[prompt_len // _PW_PAGE], tail:].any()
+            np.testing.assert_array_equal(got[untouched], old[untouched])
+
+
+@pytest.mark.parametrize("case", ["miss-page+1", "midpage-hit"])
+def test_paged_prefill_int8_entries_keep_the_row_scatter(paged_net, case):
+    """int8 entries take ``_quant_scatter`` as before: every pool and
+    every scale is the row scatter's."""
+    _, _, (_, new), (_, want) = _paged_write_run(paged_net, case, None)
+    for got_e, want_e in zip(new, want):
+        assert len(got_e) == 4
+        for got, ref in zip(got_e, want_e):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
