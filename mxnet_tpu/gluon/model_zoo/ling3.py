@@ -50,15 +50,17 @@ import functools
 import numpy as _np
 
 from ..block import Block
+from . import decoder_blocks as _blocks
+from .decoder_blocks import (EPS, LATENT_ALIGN, latent_width,
+                             mm as _mm, rms as _rms, head as _head,
+                             swiglu as _swiglu, moe as _moe,
+                             latent_rows as _latent_rows,
+                             moe_stats_vector as _stats_vector)
 
 __all__ = ["Ling3LM", "ling3_flash_vl", "ling3_tiny", "decode_params",
            "param_tree",
            "forward", "paged_decode_step", "paged_prefill",
            "layer_kinds", "LATENT_ALIGN"]
-
-EPS = 1e-6
-#: a latent row is padded to a multiple of this many lanes
-LATENT_ALIGN = 128
 
 #: the published widths (config.json of the source, language model)
 PUBLISHED = {
@@ -80,11 +82,6 @@ def layer_kinds(cfg):
     return [("mla" if (l + 1) % cfg["layer_group_size"] == 0 else "kda",
              "dense" if l < cfg["first_k_dense_replace"] else "moe")
             for l in cfg["layers"]]
-
-
-def latent_width(cfg):
-    w = cfg["kv_lora_rank"] + cfg["qk_rope_head_dim"]
-    return -(-w // LATENT_ALIGN) * LATENT_ALIGN
 
 
 def _param_shapes(cfg):
@@ -165,24 +162,7 @@ class Ling3LM(Block):
         parameter at a time (a share's expert stack is gigabytes):
         normal(0, 0.02), norm gains 1, the expert bias 0.  ``seed``: a
         whole number or a PRNG key."""
-        import jax
-        import jax.numpy as jnp
-        from ...ndarray import NDArray
-
-        def make(key, shape, dtype, init):
-            if init == "normal":
-                return (0.02 * jax.random.normal(key, shape, jnp.float32)) \
-                    .astype(dtype)
-            return (jnp.ones if init == "ones" else jnp.zeros)(shape, dtype)
-
-        make = jax.jit(make, static_argnums=(1, 2, 3))
-        key = seed if hasattr(seed, "shape") \
-            else jax.random.PRNGKey(int(seed) & 0x7FFFFFFF)
-        for i, p in enumerate(self.collect_params().values()):
-            p.set_data(NDArray(make(
-                jax.random.fold_in(key, i), tuple(p.shape),
-                jnp.dtype(p.dtype).name, self._inits[p.name])))
-        return self
+        return _blocks.init_seeded(self, self._inits, seed)
 
     def forward(self, tokens):
         import jax.numpy as jnp
@@ -197,7 +177,7 @@ class Ling3LM(Block):
         cfg = self.cfg
         h, d = cfg["num_attention_heads"], cfg["head_dim"]
         hist = cfg["short_conv_kernel_size"] - 1
-        kinds = [LatentPages(latent_width(cfg)) if mix == "mla"
+        kinds = [LatentPages((latent_width(cfg),)) if mix == "mla"
                  else SlotState((("state", (h, d, d), "float32"),
                                  ("conv", (hist, 3 * h * d), None)))
                  for mix, _ in layer_kinds(cfg)]
@@ -243,25 +223,8 @@ def param_tree(cfg, leaf):
     """The parameter tree the programs take, by layer, with
     ``leaf(path, shape)`` at every parameter (the net's live arrays, or
     shapes for a compile without weights)."""
-    shapes = _param_shapes(cfg)
-
-    def g(path):
-        return leaf(path, shapes[path][0])
-
-    layers = []
-    for i in range(len(cfg["layers"])):
-        pre = "l%d_" % i
-        names = [k[len(pre):] for k in shapes if k.startswith(pre)]
-        lp = {"ln1_g": g(pre + "ln1_gamma"), "ln2_g": g(pre + "ln2_gamma")}
-        for group in ("mla", "kda", "mlp", "moe"):
-            sub = {n[len(group) + 1:].replace("gamma", "g")
-                   .replace("router_bias", "router_b"): g(pre + n)
-                   for n in names if n.startswith(group + "_")}
-            if sub:
-                lp[group] = sub
-        layers.append(lp)
-    return {"wte": g("wte"), "head": g("head"), "lnf_g": g("lnf_gamma"),
-            "layers": layers}
+    return _blocks.param_tree(_param_shapes(cfg), len(cfg["layers"]),
+                              ("mla", "kda", "mlp", "moe"), leaf)
 
 
 def decode_params(net):
@@ -274,67 +237,10 @@ def decode_params(net):
 # the layers, as functions of the parameter tree
 # ---------------------------------------------------------------------------
 
-def _mm(x, w):
-    """``x @ w`` with the activation in the weight's stored type and
-    float32 accumulation."""
-    import jax.numpy as jnp
-    return jnp.dot(x.astype(w.dtype), w, preferred_element_type=jnp.float32)
-
-
-def _rms(x, g):
-    import jax
-    import jax.numpy as jnp
-    x = x.astype(jnp.float32)
-    return x * jax.lax.rsqrt((x * x).mean(-1, keepdims=True) + EPS) \
-        * g.astype(jnp.float32)
-
-
 def _rope(x, pos, theta):
     """Rotate-half RoPE on the last axis; ``pos`` indexes the first."""
-    import jax.numpy as jnp
-    half = x.shape[-1] // 2
-    inv = jnp.float32(theta) ** (
-        -jnp.arange(half, dtype=jnp.float32) / half)
-    ang = pos.astype(jnp.float32)[:, None] * inv[None, :]
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    if x.ndim == 3:
-        cos, sin = cos[:, None, :], sin[:, None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def _head(x, w):
-    """Logits over the vocabulary slice: ``x @ w.T`` for ``w`` stored
-    ``[vocab, units]``."""
-    import jax.numpy as jnp
-    from jax import lax
-    return lax.dot_general(x.astype(w.dtype), w, (((x.ndim - 1,), (1,)),
-                                                  ((), ())),
-                           preferred_element_type=jnp.float32)
-
-
-def _swiglu(x, gu_w, down_w):
-    import jax
-    gu = _mm(x, gu_w)
-    half = gu.shape[-1] // 2
-    return _mm(jax.nn.silu(gu[..., :half]) * gu[..., half:], down_w)
-
-
-def _moe(lp, x, cfg):
-    """Routed experts (held share) + shared expert.  Returns
-    ``(y, experts [T, k], stats)``."""
-    import jax
-    from ...parallel import moe
-    with jax.named_scope("moe"):
-        experts, weights = moe.grouped_topk_route(
-            x, lp["router_w"], lp["router_b"], cfg["n_group"],
-            cfg["topk_group"], cfg["num_experts_per_tok"],
-            cfg["routed_scaling_factor"])
-        y, stats = moe.held_experts_ffn(
-            x, experts, weights, lp["gu_w"], lp["down_w"],
-            cfg["experts_held"][0])
-        return y + _swiglu(x, lp["sh_gu_w"], lp["sh_down_w"]), experts, \
-            stats
+    return _blocks.rope(x, pos,
+                        _blocks.rope_inv_freq(theta, x.shape[-1] // 2))
 
 
 def _mla_qkv(lp, x, pos, cfg):
@@ -350,12 +256,6 @@ def _mla_qkv(lp, x, pos, cfg):
     return (q[..., :dn], _rope(q[..., dn:], pos, cfg["rope_theta"]),
             _rms(kva[:, :rank], lp["kv_norm_g"]),
             _rope(kva[:, rank:], pos, cfg["rope_theta"]))
-
-
-def _latent_rows(c, k_rope, width, dtype):
-    import jax.numpy as jnp
-    rows = jnp.concatenate([c, k_rope], -1)
-    return jnp.pad(rows, ((0, 0), (0, width - rows.shape[1]))).astype(dtype)
 
 
 def _mla_out(lp, x, o, cfg):
@@ -485,23 +385,8 @@ def _kda_decode(lp, x, active, state, conv, cfg):
     return _kda_out(lp, x, o, cfg), state, conv
 
 
-#: the decode program's last output: a float32 vector of these counts,
-#: summed over the expert layers of one step
-DECODE_STATS = ("experts_hit", "local_assignments",
-                "max_tokens_per_expert", "assignments", "held_experts",
-                "expert_layers")
-
-
-def _stats_vector(stats, n_assign, held):
-    import jax.numpy as jnp
-    if not stats:
-        return jnp.zeros(len(DECODE_STATS), jnp.float32)
-    return jnp.stack([
-        sum(s["experts_hit"] for s in stats),
-        sum(s["local_assignments"] for s in stats),
-        sum(s["max_tokens_per_expert"] for s in stats),
-        jnp.float32(n_assign * len(stats)),
-        jnp.float32(held * len(stats)), jnp.float32(len(stats))])
+#: the decode program's last output: a float32 vector of these counts
+DECODE_STATS = _blocks.MOE_STATS
 
 
 def _sequence_pass(p, tokens, prompt_len, cfg):

@@ -250,7 +250,12 @@ class ServingEngine:
     ``num_slots`` decode slots, a shared pool of ``num_pages`` KV pages
     of ``page_size`` tokens; prompts are padded to ``max_prefill_len``
     (one prefill program) and ``prompt + max_new <= max_seq_len`` per
-    request.  Greedy argmax decoding (deterministic — the join/leave
+    request.  A model whose prefill is CHUNKED
+    (``ServingPrograms.chunked_prefill``) admits longer prompts: the one
+    program runs a chunk of ``max_prefill_len`` rows at a time against
+    the slot's own pages, one run an engine step for the prefilling
+    slot admitted first, while the other slots go on decoding
+    (SERVING.md section 3).  Greedy argmax decoding (deterministic — the join/leave
     bit-exactness invariant is testable), optional ``eos_id`` early
     stop.
 
@@ -326,6 +331,7 @@ class ServingEngine:
                              "max_len %d" % (self.max_seq_len, max_len))
         if self.max_prefill_len > self.max_seq_len:
             raise ValueError("max_prefill_len > max_seq_len")
+        self._chunked = self._model.chunked_prefill
         # speculative decoding (ISSUE 16): up to ``spec_k`` host-drafted
         # tokens per slot are VERIFIED by the same single donated decode
         # dispatch (no second program, no shape churn — k is a compile-
@@ -494,6 +500,9 @@ class ServingEngine:
         self.stat_totals = {"decode": {}, "prefill": {}}
         self.decode_steps = 0
         self.prefills = 0
+        #: runs of the prefill program for a chunked model (``prefills``
+        #: counts the prompts whose last chunk was read)
+        self.prefill_chunks = 0
         # scale-poison repairs per resident request (rid -> count): the
         # divergence-guard recovery below re-prefills a victim at most
         # a few times before declaring its state unrecoverable
@@ -596,7 +605,7 @@ class ServingEngine:
             shape = pages + (kind.heads * kind.head_dim,)
             return (zeros(shape, dt), zeros(shape, dt))
         if isinstance(kind, LatentPages):
-            return (zeros(pages + (kind.width,), dt),)
+            return tuple(zeros(pages + (w,), dt) for w in kind.widths)
         return tuple(zeros((self.num_slots + 1,) + tuple(shape),
                            dtype or dt)
                      for _, shape, dtype in kind.arrays)
@@ -613,7 +622,7 @@ class ServingEngine:
                 per_page += self.alloc.page_bytes(kind.heads,
                                                   kind.head_dim)
             elif isinstance(kind, LatentPages):
-                per_page += self.alloc.latent_page_bytes(kind.width)
+                per_page += self.alloc.latent_page_bytes(sum(kind.widths))
         return per_page / float(self.page_size)
 
     @property
@@ -935,7 +944,7 @@ class ServingEngine:
                   "max_new": int(max_new), "deadline_s": deadline_s,
                   "sampling": (None if sampling is None
                                else sampling.to_doc())})
-        if prompt.size > self.max_prefill_len:
+        if prompt.size > self.max_prefill_len and not self._chunked:
             self._close_unplaced(trace, owned, VERDICT_REJECTED)
             raise ValueError(
                 "prompt length %d exceeds max_prefill_len %d"
@@ -1250,57 +1259,95 @@ class ServingEngine:
                              error=str(e))
                 _telemetry.counter("serving.prefill_errors").inc()
                 continue
-            # one span a request: a device gap under it is that
-            # request's (rid and trace tie it to the request events)
-            with _telemetry.span(
-                    "serve_prefill", "serving", rid=req.rid,
-                    trace=req.trace, prompt=int(req.prompt.size),
-                    prefix_len=req.prefix_len,
-                    queue_wait_us=int(req.queue_wait_s * 1e6)):
-                sent = self._send_prefill(req)
-                if self._decode_ahead:
-                    # read when its turn comes (``_step``)
-                    self._unread.append(sent)
-                else:
-                    produced += self._emit_prefill(sent)
+            if self._chunked:
+                # its chunks go out below, one an engine step
+                continue
+            produced += self._prefill_run(req)
+        if self._chunked:
+            # one chunk run a step, for the slot admitted first
+            req = next(iter(self.sched.prefilling), None)
+            if req is not None:
+                produced += self._prefill_run(req)
         return produced
 
+    def _prefill_run(self, req):
+        """One run of the prefill program for ``req``: its whole prompt,
+        or for a chunked model its next ``max_prefill_len`` rows.
+        Returns the tokens produced (none with ``decode_ahead``: the run
+        waits in ``_unread``)."""
+        # one span a run: a device gap under it is that request's (rid
+        # and trace tie it to the request events)
+        with _telemetry.span(
+                "serve_prefill", "serving", rid=req.rid,
+                trace=req.trace, prompt=int(req.prompt.size),
+                prefix_len=req.prefix_len, offset=req.prefilled,
+                queue_wait_us=int(req.queue_wait_s * 1e6)):
+            sent = self._send_prefill(req)
+            if self._decode_ahead:
+                # read when its turn comes (``_step``)
+                self._unread.append(sent)
+                return 0
+            return self._emit_prefill(sent)
+
     def _send_prefill(self, req):
-        """The prefill dispatch of one admitted request; nothing is
-        waited for.  With ``decode_ahead`` the program also sets the
-        first token and the key in the device's per-slot rows, as the
-        newest unread dispatch left them, and the next decode takes
-        them from there.  Returns the dispatch's record."""
+        """One prefill dispatch of an admitted request; nothing is
+        waited for.  A chunked model's covers the prompt's rows from
+        ``req.prefilled`` on, as many as the program holds.  With
+        ``decode_ahead`` the program also sets the first token and the
+        key in the device's per-slot rows, as the newest unread
+        dispatch left them, and the next decode takes them from there.
+        Returns the dispatch's record."""
         with _watchdog.guard("serve.prefill"):
             with _telemetry.stamp_span("serve_prefill.dispatch") as disp:
                 samp = self._arm_slot_sampling(req)
                 toks = _np.zeros(self.max_prefill_len, _np.int32)
                 # req.prefix_len is 0 with the cache off or on a miss:
                 # the suffix is then the whole prompt and the program's
-                # dense branch runs
-                suffix = req.prompt[req.prefix_len:]
-                toks[:suffix.size] = suffix
+                # dense branch runs.  A chunk's first row stands where a
+                # cached prefix would end
+                start = req.prefilled if self._chunked else req.prefix_len
+                end = min(int(req.prompt.size),
+                          start + self.max_prefill_len)
+                toks[:end - start] = req.prompt[start:end]
                 logits, first, new_key, stats, rows = self._run_prefill(
-                    toks, req.prompt.size, req.prefix_len,
+                    toks, end, start,
                     self.sched.block_tables[req.slot].copy(),
                     req.cow_src if req.cow_src is not None
                     else SCRATCH_PAGE,
                     req.cow_dst if req.cow_dst is not None
                     else SCRATCH_PAGE, samp, req.slot)
+                req.prefilled = end
                 _fetch_async(first, new_key, stats)
-        return {"reqs": [req], "logits": logits, "first": first,
+        # only the run that ends the prompt yields the request's token
+        last = end == req.prompt.size
+        return {"reqs": [req] if last else [], "req": req, "last": last,
+                "span": (start, end), "logits": logits, "first": first,
                 "new_key": new_key, "stats": stats, "rows": rows,
                 "disp": disp}
 
     def _emit_prefill(self, sent):
         """Wait for one prefill's first token and do the bookkeeping
         that commits it.  Returns the tokens emitted."""
-        req, disp = sent["reqs"][0], sent["disp"]
+        req, disp = sent["req"], sent["disp"]
         with _watchdog.guard("serve.prefill"):
             with _telemetry.stamp_span("serve_prefill.sync") as sync:
                 first = int(sent["first"])          # device sync
                 if sent["stats"] is not None:
                     self._note_stats("prefill", _np.asarray(sent["stats"]))
+        if self._chunked:
+            start, end = sent["span"]
+            self.prefill_chunks += 1
+            _telemetry.counter("serving.prefill.chunks").inc()
+            _telemetry.counter("serving.prefill.chunk_rows").inc(
+                end - start)
+            _telemetry.counter("serving.prefill.chunk_rows_padded").inc(
+                self.max_prefill_len)
+            _telemetry.note_request_event(
+                req.trace, "prefill_chunk", t_ns=sync.t1,
+                args={"offset": start, "rows": end - start,
+                      "last": sent["last"]})
+            if not sent["last"]:
+                return 0
         # a prefill read after its step: its two phases are noted back
         # to back, each at its own length
         t1 = sync.t0 if self._decode_ahead else disp.t1
@@ -1498,7 +1545,7 @@ class ServingEngine:
             reqs = []
             for req in running:
                 n = len(req.tokens) + unread[req.rid]
-                if n >= req.max_new:
+                if n >= req.max_new or req.prefilling:
                     continue
                 if unread[req.rid]:
                     carried[req.slot] = True
@@ -1856,7 +1903,13 @@ class ServingEngine:
         totals = self.stat_totals[program]
         for name, v in doc.items():
             totals[name] = totals.get(name, 0) + v
-            _telemetry.counter("serving.moe.%s" % name).inc(v)
+            # a count of another layer than the experts' names its
+            # family: ``dsa.rows_attended`` -> serving.dsa.rows_attended
+            family, _, leaf = name.rpartition(".")
+            if family:
+                _telemetry.counter("serving.%s.%s" % (family, leaf)).inc(v)
+            else:
+                _telemetry.counter("serving.moe.%s" % name).inc(v)
         if program != "decode":
             return
         layers = doc.get("expert_layers")

@@ -14,9 +14,12 @@ Cache kinds:
   K_kv * D]`` a layer (fp32 / bf16 / int8 pages, grouped-query heads,
   prefix sharing, speculative decoding: everything SERVING.md section 2
   describes).  A model that names no kinds keeps these in every layer;
-- :class:`LatentPages` — ONE paged pool ``[num_pages, page_size,
-  width]`` a layer, a row serving as key and value (latent attention);
-  addressed by the same allocator and block tables;
+- :class:`LatentPages` — one paged pool ``[num_pages, page_size,
+  width]`` a layer for each of ``widths``, all addressed by the same
+  allocator and block table.  ``(width,)``: a row serving as key and
+  value (latent attention).  ``(width, index_width)``: beside the latent
+  rows a narrower row a token that a query scores to CHOOSE which latent
+  rows it reads (learned sparse attention);
 - :class:`SlotState` — arrays with one row a SLOT (``[num_slots + 1,
   *shape]``, the last row scratch): recurrent state that a prefill
   overwrites at admission and a decode step updates in place; freed
@@ -31,7 +34,7 @@ from __future__ import annotations
 import collections
 
 KVPages = collections.namedtuple("KVPages", "heads head_dim")
-LatentPages = collections.namedtuple("LatentPages", "width")
+LatentPages = collections.namedtuple("LatentPages", "widths")
 #: ``arrays``: ``((name, per-slot shape, dtype or None for the pools'
 #: dtype), ...)``
 SlotState = collections.namedtuple("SlotState", "arrays")
@@ -53,13 +56,19 @@ class ServingPrograms:
     - ``decode_stats``: names of the float32 counts a decode step
       returns in its trailing ``aux["stats"]`` (empty: the programs
       return no ``aux``);
+    - ``chunked_prefill``: the prefill program admits a prompt a CHUNK
+      at a time: its ``prefix_len`` argument is the position of the
+      chunk's first row, ``prompt_len`` the prompt's length so far, and
+      it reads what the chunks before wrote from the slot's own pages.
+      The engine then admits prompts longer than ``max_prefill_len``
+      and runs the program as often as a prompt needs;
     - ``config_key``: whatever the programs bake in that the input
       shapes do not show (it joins the engine's compile-cache key).
     """
 
     def __init__(self, n_heads, max_len, decode_params, decode_step,
                  prefill, spec_decode_step=None, cache_kinds=None,
-                 decode_stats=(), config_key=""):
+                 decode_stats=(), config_key="", chunked_prefill=False):
         self.n_heads = int(n_heads)
         self.max_len = int(max_len)
         self.decode_params = decode_params
@@ -70,6 +79,7 @@ class ServingPrograms:
             else tuple(cache_kinds)
         self.decode_stats = tuple(decode_stats)
         self.config_key = str(config_key)
+        self.chunked_prefill = bool(chunked_prefill)
 
     @property
     def has_aux(self):

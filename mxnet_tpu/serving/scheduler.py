@@ -2,8 +2,10 @@
 
 Fixed-capacity decode SLOTS (static ``num_slots`` — the decode program
 compiles once, for one shape) with dynamic OCCUPANCY: requests join a
-free slot between decode steps (one prefill dispatch fills their pages)
-and leave the instant they finish (pages released, slot free for the
+free slot between decode steps (one prefill dispatch fills their pages;
+for a model whose prefill is chunked, as many dispatches as the prompt
+needs, a bounded number an engine step, the slot PREFILLING meanwhile:
+``Request.prefilling``) and leave the instant they finish (pages released, slot free for the
 next queued request).  No recompiles, no barrier on the longest
 sequence — the continuous-batching scheme of Orca/vLLM applied to the
 predictor path (ROADMAP item 2).
@@ -130,7 +132,7 @@ class Request:
                  "deadline_t", "verdict", "error", "trace",
                  "trace_owned", "sampling", "prefix_len",
                  "shared_count", "cow_src", "cow_dst", "spec_k",
-                 "last_poll_t")
+                 "last_poll_t", "prefilled")
 
     def __init__(self, rid, prompt, max_new, deadline_s=None):
         self.rid = rid
@@ -183,6 +185,16 @@ class Request:
         # sweep must NEVER reclaim (only a poller that started and then
         # went silent counts as vanished).
         self.last_poll_t = None
+        # prompt tokens whose prefill has been SENT (chunked prefill): a
+        # resident request below its prompt's length is PREFILLING and
+        # sits decode steps out; at it, the request decodes
+        self.prefilled = 0
+
+    @property
+    def prefilling(self):
+        """Resident with part of its prompt still to prefill: the third
+        state of a slot, between free and decoding."""
+        return self.state == RUNNING and self.prefilled < self.prompt.size
 
     @property
     def done(self):
@@ -490,6 +502,14 @@ class ContinuousBatchingScheduler:
     @property
     def running(self):
         return [r for r in self._slots if r is not None]
+
+    @property
+    def prefilling(self):
+        """Residents whose prompt is not all prefilled yet, in the order
+        they were admitted."""
+        return sorted((r for r in self._slots
+                       if r is not None and r.prefilling),
+                      key=lambda r: r.rid)
 
     @property
     def queued(self):

@@ -368,6 +368,34 @@ def test_hybrid_engine_program_updates_every_cache_in_place(
         int(np.prod(a.shape)) * a.dtype.itemsize for a in caches), mem
 
 
+@pytest.mark.parametrize("kernel,groups,rows", [
+    ("dsa_index", 16, 1), ("dsa_index", 64, 32),
+    ("mla_sparse", 16, 1), ("mla_sparse", 64, 1)])
+def test_sparse_latent_attention_kernels_compile(chip, kernel, groups,
+                                                 rows):
+    """The indexer's scoring over paged keys and the latent attention
+    over a list of rows, at DeepSeek-V3.2's published widths and the
+    benchmark cell's sizes (64-token pages, 512 pages a sequence, 2,048
+    selected rows): one query row a slot (decode) and a block of a
+    prefill chunk's rows."""
+    sla = importlib.import_module(
+        "mxnet_tpu.ops.pallas.sparse_latent_attention")
+    i32 = jnp.int32
+    if kernel == "dsa_index":
+        compiled = compile_for_chip(
+            sla.dsa_index, chip((groups, rows, 64, 128), jnp.bfloat16),
+            chip((groups, rows, 64), jnp.float32),
+            chip((8192, 64, 128), jnp.bfloat16), chip((16, 512), i32),
+            chip((groups,), i32), chip((groups,), i32),
+            chip((groups,), i32))
+    else:
+        compiled = compile_for_chip(
+            lambda q, r, n: sla.mla_sparse(q, r, n, 512, 0.1),
+            chip((groups, 128, 640), jnp.float32),
+            chip((groups, 2048, 640), jnp.bfloat16), chip((groups,), i32))
+    assert kernel in compiled.as_text()
+
+
 @pytest.mark.parametrize("head_dim,packed", [(64, False), (64, True),
                                              (128, False)])
 def test_flash_fwd_bwd_compiles(chip, head_dim, packed):
