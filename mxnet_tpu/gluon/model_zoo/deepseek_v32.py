@@ -34,8 +34,10 @@ layer keeps TWO paged pools that one block table addresses
 k_rope | zeros]`` (576 values in 640 lanes, as ``ling3.py`` stores
 them) and the indexer's keys (128 values a token).  Both programs
 write their tokens' rows, score the slot's paged indexer keys
-(``dsa_index``), take the top ``index_topk`` positions, gather those
-latent rows BY ROW and attend over the list (``mla_sparse``).
+(``dsa_index``), take the ``index_topk`` positions of the largest
+scores (``dsa_select``: the set ``lax.top_k`` gives, found without a
+sort), gather those latent rows BY ROW and attend over the list
+(``mla_sparse``).
 
 The prefill is CHUNKED: one program of ``T`` rows that takes the
 position of its first row (the engine's ``prefix_len`` argument) and
@@ -83,9 +85,6 @@ PUBLISHED = {
 PREFILL_ATTN_ROWS = 64
 #: query rows a group of a prefill chunk's index scoring
 PREFILL_INDEX_ROWS = 32
-#: a prefill chunk's selection sorts the narrowest multiple of this many
-#: keys that holds its context
-SELECT_BUCKET = 4096
 
 #: the programs' trailing counts: the expert layers' (``ling3.py``'s)
 #: and, summed over layers, the latent rows attended and in context
@@ -320,23 +319,6 @@ def _write_rows(pool, rows, phys, offs):
         return pool.at[phys, offs].set(rows.astype(pool.dtype))
 
 
-def _select(scores, k, context_len):
-    """The positions of each row's ``k`` largest scores, int32 [T, k]
-    (largest first).  ``scores`` [T, W] holds ``-1e30`` past
-    ``context_len``; a wide ``scores`` is sorted over the narrowest
-    multiple of ``SELECT_BUCKET`` keys that holds the context."""
-    import jax.numpy as jnp
-    from jax import lax
-    w = scores.shape[1]
-    if context_len is None or w <= SELECT_BUCKET:
-        return lax.top_k(scores, k)[1].astype(jnp.int32)
-    widths = list(range(SELECT_BUCKET, w, SELECT_BUCKET)) + [w]
-    which = (context_len > jnp.asarray(widths[:-1], jnp.int32)).sum()
-    return lax.switch(
-        which, [lambda s, w_=w_: lax.top_k(s[:, :w_], k)[1]
-                .astype(jnp.int32) for w_ in widths], scores)
-
-
 def _ffn(lp, x, cfg, routing, stats):
     h = _rms(x, lp["ln2_g"])
     if "mlp" in lp:
@@ -415,7 +397,8 @@ def paged_decode_step(p, tokens, positions, active, caches, block_tables,
     import jax.numpy as jnp
     from jax import lax
     from .gpt import sample_tokens
-    from ...ops.pallas.sparse_latent_attention import dsa_index, gather_rows
+    from ...ops.pallas.sparse_latent_attention import (dsa_index, dsa_select,
+                                                       gather_rows)
 
     s_n = tokens.shape[0]
     topk = cfg["index_topk"]
@@ -441,7 +424,7 @@ def paged_decode_step(p, tokens, positions, active, caches, block_tables,
         with jax.named_scope("index"):
             scores = dsa_index(qi[:, None], wi[:, None], ipool,
                                block_tables, slots, ctx, positions)[:, 0]
-            sel = _select(scores, k_sel, None)
+            sel = dsa_select(scores, ctx, k_sel)
         o = _attend(lp["attn"], q_nope, q_rope,
                     gather_rows(pool, block_tables, sel), n_valid, cfg)
         x = x + _mm(o, lp["attn"]["o_w"])
@@ -483,7 +466,7 @@ def paged_prefill(p, tokens, prompt_len, prefix_len, block_table_row,
     import jax.numpy as jnp
     from jax import lax
     from .gpt import _first_token
-    from ...ops.pallas.sparse_latent_attention import dsa_index
+    from ...ops.pallas.sparse_latent_attention import dsa_index, dsa_select
 
     del cow_src, cow_dst
     t_pad = tokens.shape[0]
@@ -509,8 +492,8 @@ def paged_prefill(p, tokens, prompt_len, prefix_len, block_table_row,
         pool = _write_rows(pool, _latent_rows(c, k_rope, pool.shape[2],
                                               pool.dtype), phys, offs)
         ipool = _write_rows(ipool, ki, phys, offs)
-        # index scores a group of rows at a time, the selection over the
-        # narrowest bucket of keys that holds the context
+        # index scores a group of rows at a time, the selection over each
+        # row's own context
         r_i = _rows_per_block(t_pad, PREFILL_INDEX_ROWS)
         groups = t_pad // r_i
         first = positions[::r_i]
@@ -521,7 +504,7 @@ def paged_prefill(p, tokens, prompt_len, prefix_len, block_table_row,
                 jnp.zeros(groups, jnp.int32),
                 jnp.minimum(first + r_i, prompt_len), first) \
                 .reshape(t_pad, max_ctx)
-            sel = _select(scores, k_sel, prompt_len)
+            sel = dsa_select(scores, in_context, k_sel)
         # attention a block of rows at a time (a block's gathered lists
         # are [rows, K, width] at once), gathered BY POSITION from the
         # slot's pages laid side by side once a layer: a page-table

@@ -16,6 +16,10 @@ keeps the ``index_topk`` largest, and attends over those rows only.
   time by the cell itself (the next block's copies run under this
   block's matmul), blocks past the group's context neither read nor
   scored;
+- :func:`dsa_select` keeps each row's ``k`` largest scores: exactly the
+  set ``lax.top_k`` keeps, found by the k-th largest score and a
+  compaction, where XLA sorts all of a row's keys (70.7 ms at ``[2048,
+  32768]``, v5e, PERF.md, PR 32);
 - :func:`mla_sparse` is absorbed latent attention of ``N`` query rows,
   each over its own LIST of rows (``[N, K, W]``, the first
   ``n_valid[n]`` count): one row a slot in decode, a block of a
@@ -44,6 +48,12 @@ _flash = importlib.import_module(__package__ + ".flash_attention")
 INDEX_BLOCK_KEYS = 1024
 #: rows of a list a block of :func:`mla_sparse`
 SPARSE_BLOCK_ROWS = 512
+#: rows a grid cell of :func:`dsa_select`, and the tiles of 128 lanes a
+#: step of its passes over them
+SELECT_ROWS = 8
+SELECT_CHUNK_TILES = 32
+_LANE_BITS = 7
+_LANES = 1 << _LANE_BITS
 
 
 def _prec(operand):
@@ -175,6 +185,185 @@ def dsa_index_reference(q, w, pool, block_tables, table_of, context_lens,
         + jnp.arange(rows)[None, :, None]
     ok = (key <= row) & (key < jnp.asarray(context_lens)[:, None, None])
     return jnp.where(ok, s, _NEG_INF)
+
+
+# ---------------------------------------------------------------------------
+# the selection: the k-th largest score and a compaction, no sort
+# ---------------------------------------------------------------------------
+
+def _select_kernel(tile_ctx_ref, s_ref, ctx_ref, o_ref, keys, *, k, tiles):
+    pl = _pl()
+    rows = s_ref.shape[0]
+    chunk = tiles * _LANES
+    int_min = jnp.int32(-2 ** 31)
+    tile_ctx = tile_ctx_ref[pl.program_id(0)]
+    n_chunks = (tile_ctx + chunk - 1) // chunk
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, _LANES), 1)
+    out_tiles = [slice(q * _LANES, (q + 1) * _LANES)
+                 for q in range(o_ref.shape[1] // _LANES)]
+
+    # a row whose context is at most k keeps 0 .. context-1, and the
+    # slots after them hold positions in range all the same
+    for q, at in enumerate(out_tiles):
+        o_ref[:, at] = lane + q * _LANES
+
+    def lanes_of(j):
+        return pl.ds(pl.multiple_of(j * chunk, chunk), chunk)
+
+    def tiles_of(kc):
+        return [kc[:, i * _LANES:(i + 1) * _LANES] for i in range(tiles)]
+
+    def count(beats, bound):
+        """How many keys of each row ``beats(key, bound)``: int32
+        (rows, 128), the same in every lane."""
+        def body(j, acc):
+            hits = [beats(t, bound).astype(jnp.int32)
+                    for t in tiles_of(keys[:, lanes_of(j)])]
+            while len(hits) > 1:        # pairwise: no chain of adds
+                hits = [a + b for a, b in zip(hits[::2], hits[1::2])] \
+                    + hits[len(hits) & ~1:]
+            return acc + hits[0]
+        acc = jax.lax.fori_loop(0, n_chunks, body,
+                                jnp.zeros((rows, _LANES), jnp.int32))
+        return jnp.broadcast_to(acc.sum(axis=1, keepdims=True),
+                                (rows, _LANES))
+
+    @pl.when(tile_ctx > k)
+    def _select():
+        ctx = ctx_ref[...]                                   # (rows, 1)
+
+        # int32 keys that order as the float32 scores do (-0.0 under
+        # 0.0, as lax.top_k has them); past the context: the least key
+        def to_keys(j, _):
+            bits = jax.lax.bitcast_convert_type(s_ref[:, lanes_of(j)],
+                                                jnp.int32)
+            pos = j * chunk + jax.lax.broadcasted_iota(
+                jnp.int32, (rows, chunk), 1)
+            keys[:, lanes_of(j)] = jnp.where(
+                pos < ctx, bits ^ ((bits >> 31) & jnp.int32(0x7fffffff)),
+                int_min)
+        jax.lax.fori_loop(0, n_chunks, to_keys, None)
+
+        # the k-th largest key, a bit a pass from the top: the largest
+        # ``thr`` that at least k keys reach (the least key where the
+        # context holds fewer than k)
+        def one_bit(b, thr):
+            cand = jnp.where(b == 0, jnp.zeros_like(thr),
+                             thr | jnp.left_shift(jnp.int32(1), 31 - b))
+            return jnp.where(count(jnp.greater_equal, cand) >= k, cand, thr)
+        thr = jax.lax.fori_loop(
+            0, 32, one_bit, jnp.full((rows, _LANES), int_min, jnp.int32))
+        # keys EQUAL to it fill what the keys above it leave of k, from
+        # the lowest position up
+        ties = k - count(jnp.greater, thr)
+
+        upper = jax.lax.broadcasted_iota(jnp.int32, (_LANES, 2 * _LANES), 0) \
+            <= jax.lax.broadcasted_iota(jnp.int32, (_LANES, 2 * _LANES), 1)
+        upper = upper.astype(jnp.bfloat16)
+
+        def compact(j, base):
+            above, equal = base
+            kc = tiles_of(keys[:, lanes_of(j)])
+            # a running count inside each tile of 128 lanes, and the
+            # tile's total in lanes 128.., in one exact product
+            run = jax.lax.dot_general(
+                jnp.concatenate(
+                    [beats(t, thr).astype(jnp.float32)
+                     for beats in (jnp.greater, jnp.equal) for t in kc],
+                    0).astype(jnp.bfloat16),
+                upper, (((1,), (0,)), ((), ())),
+                precision=jax.lax.Precision.DEFAULT,
+                preferred_element_type=jnp.float32).astype(jnp.int32)
+            placed = []
+            for i in range(tiles):
+                up = run[i * rows:(i + 1) * rows]
+                eq = run[(tiles + i) * rows:(tiles + i + 1) * rows]
+                taken = jnp.minimum(equal, ties)
+                # kept keys up to and with each lane; their first slot
+                kept = up[:, :_LANES] - taken \
+                    + jnp.minimum(equal + eq[:, :_LANES], ties)
+                n_kept = up[:, _LANES:] - taken \
+                    + jnp.minimum(equal + eq[:, _LANES:], ties)
+                first = above + taken
+                turn = first & (_LANES - 1)
+                # output lane l takes the tile's kept key number
+                # ``nth``: the first lane whose running count reaches it
+                nth = (lane - turn) & (_LANES - 1)
+                lo = jnp.zeros_like(lane)
+                step = _LANES // 2
+                while step:
+                    seen = jnp.take_along_axis(
+                        kept, lo + (step - 1), axis=1,
+                        mode="promise_in_bounds")
+                    lo = jnp.where(seen <= nth, lo + step, lo)
+                    step //= 2
+                # (position, tile of the output it lands in or -1)
+                placed.append((
+                    j * chunk + i * _LANES + lo,
+                    jnp.where(nth < n_kept, (first >> _LANE_BITS)
+                              + (lane < turn).astype(jnp.int32), -1)))
+                above = above + up[:, _LANES:]
+                equal = equal + eq[:, _LANES:]
+            for q, at in enumerate(out_tiles):
+                cur = o_ref[:, at]
+                for pos, tile in placed:
+                    cur = jnp.where(tile == q, pos, cur)
+                o_ref[:, at] = cur
+            return above, equal
+
+        zero = jnp.zeros((rows, _LANES), jnp.int32)
+        jax.lax.fori_loop(0, n_chunks, compact, (zero, zero))
+
+
+def dsa_select(scores, context_lens, k):
+    """The positions of each row's ``k`` largest scores among its first
+    ``context_lens`` keys: int32 [T, k], the SET ``lax.top_k`` gives
+    (equal scores go to the lower position), in ascending position.
+
+    - ``scores``: float32 [T, W]; what lies at or past a row's context
+      is never compared;
+    - ``context_lens``: int32 [T].  A row with at most ``k`` keys in
+      context gets ``0 .. context-1``; the slots after them hold
+      positions in ``[0, W)`` that mean nothing.
+
+    No sort: the k-th largest score a row is found a bit a pass
+    (float32 as int32 keys of the same order), then what lies above it,
+    and of what EQUALS it the lowest positions, is compacted into the
+    row's k slots.  Both stop at the largest context of a tile of rows.
+    """
+    return _dsa_select(scores.astype(jnp.float32),
+                       jnp.asarray(context_lens, jnp.int32), k=int(k),
+                       interpret=_flash._use_interpret())
+
+
+@functools.partial(jax.jit, static_argnames=("k", "interpret"))
+def _dsa_select(scores, ctx, *, k, interpret):
+    pl = _pl()
+    from jax.experimental.pallas import tpu as pltpu
+    t, w = scores.shape
+    if not 0 < k <= w:
+        raise ValueError("k must lie in 1 .. %d (the width), got %d" % (w, k))
+    tiles = min(SELECT_CHUNK_TILES, -(-w // _LANES))
+    chunk = tiles * _LANES
+    t_pad, w_pad = -(-t // SELECT_ROWS) * SELECT_ROWS, -(-w // chunk) * chunk
+    k_pad = -(-k // _LANES) * _LANES
+    scores = jnp.pad(scores, ((0, t_pad - t), (0, w_pad - w)))
+    ctx = jnp.pad(jnp.minimum(ctx, w), (0, t_pad - t))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(t_pad // SELECT_ROWS,),
+        in_specs=[pl.BlockSpec((SELECT_ROWS, w_pad), lambda g, *_: (g, 0)),
+                  pl.BlockSpec((SELECT_ROWS, 1), lambda g, *_: (g, 0))],
+        out_specs=pl.BlockSpec((SELECT_ROWS, k_pad), lambda g, *_: (g, 0)),
+        scratch_shapes=[pltpu.VMEM((SELECT_ROWS, w_pad), jnp.int32)])
+    out = _pallas_call(
+        functools.partial(_select_kernel, k=k, tiles=tiles),
+        [ctx.reshape(-1, SELECT_ROWS).max(axis=1), scores, ctx[:, None]],
+        interpret=interpret,
+        name="dsa_select",
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((t_pad, k_pad), jnp.int32))
+    return out[:t, :k]
 
 
 # ---------------------------------------------------------------------------

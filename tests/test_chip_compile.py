@@ -370,14 +370,15 @@ def test_hybrid_engine_program_updates_every_cache_in_place(
 
 @pytest.mark.parametrize("kernel,groups,rows", [
     ("dsa_index", 16, 1), ("dsa_index", 64, 32),
-    ("mla_sparse", 16, 1), ("mla_sparse", 64, 1)])
+    ("mla_sparse", 16, 1), ("mla_sparse", 64, 1),
+    ("dsa_select", 16, 1), ("dsa_select", 2048, 1)])
 def test_sparse_latent_attention_kernels_compile(chip, kernel, groups,
                                                  rows):
-    """The indexer's scoring over paged keys and the latent attention
-    over a list of rows, at DeepSeek-V3.2's published widths and the
-    benchmark cell's sizes (64-token pages, 512 pages a sequence, 2,048
-    selected rows): one query row a slot (decode) and a block of a
-    prefill chunk's rows."""
+    """The indexer's scoring over paged keys, the selection and the
+    latent attention over a list of rows, at DeepSeek-V3.2's published
+    widths and the benchmark cell's sizes (64-token pages, 512 pages a
+    sequence, 2,048 selected rows): one query row a slot (decode) and a
+    block of a prefill chunk's rows (the selection: all 2,048)."""
     sla = importlib.import_module(
         "mxnet_tpu.ops.pallas.sparse_latent_attention")
     i32 = jnp.int32
@@ -388,6 +389,10 @@ def test_sparse_latent_attention_kernels_compile(chip, kernel, groups,
             chip((8192, 64, 128), jnp.bfloat16), chip((16, 512), i32),
             chip((groups,), i32), chip((groups,), i32),
             chip((groups,), i32))
+    elif kernel == "dsa_select":
+        compiled = compile_for_chip(
+            lambda s, c: sla.dsa_select(s, c, 2048),
+            chip((groups, 32768), jnp.float32), chip((groups,), i32))
     else:
         compiled = compile_for_chip(
             lambda q, r, n: sla.mla_sparse(q, r, n, 512, 0.1),

@@ -475,17 +475,80 @@ def test_sparse_decode_is_the_selected_rows_softmax(net):
     assert np.abs(np.asarray(got[0]) - want).max() < 1e-5
 
 
-@pytest.mark.parametrize("ctx", [3000, 4096, 4097, 9000])
-def test_selection_over_a_bucket_of_the_context(ctx, monkeypatch):
-    """A wide score matrix is sorted over the narrowest bucket of keys
-    that holds the context: the same rows as over all of it."""
-    monkeypatch.setattr(ds, "SELECT_BUCKET", 4096)
-    rng = np.random.default_rng(ctx)
-    scores = np.full((4, 12288), -1e30, np.float32)
-    scores[:, :ctx] = rng.normal(size=(4, ctx))
-    got = ds._select(jnp.asarray(scores), 16, jnp.int32(ctx))
-    want = jax.lax.top_k(jnp.asarray(scores), 16)[1]
-    assert (np.asarray(got) == np.asarray(want)).all()
+def _select_case(name):
+    """``(scores float32 [T, W], contexts [T], k)`` of one case of
+    :func:`test_dsa_select_keeps_the_set_top_k_keeps`."""
+    rng = np.random.default_rng(len(name))
+    k, rows, w = 16, 8, 1024
+    scores = rng.normal(size=(rows, w)).astype(np.float32)
+    if name.startswith("context_"):
+        ctx = [{"0": 0, "1": 1, "k-1": k - 1, "k": k, "k+1": k + 1}
+               [name[len("context_"):]]] * rows
+    elif name == "ragged_chunk":
+        # a chunk of 40 rows at offset 900: row r sees 900 + r + 1 keys
+        rows, w = 40, 2048
+        scores = rng.normal(size=(rows, w)).astype(np.float32)
+        ctx = 900 + 1 + np.arange(rows)
+    elif name == "contexts_of_a_decode_step":
+        ctx = [0, 1000, 3, 17, 1024, 16, 500, 129]
+    elif name == "width_of_no_whole_tile":
+        # 9 tiles of 128 lanes and 8 lanes more, 11 rows
+        rows, w = 11, 1160
+        scores = rng.normal(size=(rows, w)).astype(np.float32)
+        ctx = rng.integers(k, w + 1, size=rows)
+    elif name == "exact_zeros":
+        # a score is 0 where no head's ReLU fires: the lower position
+        scores = np.maximum(scores - 2.0, 0.0)
+        scores[0] = 0.0
+        scores[1, 5:] = -0.0
+        ctx = [1024, 1024, 700, 300, 1024, 18, 1024, 999]
+    elif name == "one_repeated_value":
+        scores[:] = np.float32(0.37)
+        scores[1] = -2.5
+        scores[2, ::3] = 1.0
+        ctx = [1024, 900, 1024, 17, 16, 15, 1024, 129]
+    elif name == "negative_and_masked":
+        scores = -np.abs(scores) - 1.0
+        scores[:, ::2] = -1e30
+        scores[3] = -1e30
+        scores[4, 40:] = -np.inf
+        ctx = [1024, 1000, 31, 1024, 1024, 200, 64, 1024]
+    elif name == "selection_is_most_of_the_context":
+        k = 300
+        ctx = [301, 300, 1024, 600, 299, 310, 1000, 512]
+    elif name == "cell_decode_rows":
+        # the cell's decode run: 16 rows over 32,768 keys, 2,048 kept
+        k, rows, w = 2048, 16, 32768
+        scores = np.maximum(
+            rng.normal(size=(rows, w)).astype(np.float32) - 1.5, 0.0)
+        ctx = np.linspace(6900, 31100, rows).astype(np.int64)
+        ctx[5] = 0
+    else:
+        raise ValueError(name)
+    return scores, np.asarray(ctx, np.int64), k
+
+
+@pytest.mark.parametrize("name", [
+    "context_0", "context_1", "context_k-1", "context_k", "context_k+1",
+    "ragged_chunk", "contexts_of_a_decode_step", "width_of_no_whole_tile",
+    "exact_zeros", "one_repeated_value", "negative_and_masked",
+    "selection_is_most_of_the_context", "cell_decode_rows"])
+def test_dsa_select_keeps_the_set_top_k_keeps(name):
+    """The selection without a sort against ``lax.top_k`` AS SETS over
+    the first ``min(context, k)`` entries (equal scores go to the lower
+    position); every index in range, none twice."""
+    scores, ctx, k = _select_case(name)
+    w = scores.shape[1]
+    got = np.asarray(sla.dsa_select(jnp.asarray(scores),
+                                    jnp.asarray(ctx, jnp.int32), k))
+    assert got.shape == (len(ctx), k) and got.dtype == np.int32
+    assert (got >= 0).all() and (got < w).all()
+    masked = np.where(np.arange(w)[None, :] < ctx[:, None], scores, -np.inf)
+    want = np.asarray(jax.lax.top_k(jnp.asarray(masked), k)[1])
+    for r, c in enumerate(ctx):
+        n = min(int(c), k)
+        assert len(set(got[r, :n])) == n, (name, r)
+        assert set(got[r, :n]) == set(want[r, :n]), (name, r)
 
 
 # -- the share --------------------------------------------------------------
