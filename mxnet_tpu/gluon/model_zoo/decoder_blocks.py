@@ -1,5 +1,7 @@
 """What the served decoder models of this zoo share: the small layer
-functions over a parameter tree (``ling3.py``, ``deepseek_v32.py``).
+functions over a parameter tree (``ling3.py``, ``deepseek_v32.py``,
+``exaone_moe.py``) and the page-at-a-time write of a slot's rows
+(``gpt.py`` too).
 
 Every function takes arrays, not a net: bfloat16 weights as stored,
 float32 residual stream, float32 accumulation.  A rotary embedding takes
@@ -85,11 +87,11 @@ def mm(x, w):
     return jnp.dot(x.astype(w.dtype), w, preferred_element_type=jnp.float32)
 
 
-def rms(x, g):
+def rms(x, g, eps=EPS):
     import jax
     import jax.numpy as jnp
     x = x.astype(jnp.float32)
-    return x * jax.lax.rsqrt((x * x).mean(-1, keepdims=True) + EPS) \
+    return x * jax.lax.rsqrt((x * x).mean(-1, keepdims=True) + eps) \
         * g.astype(jnp.float32)
 
 
@@ -156,10 +158,14 @@ def swiglu(x, gu_w, down_w):
     return mm(jax.nn.silu(gu[..., :half]) * gu[..., half:], down_w)
 
 
-def moe(lp, x, cfg):
-    """Routed experts (held share) + shared expert.  Returns
-    ``(y, experts [T, k], stats)``."""
+def moe(lp, x, cfg, valid=None):
+    """Routed experts (held share) + shared expert.  ``valid`` bool [T]
+    marks the rows that are tokens: a pad row (a chunk's tail, an empty
+    slot) is routed like any row and given to NO held expert, so what it
+    would have chosen costs no expert a row and counts in no statistic
+    but ``assignments``.  Returns ``(y, experts [T, k], stats)``."""
     import jax
+    import jax.numpy as jnp
     from ...parallel import moe as _moe
     with jax.named_scope("moe"):
         experts, weights = _moe.grouped_topk_route(
@@ -167,8 +173,9 @@ def moe(lp, x, cfg):
             cfg["topk_group"], cfg["num_experts_per_tok"],
             cfg["routed_scaling_factor"])
         y, stats = _moe.held_experts_ffn(
-            x, experts, weights, lp["gu_w"], lp["down_w"],
-            cfg["experts_held"][0])
+            x, experts if valid is None
+            else jnp.where(valid[:, None], experts, -1), weights,
+            lp["gu_w"], lp["down_w"], cfg["experts_held"][0])
         return y + swiglu(x, lp["sh_gu_w"], lp["sh_down_w"]), experts, \
             stats
 
@@ -191,3 +198,59 @@ def latent_rows(c, k_rope, width, dtype):
     import jax.numpy as jnp
     rows = jnp.concatenate([c, k_rope], -1)
     return jnp.pad(rows, ((0, 0), (0, width - rows.shape[1]))).astype(dtype)
+
+
+def rows_per_block(t, want):
+    """The largest divisor of ``t`` that is at most ``want``: the rows
+    a block of a chunk's attention takes at a time."""
+    r = min(want, t)
+    while t % r:
+        r -= 1
+    return r
+
+
+def page_scatter(pool, block_table_row, rows, start, n_rows):
+    """Write ONE slot's consecutive rows into a full-precision page
+    pool with one update a PAGE, not one a row.
+
+    ``pool``: [num_pages, page_size, K_kv * D]; ``rows``: [T, K_kv, D],
+    the slot's positions ``start .. start + T`` in order, of which the
+    first ``n_rows`` are real (both traced scalars); ``block_table_row``:
+    int32 [max_pages_per_seq].  The rows are laid out as the slot's
+    consecutive pages (shifted down by ``start % page_size`` into a
+    zeroed buffer of ``ceil((T + page_size - 1) / page_size)`` pages,
+    block ``j`` being the page that holds position ``(start //
+    page_size + j) * page_size``) and the pool takes one scatter of
+    whole pages: a page is a whole number of the chip's tiles, a row a
+    sublane of one, and the scatter costs by the update.
+
+    - a block with no real row (all past ``start + n_rows``, which
+      includes every block past the block table's end) goes to scratch
+      page 0, as zeros;
+    - the head page keeps its rows below ``start % page_size`` (the
+      prefix rows of a copy-on-write page): they are read back from the
+      pool and written again as they are;
+    - the tail page's rows past ``start + n_rows`` are written as
+      ZEROS: the page is this slot's alone, no kernel reads a row at or
+      past a slot's context, and the decode steps write them one by one.
+
+    Every real row lands bit for bit where ``pool.at[phys, offs].set``
+    put it.  Returns the new pool."""
+    import jax.numpy as jnp
+    from jax import lax
+    t, page_size = rows.shape[0], pool.shape[1]
+    shift = start % page_size
+    n_blocks = -(-(t + page_size - 1) // page_size)
+    block = jnp.arange(n_blocks)
+    pages = jnp.where(
+        block * page_size < shift + n_rows,
+        jnp.take(block_table_row, start // page_size + block, mode="clip"),
+        0)
+    x = jnp.where((jnp.arange(t) < n_rows)[:, None], rows.reshape(t, -1),
+                  0).astype(pool.dtype)
+    blocks = lax.dynamic_update_slice_in_dim(
+        jnp.zeros((n_blocks * page_size, x.shape[1]), pool.dtype),
+        x, shift, 0).reshape(n_blocks, page_size, -1)
+    head = jnp.where((jnp.arange(page_size) < shift)[:, None],
+                     pool[pages[0]], blocks[0])
+    return pool.at[pages].set(blocks.at[0].set(head))

@@ -55,7 +55,8 @@ from ..block import Block
 from . import decoder_blocks as _blocks
 from .decoder_blocks import (latent_width, mm as _mm, rms as _rms,
                              head as _head, swiglu as _swiglu, moe as _moe,
-                             latent_rows as _latent_rows)
+                             latent_rows as _latent_rows,
+                             rows_per_block as _rows_per_block)
 
 __all__ = ["DeepseekV32LM", "deepseek_v32", "deepseek_v32_tiny",
            "decode_params", "param_tree", "forward", "paged_decode_step",
@@ -532,11 +533,3 @@ def paged_prefill(p, tokens, prompt_len, prefix_len, block_table_row,
         logits = _head(last, p["head"])
     aux = _aux(cfg, t_pad, routing, stats, selected, n_valid, in_context)
     return _first_token(logits, sampling, new_caches) + (aux,)
-
-
-def _rows_per_block(t, want):
-    """The largest divisor of ``t`` that is at most ``want``."""
-    r = min(want, t)
-    while t % r:
-        r -= 1
-    return r

@@ -27,6 +27,9 @@ import functools
 
 from .. import nn
 from ..block import HybridBlock
+# the page-at-a-time write of one slot's rows, shared with the other
+# served models
+from .decoder_blocks import page_scatter as _page_scatter
 
 __all__ = ["GPTBlock", "GPTLM", "get_gpt", "gpt2_tiny",
            "gpt2_tiny_moe", "gpt2_small", "gpt2_medium",
@@ -760,53 +763,6 @@ def _quant_scatter(pool, scales, phys, offs, rows, mask):
         -_KV_QMAX, _KV_QMAX)
     return p1.at[tgt, offs].set(
         q.reshape(r_n, n_kv * d).astype(pool.dtype)), s1
-
-
-def _page_scatter(pool, block_table_row, rows, start, n_rows):
-    """Write ONE slot's consecutive rows into a full-precision page
-    pool with one update a PAGE, not one a row.
-
-    ``pool``: [num_pages, page_size, K_kv * D]; ``rows``: [T, K_kv, D],
-    the slot's positions ``start .. start + T`` in order, of which the
-    first ``n_rows`` are real (both traced scalars); ``block_table_row``:
-    int32 [max_pages_per_seq].  The rows are laid out as the slot's
-    consecutive pages (shifted down by ``start % page_size`` into a
-    zeroed buffer of ``ceil((T + page_size - 1) / page_size)`` pages,
-    block ``j`` being the page that holds position ``(start //
-    page_size + j) * page_size``) and the pool takes one scatter of
-    whole pages: a page is a whole number of the chip's tiles, a row a
-    sublane of one, and the scatter costs by the update.
-
-    - a block with no real row (all past ``start + n_rows``, which
-      includes every block past the block table's end) goes to scratch
-      page 0, as zeros;
-    - the head page keeps its rows below ``start % page_size`` (the
-      prefix rows of a copy-on-write page): they are read back from the
-      pool and written again as they are;
-    - the tail page's rows past ``start + n_rows`` are written as
-      ZEROS: the page is this slot's alone, no kernel reads a row at or
-      past a slot's context, and the decode steps write them one by one.
-
-    Every real row lands bit for bit where ``pool.at[phys, offs].set``
-    put it.  Returns the new pool."""
-    import jax.numpy as jnp
-    from jax import lax
-    t, page_size = rows.shape[0], pool.shape[1]
-    shift = start % page_size
-    n_blocks = -(-(t + page_size - 1) // page_size)
-    block = jnp.arange(n_blocks)
-    pages = jnp.where(
-        block * page_size < shift + n_rows,
-        jnp.take(block_table_row, start // page_size + block, mode="clip"),
-        0)
-    x = jnp.where((jnp.arange(t) < n_rows)[:, None], rows.reshape(t, -1),
-                  0).astype(pool.dtype)
-    blocks = lax.dynamic_update_slice_in_dim(
-        jnp.zeros((n_blocks * page_size, x.shape[1]), pool.dtype),
-        x, shift, 0).reshape(n_blocks, page_size, -1)
-    head = jnp.where((jnp.arange(page_size) < shift)[:, None],
-                     pool[pages[0]], blocks[0])
-    return pool.at[pages].set(blocks.at[0].set(head))
 
 
 def _filter_logits_per_slot(logits, top_k, top_p):

@@ -207,6 +207,39 @@ def _zeros_program(shape, dtype):
     return jax.jit(lambda: jnp.zeros(shape, dtype))
 
 
+#: what a cache kind other than paged K/V is called, and why it cannot
+#: share a prefix, roll a rejected draft back or hold int8 pages
+_KIND_REFUSALS = {
+    "latent": {"name": "latent pages",
+               "prefix": "a latent row is written by one prefill from "
+                         "position 0",
+               "spec": "a latent pool has no verify program",
+               "int8": "a latent row has no per-page scale"},
+    "state": {"name": "per-slot recurrent state",
+              "prefix": "a recurrent layer's prefix is a state, not a "
+                        "list of pages",
+              "spec": "a recurrent state cannot be rolled back",
+              "int8": "a recurrent state has no per-page scale"},
+    "ring": {"name": "per-slot window rings",
+             "prefix": "a cached prefix has no ring",
+             "spec": "a rejected draft has overwritten ring rows",
+             "int8": "a ring row has no per-page scale"}}
+
+
+def _refusal(kinds, what):
+    """``(names, reasons)`` of the declared kinds other than paged K/V:
+    what the model keeps, and why each kind refuses ``what`` ("prefix"
+    reuse, "spec"ulative decoding or "int8" pages)."""
+    found = []
+    for kind in kinds or ():
+        name = "latent" if isinstance(kind, LatentPages) \
+            else kind.role if isinstance(kind, SlotState) else None
+        if name and name not in found:
+            found.append(name)
+    return (" and ".join(_KIND_REFUSALS[n]["name"] for n in found),
+            "; ".join(_KIND_REFUSALS[n][what] for n in found))
+
+
 def ngram_draft(context, k, max_n=3):
     """Model-free n-gram drafter (prompt-lookup decoding): propose the
     continuation of the LAST earlier occurrence of the context's
@@ -282,8 +315,10 @@ class ServingEngine:
         paged_kv = self._model.cache_kinds is None
         if not paged_kv and kv_heads is not None:
             raise ValueError(
-                "kv_heads regroups paged K/V heads; this model keeps "
-                "latent or per-slot state caches")
+                "kv_heads regroups the K/V heads of a model of paged K/V "
+                "alone; this model declares its own caches (%s)"
+                % (_refusal(self._model.cache_kinds, "name")[0]
+                   or "K/V pages at its own head count"))
         # grouped-query serving (ISSUE 15): K_kv <= H KV heads shrink
         # the page pools H/K_kv-fold -> proportionally more resident
         # sequences for the same pool bytes.  Explicit arg wins; env
@@ -313,9 +348,9 @@ class ServingEngine:
         self.kv_dtype = normalize_kv_dtype(kv_dtype)
         if not paged_kv and self.kv_dtype == "int8":
             raise ValueError(
-                "int8 pages are defined for paged K/V pools only: a "
-                "latent row or a recurrent state has no per-page scale "
-                "here (use bf16 or fp32)")
+                "int8 pages are defined for a model of paged K/V pools "
+                "alone; this model keeps %s: %s (use bf16 or fp32)"
+                % _refusal(self._model.cache_kinds, "int8"))
         self._p = self._model.decode_params(net, kv_heads=self.kv_heads)
         self._n_layers = len(self._p["layers"])
         self._units = int(self._p["wte"].shape[1])
@@ -347,8 +382,9 @@ class ServingEngine:
         if self.spec_k and not paged_kv:
             raise ValueError(
                 "speculative decoding rolls rejected drafts back by "
-                "masking pages; a per-slot recurrent state cannot be "
-                "rolled back, so spec_k must be 0 for this model")
+                "masking pages; this model keeps %s: %s, so spec_k must "
+                "be 0 for this model"
+                % _refusal(self._model.cache_kinds, "spec"))
         if self.spec_k and \
                 self.max_seq_len + self.spec_k > max_len:
             raise ValueError(
@@ -423,8 +459,8 @@ class ServingEngine:
             if prefix_cache:
                 raise ValueError(
                     "the prefix cache shares K/V pages; this model "
-                    "keeps latent or per-slot state caches, whose "
-                    "prefix is not a list of pages")
+                    "keeps %s: %s"
+                    % _refusal(self._model.cache_kinds, "prefix"))
             prefix_cache = False
         if prefix_cache is None:
             prefix_cache = os.environ.get(
@@ -519,6 +555,13 @@ class ServingEngine:
             8 * self.alloc.kv_itemsize)
         _telemetry.gauge("serving.kv.bytes_per_token").set(
             self.kv_bytes_per_token)
+        if not paged_kv:
+            # a model of mixed kinds: what a token costs in pages, and
+            # what the slots' window rings hold whatever their lengths
+            _telemetry.gauge("serving.cache.page_bytes_per_token").set(
+                self.kv_bytes_per_token)
+            _telemetry.gauge("serving.cache.ring_bytes").set(
+                self.num_slots * self._slot_bytes("ring"))
         #: pages the paged kernel reads a block, for the block_fill
         #: gauge (None: the model's decode does not run that kernel)
         self._pages_per_block = None
@@ -627,13 +670,19 @@ class ServingEngine:
 
     @property
     def state_bytes_per_slot(self):
-        """All-layer bytes of per-slot recurrent state one resident
-        sequence holds, whatever its length."""
+        """All-layer bytes of per-slot arrays (recurrent state, window
+        rings) one resident sequence holds, whatever its length."""
+        return self._slot_bytes()
+
+    def _slot_bytes(self, role=None):
+        """Bytes a slot of the :class:`SlotState` layers' arrays, all
+        of them or those of one ``role``."""
         item = 4 if self.kv_dtype == "fp32" else 2
         return sum(
             int(_np.prod(shape)) * (_np.dtype(dtype).itemsize
                                     if dtype else item)
             for kind in self._kinds if isinstance(kind, SlotState)
+            and role in (None, kind.role)
             for _, shape, dtype in kind.arrays)
 
     # -- program construction ---------------------------------------------

@@ -21,13 +21,26 @@ Cache kinds:
   rows a narrower row a token that a query scores to CHOOSE which latent
   rows it reads (learned sparse attention);
 - :class:`SlotState` — arrays with one row a SLOT (``[num_slots + 1,
-  *shape]``, the last row scratch): recurrent state that a prefill
-  overwrites at admission and a decode step updates in place; freed
-  with the slot, never paged.
+  *shape]``, the last row scratch), freed with the slot, never paged.
+  Its ``role`` says what the rows are: "state", a recurrent state that
+  a prefill overwrites at admission and a decode step updates in place;
+  or "ring", the newest ``window`` K and V rows of a sliding-window
+  attention layer, position ``p`` at row ``p % window``, which a decode
+  step overwrites a row at a time and a prefill reads before it leaves
+  its own last rows.
+
+A model may mix kinds layer by layer: the pools are made for the paged
+layers only, a slot's bytes count its :class:`SlotState` arrays
+(``state_bytes_per_slot``) and a token's its pages
+(``kv_bytes_per_token``).
 
 Prefix reuse, speculative decoding, int8 pages and a reduced KV-head
-count are defined on :class:`KVPages` only; the engine refuses them
-for a model with any other kind.
+count are defined for a model of :class:`KVPages` alone; the engine
+refuses them for a model with any other kind, in a sentence that names
+the kind.  A CHUNKED prefill may meet :class:`KVPages`,
+:class:`LatentPages` and a :class:`SlotState` of role "ring" (the chunk
+before left what this one reads: pages, or the ring's rows); a recurrent
+"state" has no chunked form here (``ling3.py`` prefills a prompt whole).
 """
 from __future__ import annotations
 
@@ -36,8 +49,9 @@ import collections
 KVPages = collections.namedtuple("KVPages", "heads head_dim")
 LatentPages = collections.namedtuple("LatentPages", "widths")
 #: ``arrays``: ``((name, per-slot shape, dtype or None for the pools'
-#: dtype), ...)``
-SlotState = collections.namedtuple("SlotState", "arrays")
+#: dtype), ...)``; ``role``: "state" or "ring"
+SlotState = collections.namedtuple("SlotState", "arrays role",
+                                   defaults=("state",))
 
 
 class ServingPrograms:
@@ -50,7 +64,8 @@ class ServingPrograms:
     - ``decode_step`` / ``prefill`` (/ ``spec_decode_step``): the
       contract of ``gpt.paged_decode_step`` / ``gpt.paged_prefill`` /
       ``gpt.paged_spec_decode_step``.  A model with :class:`SlotState`
-      layers takes the slot as ``prefill(..., slot=)``;
+      layers takes the slot as ``prefill(..., slot=)``, a chunk run
+      too;
     - ``cache_kinds``: one kind a layer, or None for :class:`KVPages`
       everywhere at the engine's ``kv_heads``;
     - ``decode_stats``: names of the float32 counts a decode step
@@ -59,7 +74,8 @@ class ServingPrograms:
     - ``chunked_prefill``: the prefill program admits a prompt a CHUNK
       at a time: its ``prefix_len`` argument is the position of the
       chunk's first row, ``prompt_len`` the prompt's length so far, and
-      it reads what the chunks before wrote from the slot's own pages.
+      it reads what the chunks before wrote from the slot's own pages
+      (and rings).
       The engine then admits prompts longer than ``max_prefill_len``
       and runs the program as often as a prompt needs;
     - ``config_key``: whatever the programs bake in that the input

@@ -368,6 +368,80 @@ def test_hybrid_engine_program_updates_every_cache_in_place(
         int(np.prod(a.shape)) * a.dtype.itemsize for a in caches), mem
 
 
+_RING_PROGRAMS = {}
+
+
+def _ring_engine_programs(monkeypatch):
+    """``{name: (fn, example shapes)}`` of the engine's programs over
+    K-EXAONE's share at its PUBLISHED widths and the benchmark cell's
+    own engine sizes (``perfbench/workloads/kexaone-serve-mixedlen.json``:
+    64 slots, 64-token pages, chunks of 2,048 rows), every kept layer.
+    Neither weights nor caches are made (``_hybrid_engine_programs``)."""
+    if not _RING_PROGRAMS:
+        from mxnet_tpu.gluon.model_zoo import exaone_moe
+        from mxnet_tpu.serving import ServingEngine
+        with open(os.path.join(REPO, "perfbench", "workloads",
+                               "kexaone-serve-mixedlen.json")) as f:
+            sizes = json.load(f)["engine"]
+        net = exaone_moe.k_exaone()
+        programs = net.serving_programs()
+        programs.decode_params = lambda net, kv_heads=None: \
+            exaone_moe.param_tree(net.cfg, lambda path, shape:
+                                  jax.ShapeDtypeStruct(shape, jnp.bfloat16))
+        monkeypatch.setattr(net, "serving_programs", lambda: programs,
+                            raising=False)
+        monkeypatch.setattr(
+            ServingEngine, "_compile",
+            lambda self, name, fn, examples, extra:
+            _RING_PROGRAMS.__setitem__(name, (fn, examples)))
+        make = ServingEngine._init_cache
+        monkeypatch.setattr(
+            ServingEngine, "_init_cache",
+            lambda self, kind: jax.eval_shape(lambda: make(self, kind)))
+        _RING_PROGRAMS["engine"] = ServingEngine(net, **sizes)
+    return _RING_PROGRAMS
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_ring_engine_programs_compile_at_the_published_widths(
+        chip, monkeypatch, program):
+    """Both programs of the model with window rings beside pages compile
+    for the described v5e at the cell's sizes: the paged kernel at 64
+    query heads over 8 K/V heads of 128 and the grouped matmul by name,
+    every cache aliased (pools and rings are updated where they lie),
+    and arguments, temporaries and fresh outputs inside the chip's 16
+    GiB.  The engine's sizing units are the configuration file's."""
+    got = _ring_engine_programs(monkeypatch)
+    eng = got["engine"]
+    with open(os.path.join(REPO, "perfbench", "configs",
+                           "k-exaone-236b-a23b.json")) as f:
+        cache = json.load(f)["cache"]
+    assert eng.kv_bytes_per_token == cache["page_bytes_per_token"] == 4096
+    assert eng.state_bytes_per_slot == cache["ring_bytes_per_slot"] \
+        == 4 * 2 * 128 * 2048
+    assert [tuple(a.shape) for a in eng._kv[4]] \
+        == [(eng.alloc.num_pages, 64, 1024)] * 2
+    assert [tuple(a.shape) for a in eng._kv[0]] == [(65, 128, 1024)] * 2
+    fn, examples = got[program]
+    caches = jax.tree_util.tree_leaves(examples[1])
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+            *jax.tree_util.tree_map(lambda a: chip(a.shape, a.dtype),
+                                    examples)).compile()
+    text = compiled.as_text()
+    assert "moe_gmm" in text
+    if program == "decode":
+        assert "paged_decode" in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(
+        int(np.prod(a.shape)) * a.dtype.itemsize for a in caches), mem
+    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        + max(0, mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    print(program, "arguments %.2f GB, temporaries %.2f GB"
+          % (mem.argument_size_in_bytes / 1e9, mem.temp_size_in_bytes / 1e9))
+    assert held < 15.5 * 2 ** 30, mem
+
+
 @pytest.mark.parametrize("kernel,groups,rows", [
     ("dsa_index", 16, 1), ("dsa_index", 64, 32),
     ("mla_sparse", 16, 1), ("mla_sparse", 64, 1),
