@@ -20,7 +20,7 @@ LATENT_ALIGN = 128
 #: summed over the expert layers of one step
 MOE_STATS = ("experts_hit", "local_assignments",
              "max_tokens_per_expert", "assignments", "held_experts",
-             "expert_layers")
+             "expert_layers", "weight_tiles")
 
 
 def latent_width(cfg):
@@ -175,7 +175,8 @@ def moe(lp, x, cfg, valid=None):
         y, stats = _moe.held_experts_ffn(
             x, experts if valid is None
             else jnp.where(valid[:, None], experts, -1), weights,
-            lp["gu_w"], lp["down_w"], cfg["experts_held"][0])
+            lp["gu_w"], lp["down_w"], cfg["experts_held"][0],
+            lp["router_w"].shape[1])
         return y + swiglu(x, lp["sh_gu_w"], lp["sh_down_w"]), experts, \
             stats
 
@@ -189,7 +190,8 @@ def moe_stats_vector(stats, n_assign, held):
         sum(s["local_assignments"] for s in stats),
         sum(s["max_tokens_per_expert"] for s in stats),
         jnp.float32(n_assign * len(stats)),
-        jnp.float32(held * len(stats)), jnp.float32(len(stats))])
+        jnp.float32(held * len(stats)), jnp.float32(len(stats)),
+        sum(s["weight_tiles"] for s in stats)])
 
 
 def latent_rows(c, k_rope, width, dtype):

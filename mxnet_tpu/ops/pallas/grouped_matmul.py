@@ -9,9 +9,18 @@ weight block's index map picks the expert's matrix: the gather of an
 expert's weights is the pipeline's address computation.  The cost
 follows the tiles that hold tokens: a tile past ``n_valid`` skips its
 matmul under ``pl.when`` and repeats the block indices of the last
-valid one, so nothing is fetched for it.  With a few rows an expert
-(decode) the kernel is bound by the hit experts' weight bytes, each
-read once.
+valid one, so nothing is fetched for it.
+
+The grid is (row tiles, column blocks) with the columns innermost: a
+row tile walks all of its expert's column blocks, so an expert's
+weights cross HBM once a TILE.  They cross once a run where an
+expert's rows fit one tile, and that is the layout's to arrange
+(:func:`mxnet_tpu.parallel.moe.expert_tile_rows` picks the height from
+the rows an expert can expect): with a few rows an expert (decode) the
+kernel is bound by the hit experts' weight bytes, each read once; an
+expert with more rows than a tile holds has its weights read again for
+each further tile, which costs no time only at 256 rows, where a
+tile's matmul takes as long as its weights' read.
 """
 from __future__ import annotations
 

@@ -230,19 +230,42 @@ def expert_tiles(local_expert, num_held, tile_rows):
             n_valid.astype(jnp.int32), counts, rows)
 
 
+def expert_tile_rows(m, num_experts):
+    """Height of a row tile for ``m`` assignments routed over
+    ``num_experts`` experts: the power of two that holds twice the rows
+    an expert can expect, between 16 (the smallest bf16 tile) and 256.
+
+    :func:`moe_gmm` reads a tile's expert's weights once a TILE, so an
+    expert's weights cross HBM once a run only while its rows fit one
+    tile: a decode step's few rows an expert take 16, a prompt of 1,024
+    rows over 512 experts 32, a chunk of 2,048 rows over 256 experts
+    128, over 128 experts 256.  Twice the mean, because a full chunk
+    gives half the experts more than the mean and an uneven router some
+    of them far more.  Not taller than that, and never past 256: the
+    MXU multiplies a tile's pad rows too, the layout (``m + held *
+    (tile_rows - 1)`` rows) grows with the height, and at 256 rows a
+    tile's matmul already takes as long as its weights' read (measured
+    on a v5e at the served widths: CHANGES.md, PR 35).
+    """
+    twice = -(-2 * m // num_experts)
+    return min(max(16, 1 << (twice - 1).bit_length()), 256)
+
+
 def held_experts_ffn(x, experts, weights, gu_w, down_w, first,
-                     tile_rows=None):
+                     num_experts, tile_rows=None):
     """The held experts' part of ``sum_e w_e down_e(silu(gate_e x) *
     up_e x)`` for tokens ``x`` [T, C] routed by
-    :func:`grouped_topk_route`.
+    :func:`grouped_topk_route` over ``num_experts`` experts.
 
     ``gu_w`` [E_held, C, 2F] (gate columns, then up), ``down_w``
     [E_held, F, C]: the matrices of experts ``first .. first + E_held``
     of the router's range.  Terms of experts held elsewhere are left
-    out; the weights stay as normalised over all ``k``.  Returns
+    out; the weights stay as normalised over all ``k``.  ``tile_rows``
+    is :func:`expert_tile_rows`'s unless a test gives its own.  Returns
     ``(y float32 [T, C], stats)`` with ``stats`` the step's counts as
-    float32 scalars: held experts hit, local assignments, and the most
-    tokens one held expert got.
+    float32 scalars: held experts hit, local assignments, the most
+    tokens one held expert got, and the row tiles that hold a token
+    (``weight_tiles``: how often an expert's weights crossed HBM).
     """
     from ..ops.pallas.grouped_matmul import moe_gmm
 
@@ -251,11 +274,7 @@ def held_experts_ffn(x, experts, weights, gu_w, down_w, first,
     num_held, _, two_f = gu_w.shape
     m = t * k
     if tile_rows is None:
-        # few rows an expert (decode): the smallest bf16 tile.  A prompt
-        # gives a held expert ~16 rows: 32-row tiles keep the padded
-        # layout (m + held * (tile_rows - 1) rows) near m, and a tile
-        # reads its expert's weights once whatever its height
-        tile_rows = 16 if m <= 2048 else 32
+        tile_rows = expert_tile_rows(m, num_experts)
     local = experts.reshape(m) - first
     is_local = (local >= 0) & (local < num_held)
     dest, tile_expert, n_valid, counts, rows = expert_tiles(
@@ -276,5 +295,6 @@ def held_experts_ffn(x, experts, weights, gu_w, down_w, first,
                   0.0) * weights.reshape(m, 1)
     stats = {"experts_hit": (counts > 0).sum().astype(jnp.float32),
              "local_assignments": counts.sum().astype(jnp.float32),
-             "max_tokens_per_expert": counts.max().astype(jnp.float32)}
+             "max_tokens_per_expert": counts.max().astype(jnp.float32),
+             "weight_tiles": n_valid[0].astype(jnp.float32)}
     return y.reshape(t, k, c).sum(1), stats

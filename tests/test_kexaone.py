@@ -221,6 +221,24 @@ def test_engine_counts_chunks_pages_and_rings(net, served):
         == eng.kv_bytes_per_token
 
 
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_engine_counts_the_experts_weight_tiles(served, program):
+    """``weight_tiles`` (the row tiles that held a token: how often an
+    expert's weights were read) comes back from both programs with the
+    other counts and sums into ``serving.moe.weight_tiles``: a tile a
+    hit expert at least, a tile a local assignment at most."""
+    eng, _ = served
+    assert decoder_blocks.MOE_STATS[-1] == "weight_tiles"
+    got = eng.stat_totals[program]
+    assert 0 < got["experts_hit"] <= got["weight_tiles"] \
+        <= got["local_assignments"]
+    both = sum(eng.stat_totals[p]["weight_tiles"]
+               for p in ("prefill", "decode"))
+    # engines of later tests count into the same counter
+    assert telemetry.report()["counters"]["serving.moe.weight_tiles"] \
+        >= both
+
+
 @pytest.mark.parametrize("ahead", [0, 2])
 def test_a_reused_slot_does_not_see_the_last_tenants_ring(net, ahead):
     """One slot, two tenants: the second's logits are the reference's

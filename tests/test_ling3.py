@@ -312,7 +312,7 @@ def test_every_token_to_one_expert_drops_none():
     experts = jnp.full((t, 2), 6, jnp.int32).at[:, 1].set(1)  # 6 held, 1 not
     weights = jnp.asarray(rng.uniform(0.1, 1, size=(t, 2)), jnp.float32)
     y, stats = moe.held_experts_ffn(x, experts, weights, gu, down, first=4,
-                                    tile_rows=8)
+                                    num_experts=8, tile_rows=8)
     h = jnp.dot(x, gu[2], precision="highest")
     want = weights[:, :1] * jnp.dot(jax.nn.silu(h[:, :f]) * h[:, f:],
                                     down[2], precision="highest")
@@ -322,7 +322,7 @@ def test_every_token_to_one_expert_drops_none():
     assert float(stats["max_tokens_per_expert"]) == t
 
 
-@pytest.mark.parametrize("tile_rows", [8, 16])
+@pytest.mark.parametrize("tile_rows", [8, 16, 128])
 def test_moe_gmm_kernel_on_the_interpreter(tile_rows):
     rng = np.random.default_rng(tile_rows)
     local = jnp.asarray(rng.integers(0, 5, 50), jnp.int32)   # 4 = elsewhere
