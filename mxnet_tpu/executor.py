@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as _P
 
+from . import telemetry as _telemetry
 from .base import MXNetError
 from .ndarray.ndarray import NDArray
 
@@ -610,7 +611,7 @@ class Executor:
                 outs, new_aux = plan(merged, aux_vals, rng, True)
                 return tuple(outs), new_aux
 
-            with jax.named_scope("forward_backward"):
+            with _telemetry.device_scope("forward_backward"):
                 outs, vjp, new_aux = jax.vjp(f, param_vals, has_aux=True)
                 # loss heads seed with ones, exactly like
                 # forward_backward's default out_grads — fused and
@@ -623,7 +624,7 @@ class Executor:
             # poisoned forward-pass statistics (BatchNorm moving mean/var)
             # any more than poisoned weights
             merged_aux = dict(aux_vals)
-            with jax.named_scope("divergence_guard"):
+            with _telemetry.device_scope("divergence_guard"):
                 for k, v in new_aux.items():
                     merged_aux[k] = jnp.where(ok, v, aux_vals[k])
             return outs, new_params, new_state, merged_aux, ok
@@ -755,7 +756,6 @@ class Executor:
           ZeRO-sharded param vs all-reduce 2B(N-1)/N per replicated one
           — equal totals, but ZeRO holds 1/N of the state and runs 1/N
           of the update math."""
-        from . import telemetry as _telemetry
         mesh = self._mesh
         n = mesh.shape.get(self._dp_axis, 1)
 
@@ -941,7 +941,6 @@ class Executor:
         if not doc:
             return None
         self._cost_doc = doc
-        from . import telemetry as _telemetry
         for k, v in (doc.get("cost") or {}).items():
             _telemetry.gauge("xla.cost.%s_per_step" % k).set(v)
         for k, v in (doc.get("memory") or {}).items():
@@ -980,7 +979,6 @@ class Executor:
         cache where donated replay is unsafe), then serializes this
         backend's consumable variant off the hot path."""
         from . import aot_cache as _aot
-        from . import telemetry as _telemetry
         from . import watchdog as _watchdog
 
         def sds(x):
@@ -1004,6 +1002,7 @@ class Executor:
                 # original compiled object: cost attribution re-derives
                 # (or a prior capture on this executor already published)
                 self._capture_cost_telemetry(memo)
+                _telemetry.note_program("fit_step", memo)
                 return self._instrument(memo, first_call_compiles=False)
             loaded = _aot.load(key) if disk_ok else None
             if loaded is not None:
@@ -1018,6 +1017,7 @@ class Executor:
                     meta or self._analyze_compiled(compiled))
                 if var == _aot.VARIANT_DONATED:
                     _aot.memo_put(key, compiled)
+                    _telemetry.note_program("fit_step", compiled)
                     return self._instrument(compiled,
                                             first_call_compiles=False)
                 return self._twin_hotswap(mk_jit, examples, key, compiled)
@@ -1030,6 +1030,7 @@ class Executor:
             if disk_ok:
                 self._spawn_aot_store(mk_jit, examples, key, compiled,
                                       meta)
+            _telemetry.note_program("fit_step", compiled)
             return self._instrument(compiled)
         except Exception as e:
             import logging

@@ -12,6 +12,8 @@ from __future__ import annotations
 
 import numpy as _np
 
+from ...telemetry import device_scope
+
 EPS = 1e-6
 #: a latent row is padded to a multiple of this many lanes
 LATENT_ALIGN = 128
@@ -90,9 +92,10 @@ def mm(x, w):
 def rms(x, g, eps=EPS):
     import jax
     import jax.numpy as jnp
-    x = x.astype(jnp.float32)
-    return x * jax.lax.rsqrt((x * x).mean(-1, keepdims=True) + eps) \
-        * g.astype(jnp.float32)
+    with device_scope("norm"):
+        x = x.astype(jnp.float32)
+        return x * jax.lax.rsqrt((x * x).mean(-1, keepdims=True) + eps) \
+            * g.astype(jnp.float32)
 
 
 def rope_inv_freq(theta, half):
@@ -164,21 +167,22 @@ def moe(lp, x, cfg, valid=None):
     slot) is routed like any row and given to NO held expert, so what it
     would have chosen costs no expert a row and counts in no statistic
     but ``assignments``.  Returns ``(y, experts [T, k], stats)``."""
-    import jax
     import jax.numpy as jnp
     from ...parallel import moe as _moe
-    with jax.named_scope("moe"):
-        experts, weights = _moe.grouped_topk_route(
-            x, lp["router_w"], lp["router_b"], cfg["n_group"],
-            cfg["topk_group"], cfg["num_experts_per_tok"],
-            cfg["routed_scaling_factor"])
+    with device_scope("moe"):
+        with device_scope("moe.route"):
+            experts, weights = _moe.grouped_topk_route(
+                x, lp["router_w"], lp["router_b"], cfg["n_group"],
+                cfg["topk_group"], cfg["num_experts_per_tok"],
+                cfg["routed_scaling_factor"])
+            local = experts if valid is None \
+                else jnp.where(valid[:, None], experts, -1)
         y, stats = _moe.held_experts_ffn(
-            x, experts if valid is None
-            else jnp.where(valid[:, None], experts, -1), weights,
-            lp["gu_w"], lp["down_w"], cfg["experts_held"][0],
-            lp["router_w"].shape[1])
-        return y + swiglu(x, lp["sh_gu_w"], lp["sh_down_w"]), experts, \
-            stats
+            x, local, weights, lp["gu_w"], lp["down_w"],
+            cfg["experts_held"][0], lp["router_w"].shape[1])
+        with device_scope("moe.shared"):
+            return y + swiglu(x, lp["sh_gu_w"], lp["sh_down_w"]), \
+                experts, stats
 
 
 def moe_stats_vector(stats, n_assign, held):

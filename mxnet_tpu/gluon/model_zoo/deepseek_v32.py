@@ -51,6 +51,7 @@ import functools
 
 import numpy as _np
 
+from ...telemetry import device_scope
 from ..block import Block
 from . import decoder_blocks as _blocks
 from .decoder_blocks import (latent_width, mm as _mm, rms as _rms,
@@ -256,10 +257,11 @@ def softmax_scale(cfg):
 def _layer_norm(x, g, b):
     import jax
     import jax.numpy as jnp
-    mu = x.mean(-1, keepdims=True)
-    var = ((x - mu) ** 2).mean(-1, keepdims=True)
-    return (x - mu) * jax.lax.rsqrt(var + _blocks.EPS) \
-        * g.astype(jnp.float32) + b.astype(jnp.float32)
+    with device_scope("norm"):
+        mu = x.mean(-1, keepdims=True)
+        var = ((x - mu) ** 2).mean(-1, keepdims=True)
+        return (x - mu) * jax.lax.rsqrt(var + _blocks.EPS) \
+            * g.astype(jnp.float32) + b.astype(jnp.float32)
 
 
 def _attn_inputs(lp, ip, h, pos, cfg):
@@ -275,59 +277,65 @@ def _attn_inputs(lp, ip, h, pos, cfg):
     rank = cfg["kv_lora_rank"]
     n_i, d_i = cfg["index_n_heads"], cfg["index_head_dim"]
     freqs = _freqs(cfg)
-    c_q = _rms(_mm(h, lp["q_a_w"]), lp["q_a_norm_g"])
-    q = _mm(c_q, lp["q_b_w"]).reshape(t, n_h, dn + dr)
-    kva = _mm(h, lp["kva_w"])
-    qi = _mm(c_q, ip["q_w"]).reshape(t, n_i, d_i)
-    qi = jnp.concatenate([_blocks.rope(qi[..., :dr], pos, freqs),
-                          qi[..., dr:]], -1)
-    ki = _layer_norm(_mm(h, ip["k_w"]), ip["k_norm_g"], ip["k_norm_bias"])
-    ki = jnp.concatenate([_blocks.rope(ki[:, :dr], pos, freqs),
-                          ki[:, dr:]], -1)
-    wi = _mm(h, ip["w_w"]) * _np.float32(n_i ** -0.5 * d_i ** -0.5)
-    return (q[..., :dn],
-            _blocks.rope(q[..., dn:], pos, freqs, interleaved=True),
-            _rms(kva[:, :rank], lp["kv_norm_g"]),
-            _blocks.rope(kva[:, rank:], pos, freqs, interleaved=True),
-            qi, ki, wi)
+    with device_scope("attn.proj"):
+        c_q = _rms(_mm(h, lp["q_a_w"]), lp["q_a_norm_g"])
+        q = _mm(c_q, lp["q_b_w"]).reshape(t, n_h, dn + dr)
+        kva = _mm(h, lp["kva_w"])
+    with device_scope("index"):
+        qi = _mm(c_q, ip["q_w"]).reshape(t, n_i, d_i)
+        qi = jnp.concatenate([_blocks.rope(qi[..., :dr], pos, freqs),
+                              qi[..., dr:]], -1)
+        ki = _layer_norm(_mm(h, ip["k_w"]), ip["k_norm_g"],
+                         ip["k_norm_bias"])
+        ki = jnp.concatenate([_blocks.rope(ki[:, :dr], pos, freqs),
+                              ki[:, dr:]], -1)
+        wi = _mm(h, ip["w_w"]) * _np.float32(n_i ** -0.5 * d_i ** -0.5)
+    with device_scope("attn.proj"):
+        return (q[..., :dn],
+                _blocks.rope(q[..., dn:], pos, freqs, interleaved=True),
+                _rms(kva[:, :rank], lp["kv_norm_g"]),
+                _blocks.rope(kva[:, rank:], pos, freqs, interleaved=True),
+                qi, ki, wi)
 
 
 def _attend(lp, q_nope, q_rope, rows, n_valid, cfg):
     """Absorbed latent attention of queries ``q_*`` [N, H, .], each over
     its own list of selected latent ``rows`` [N, K, W] (the first
-    ``n_valid`` count).  Returns ``o`` float32 [N, H * dv]."""
-    import jax
+    ``n_valid`` count), under the caller's ``attn`` scope.  Returns
+    ``o`` float32 [N, H * dv]."""
     import jax.numpy as jnp
     from ...ops.pallas.sparse_latent_attention import mla_sparse
     n_h = cfg["num_attention_heads"]
     dn, dv = cfg["qk_nope_head_dim"], cfg["v_head_dim"]
     rank = cfg["kv_lora_rank"]
-    kvb = lp["kvb_w"].reshape(rank, n_h, dn + dv)
-    q_lat = jnp.einsum("shd,chd->shc", q_nope.astype(kvb.dtype),
-                       kvb[..., :dn], preferred_element_type=jnp.float32)
-    q = jnp.concatenate([q_lat, q_rope], -1)
-    q = jnp.pad(q, ((0, 0), (0, 0), (0, rows.shape[2] - q.shape[2])))
-    with jax.named_scope("attn"):
-        o_lat = mla_sparse(q, rows, n_valid, rank, softmax_scale(cfg))
-    o = jnp.einsum("shc,chd->shd", o_lat.astype(kvb.dtype), kvb[..., dn:],
-                   preferred_element_type=jnp.float32)
-    return o.reshape(o.shape[0], n_h * dv)
+    with device_scope("attn.proj"):
+        kvb = lp["kvb_w"].reshape(rank, n_h, dn + dv)
+        q_lat = jnp.einsum("shd,chd->shc", q_nope.astype(kvb.dtype),
+                           kvb[..., :dn],
+                           preferred_element_type=jnp.float32)
+        q = jnp.concatenate([q_lat, q_rope], -1)
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, rows.shape[2] - q.shape[2])))
+    o_lat = mla_sparse(q, rows, n_valid, rank, softmax_scale(cfg))
+    with device_scope("attn.out"):
+        o = jnp.einsum("shc,chd->shd", o_lat.astype(kvb.dtype),
+                       kvb[..., dn:], preferred_element_type=jnp.float32)
+        return o.reshape(o.shape[0], n_h * dv)
 
 
 def _write_rows(pool, rows, phys, offs):
-    import jax
-    with jax.named_scope("kv_write"):
-        return pool.at[phys, offs].set(rows.astype(pool.dtype))
+    return pool.at[phys, offs].set(rows.astype(pool.dtype))
 
 
 def _ffn(lp, x, cfg, routing, stats):
     h = _rms(x, lp["ln2_g"])
     if "mlp" in lp:
-        return x + _swiglu(h, lp["mlp"]["gu_w"], lp["mlp"]["down_w"])
+        with device_scope("mlp"):
+            return x + _swiglu(h, lp["mlp"]["gu_w"], lp["mlp"]["down_w"])
     y, experts, st = _moe(lp["moe"], h, cfg)
     routing.append(experts)
     stats.append(st)
-    return x + y
+    with device_scope("moe"):
+        return x + y
 
 
 def _aux(cfg, n_rows, routing, stats, selected, n_valid, in_context):
@@ -338,14 +346,17 @@ def _aux(cfg, n_rows, routing, stats, selected, n_valid, in_context):
     import jax.numpy as jnp
     k = cfg["num_experts_per_tok"]
     n_layers = len(cfg["layers"])
-    counts = jnp.stack([n_valid.sum(), in_context.sum()]) \
-        .astype(jnp.float32) * n_layers
-    return {"stats": jnp.concatenate([
-        _blocks.moe_stats_vector(stats, n_rows * k, cfg["experts_held"][1]),
-        counts]),
-        "experts": jnp.stack(routing) if routing
-        else jnp.zeros((0, n_rows, k), jnp.int32),
-        "selected": jnp.stack(selected), "n_selected": n_valid}
+    with device_scope("moe"), device_scope("moe.route"):
+        counts = jnp.stack([n_valid.sum(), in_context.sum()]) \
+            .astype(jnp.float32) * n_layers
+        stats = jnp.concatenate([
+            _blocks.moe_stats_vector(stats, n_rows * k,
+                                     cfg["experts_held"][1]), counts])
+        experts = jnp.stack(routing) if routing \
+            else jnp.zeros((0, n_rows, k), jnp.int32)
+    with device_scope("index"):
+        return {"stats": stats, "experts": experts,
+                "selected": jnp.stack(selected), "n_selected": n_valid}
 
 
 def forward(p, tokens, cfg):
@@ -394,7 +405,6 @@ def paged_decode_step(p, tokens, positions, active, caches, block_tables,
     aux)`` with sampling and ``(logits, next_tokens, new_caches, aux)``
     without; ``aux`` as :func:`_aux` has it.
     """
-    import jax
     import jax.numpy as jnp
     from jax import lax
     from .gpt import sample_tokens
@@ -403,7 +413,7 @@ def paged_decode_step(p, tokens, positions, active, caches, block_tables,
 
     s_n = tokens.shape[0]
     topk = cfg["index_topk"]
-    with jax.named_scope("embed"):
+    with device_scope("embed"):
         x = p["wte"][tokens].astype(jnp.float32)
     ctx = jnp.where(active, positions + 1, 0).astype(jnp.int32)
     n_valid = jnp.minimum(ctx, topk)
@@ -415,30 +425,35 @@ def paged_decode_step(p, tokens, positions, active, caches, block_tables,
         h = _rms(x, lp["ln1_g"])
         q_nope, q_rope, c, k_rope, qi, ki, wi = _attn_inputs(
             lp["attn"], lp["idx"], h, positions, cfg)
-        phys = jnp.where(active, jnp.take_along_axis(
-            block_tables, (positions // page_size)[:, None], axis=1)[:, 0],
-            0)
-        offs = positions % page_size
-        pool = _write_rows(pool, _latent_rows(c, k_rope, pool.shape[2],
-                                              pool.dtype), phys, offs)
-        ipool = _write_rows(ipool, ki, phys, offs)
-        with jax.named_scope("index"):
+        with device_scope("kv_write"):
+            phys = jnp.where(active, jnp.take_along_axis(
+                block_tables, (positions // page_size)[:, None],
+                axis=1)[:, 0], 0)
+            offs = positions % page_size
+            pool = _write_rows(pool, _latent_rows(
+                c, k_rope, pool.shape[2], pool.dtype), phys, offs)
+            ipool = _write_rows(ipool, ki, phys, offs)
+        with device_scope("index"):
             scores = dsa_index(qi[:, None], wi[:, None], ipool,
                                block_tables, slots, ctx, positions)[:, 0]
-            sel = dsa_select(scores, ctx, k_sel)
-        o = _attend(lp["attn"], q_nope, q_rope,
-                    gather_rows(pool, block_tables, sel), n_valid, cfg)
-        x = x + _mm(o, lp["attn"]["o_w"])
+            with device_scope("index.select"):
+                sel = dsa_select(scores, ctx, k_sel)
+        with device_scope("attn"):
+            with device_scope("attn.gather"):
+                rows = gather_rows(pool, block_tables, sel)
+            o = _attend(lp["attn"], q_nope, q_rope, rows, n_valid, cfg)
+        with device_scope("attn.out"):
+            x = x + _mm(o, lp["attn"]["o_w"])
         x = _ffn(lp, x, cfg, routing, stats)
         new_caches.append((pool, ipool))
         selected.append(sel)
-    with jax.named_scope("lm_head"):
+    with device_scope("lm_head"):
         logits = _head(_rms(x, p["lnf_g"]), p["head"])
     aux = _aux(cfg, s_n, routing, stats, selected, n_valid, ctx)
     if sampling is None:
         return logits, logits.argmax(-1).astype(jnp.int32), new_caches, aux
     temps, top_ks, top_ps, keys = sampling
-    with jax.named_scope("sample"):
+    with device_scope("sample"):
         nxt, new_keys = lax.cond(
             jnp.any(temps > 0),
             lambda: sample_tokens(logits, temps, top_ks, top_ps, keys),
@@ -463,7 +478,6 @@ def paged_prefill(p, tokens, prompt_len, prefix_len, block_table_row,
     (the first generated token when the chunk is the prompt's last)
     with ``aux`` appended (:func:`_aux`, rows = the chunk's padded rows).
     """
-    import jax
     import jax.numpy as jnp
     from jax import lax
     from .gpt import _first_token
@@ -476,7 +490,7 @@ def paged_prefill(p, tokens, prompt_len, prefix_len, block_table_row,
     valid = positions < prompt_len
     in_context = jnp.where(valid, positions + 1, 0)
     n_valid = jnp.minimum(in_context, topk)
-    with jax.named_scope("embed"):
+    with device_scope("embed"):
         x = p["wte"][tokens].astype(jnp.float32)
     new_caches, routing, stats, selected = [], [], [], []
     for lp, (pool, ipool) in zip(p["layers"], caches):
@@ -486,47 +500,56 @@ def paged_prefill(p, tokens, prompt_len, prefix_len, block_table_row,
         h = _rms(x, lp["ln1_g"])
         q_nope, q_rope, c, k_rope, qi, ki, wi = _attn_inputs(
             lp["attn"], lp["idx"], h, positions, cfg)
-        phys = jnp.where(
-            valid, block_table_row[jnp.minimum(positions, max_ctx - 1)
-                                   // page_size], 0)
-        offs = positions % page_size
-        pool = _write_rows(pool, _latent_rows(c, k_rope, pool.shape[2],
-                                              pool.dtype), phys, offs)
-        ipool = _write_rows(ipool, ki, phys, offs)
+        with device_scope("kv_write"):
+            phys = jnp.where(
+                valid, block_table_row[jnp.minimum(positions, max_ctx - 1)
+                                       // page_size], 0)
+            offs = positions % page_size
+            pool = _write_rows(pool, _latent_rows(
+                c, k_rope, pool.shape[2], pool.dtype), phys, offs)
+            ipool = _write_rows(ipool, ki, phys, offs)
         # index scores a group of rows at a time, the selection over each
         # row's own context
-        r_i = _rows_per_block(t_pad, PREFILL_INDEX_ROWS)
-        groups = t_pad // r_i
-        first = positions[::r_i]
-        with jax.named_scope("index"):
+        with device_scope("index"):
+            r_i = _rows_per_block(t_pad, PREFILL_INDEX_ROWS)
+            groups = t_pad // r_i
+            first = positions[::r_i]
             scores = dsa_index(
                 qi.reshape(groups, r_i, *qi.shape[1:]),
                 wi.reshape(groups, r_i, -1), ipool, block_table_row[None],
                 jnp.zeros(groups, jnp.int32),
                 jnp.minimum(first + r_i, prompt_len), first) \
                 .reshape(t_pad, max_ctx)
-            sel = dsa_select(scores, in_context, k_sel)
+            with device_scope("index.select"):
+                sel = dsa_select(scores, in_context, k_sel)
         # attention a block of rows at a time (a block's gathered lists
         # are [rows, K, width] at once), gathered BY POSITION from the
         # slot's pages laid side by side once a layer: a page-table
         # lookup a selected row cost half of what its gather costs
-        r_a = _rows_per_block(t_pad, PREFILL_ATTN_ROWS)
-        blocks = t_pad // r_a
-        in_order = pool[block_table_row].reshape(max_ctx, pool.shape[2])
+        with device_scope("attn"):
+            r_a = _rows_per_block(t_pad, PREFILL_ATTN_ROWS)
+            blocks = t_pad // r_a
+            with device_scope("attn.gather"):
+                in_order = pool[block_table_row].reshape(max_ctx,
+                                                         pool.shape[2])
 
-        def one_block(args, lp=lp, in_order=in_order):
-            qn, qr, s, n = args
-            return _attend(lp["attn"], qn, qr, in_order[s], n, cfg)
+            def one_block(args, lp=lp, in_order=in_order):
+                qn, qr, s, n = args
+                with device_scope("attn.gather"):
+                    rows = in_order[s]
+                return _attend(lp["attn"], qn, qr, rows, n, cfg)
 
-        o = lax.map(one_block, (
-            q_nope.reshape(blocks, r_a, *q_nope.shape[1:]),
-            q_rope.reshape(blocks, r_a, *q_rope.shape[1:]),
-            sel.reshape(blocks, r_a, k_sel), n_valid.reshape(blocks, r_a)))
-        x = x + _mm(o.reshape(t_pad, -1), lp["attn"]["o_w"])
+            o = lax.map(one_block, (
+                q_nope.reshape(blocks, r_a, *q_nope.shape[1:]),
+                q_rope.reshape(blocks, r_a, *q_rope.shape[1:]),
+                sel.reshape(blocks, r_a, k_sel),
+                n_valid.reshape(blocks, r_a)))
+        with device_scope("attn.out"):
+            x = x + _mm(o.reshape(t_pad, -1), lp["attn"]["o_w"])
         x = _ffn(lp, x, cfg, routing, stats)
         new_caches.append((pool, ipool))
         selected.append(sel)
-    with jax.named_scope("lm_head"):
+    with device_scope("lm_head"):
         last = lax.dynamic_index_in_dim(
             _rms(x, p["lnf_g"]), prompt_len - 1 - prefix_len, 0,
             keepdims=False)

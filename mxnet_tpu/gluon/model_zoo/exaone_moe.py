@@ -53,6 +53,7 @@ from __future__ import annotations
 
 import functools
 
+from ...telemetry import device_scope
 from ..block import Block
 from . import decoder_blocks as _blocks
 from .decoder_blocks import (mm as _mm, rms as _rms, head as _head,
@@ -241,15 +242,16 @@ def _qkv(lp, x, pos, sliding, cfg):
     h, kv, d = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
                 cfg["head_dim"])
     eps = cfg["rms_norm_eps"]
-    qkv = _mm(x, lp["qkv_w"])
-    q = _rms(qkv[:, :h * d].reshape(t, h, d), lp["q_norm_g"], eps)
-    k = _rms(qkv[:, h * d:(h + kv) * d].reshape(t, kv, d), lp["k_norm_g"],
-             eps)
-    v = qkv[:, (h + kv) * d:].reshape(t, kv, d)
-    if sliding:
-        freqs = _blocks.rope_inv_freq(float(cfg["rope_theta"]), d // 2)
-        q, k = _blocks.rope(q, pos, freqs), _blocks.rope(k, pos, freqs)
-    return q, k, v
+    with device_scope("attn.proj"):
+        qkv = _mm(x, lp["qkv_w"])
+        q = _rms(qkv[:, :h * d].reshape(t, h, d), lp["q_norm_g"], eps)
+        k = _rms(qkv[:, h * d:(h + kv) * d].reshape(t, kv, d),
+                 lp["k_norm_g"], eps)
+        v = qkv[:, (h + kv) * d:].reshape(t, kv, d)
+        if sliding:
+            freqs = _blocks.rope_inv_freq(float(cfg["rope_theta"]), d // 2)
+            q, k = _blocks.rope(q, pos, freqs), _blocks.rope(k, pos, freqs)
+        return q, k, v
 
 
 def _attend(q, k, v, mask):
@@ -373,15 +375,18 @@ def _finish(lp, x, o, cfg, routing, stats, valid=None):
     the norm on its output.  ``valid`` marks the rows that are tokens
     (``decoder_blocks.moe``)."""
     eps = cfg["rms_norm_eps"]
-    h = x + _rms(_mm(o.reshape(o.shape[0], -1), lp["attn"]["o_w"]),
-                 lp["ln1_g"], eps)
+    with device_scope("attn.out"):
+        h = x + _rms(_mm(o.reshape(o.shape[0], -1), lp["attn"]["o_w"]),
+                     lp["ln1_g"], eps)
     if "mlp" in lp:
-        f = _swiglu(h, lp["mlp"]["gu_w"], lp["mlp"]["down_w"])
-    else:
-        f, experts, st = _moe(lp["moe"], h, cfg, valid)
-        routing.append(experts)
-        stats.append(st)
-    return h + _rms(f, lp["ln2_g"], eps)
+        with device_scope("mlp"):
+            return h + _rms(_swiglu(h, lp["mlp"]["gu_w"],
+                                    lp["mlp"]["down_w"]), lp["ln2_g"], eps)
+    f, experts, st = _moe(lp["moe"], h, cfg, valid)
+    routing.append(experts)
+    stats.append(st)
+    with device_scope("moe"):
+        return h + _rms(f, lp["ln2_g"], eps)
 
 
 def _aux(cfg, n_rows, routing, stats, in_context):
@@ -393,15 +398,17 @@ def _aux(cfg, n_rows, routing, stats, in_context):
     k = cfg["num_experts_per_tok"]
     kinds = layer_kinds(cfg)
     n_full = kinds.count(FULL)
-    full = in_context.sum().astype(jnp.float32)
-    ring = jnp.minimum(in_context, cfg["sliding_window"]).sum() \
-        .astype(jnp.float32)
-    return {"stats": jnp.concatenate([
-        _blocks.moe_stats_vector(stats, n_rows * k, cfg["experts_held"][1]),
-        jnp.stack([n_full * full + (len(kinds) - n_full) * ring,
-                   len(kinds) * full])]),
-        "experts": jnp.stack(routing) if routing
-        else jnp.zeros((0, n_rows, k), jnp.int32)}
+    with device_scope("moe"), device_scope("moe.route"):
+        full = in_context.sum().astype(jnp.float32)
+        ring = jnp.minimum(in_context, cfg["sliding_window"]).sum() \
+            .astype(jnp.float32)
+        return {"stats": jnp.concatenate([
+            _blocks.moe_stats_vector(stats, n_rows * k,
+                                     cfg["experts_held"][1]),
+            jnp.stack([n_full * full + (len(kinds) - n_full) * ring,
+                       len(kinds) * full])]),
+            "experts": jnp.stack(routing) if routing
+            else jnp.zeros((0, n_rows, k), jnp.int32)}
 
 
 def forward(p, tokens, cfg):
@@ -436,48 +443,47 @@ def paged_decode_step(p, tokens, positions, active, caches, block_tables,
     aux)`` with sampling and ``(logits, next_tokens, new_caches, aux)``
     without; ``aux`` as :func:`_aux` has it.
     """
-    import jax
     import jax.numpy as jnp
     from jax import lax
     from .gpt import sample_tokens
     from ...ops.pallas.paged_attention import paged_attention
 
     s_n = tokens.shape[0]
-    with jax.named_scope("embed"):
+    with device_scope("embed"):
         x = p["wte"][tokens].astype(jnp.float32)
     ctx = jnp.where(active, positions + 1, 0).astype(jnp.int32)
     ring_row = jnp.where(active, jnp.arange(s_n), s_n)
     new_caches, routing, stats = [], [], []
     for lp, kind, (kc, vc) in zip(p["layers"], layer_kinds(cfg), caches):
         q, k, v = _qkv(lp["attn"], x, positions, kind == SLIDING, cfg)
-        k, v = k.reshape(s_n, -1), v.reshape(s_n, -1)
-        if kind == FULL:
-            page_size = kc.shape[1]
-            phys = jnp.where(active, jnp.take_along_axis(
-                block_tables, (positions // page_size)[:, None],
-                axis=1)[:, 0], 0)
-            at = (phys, positions % page_size)
-        else:
-            at = (ring_row, positions % kc.shape[1])
-        with jax.named_scope("kv_write"):
+        with device_scope("kv_write"):
+            k, v = k.reshape(s_n, -1), v.reshape(s_n, -1)
+            if kind == FULL:
+                page_size = kc.shape[1]
+                phys = jnp.where(active, jnp.take_along_axis(
+                    block_tables, (positions // page_size)[:, None],
+                    axis=1)[:, 0], 0)
+                at = (phys, positions % page_size)
+            else:
+                at = (ring_row, positions % kc.shape[1])
             kc = kc.at[at].set(k.astype(kc.dtype))
             vc = vc.at[at].set(v.astype(vc.dtype))
         if kind == FULL:
-            with jax.named_scope("attn.full"):
+            with device_scope("attn.full"):
                 o = paged_attention(q, kc, vc, block_tables, ctx)
         else:
-            with jax.named_scope("attn.window"):
+            with device_scope("attn.window"):
                 o = _attend_ring(q, kc[:s_n], vc[:s_n], positions, active,
                                  cfg)
         x = _finish(lp, x, o, cfg, routing, stats, active)
         new_caches.append((kc, vc))
-    with jax.named_scope("lm_head"):
+    with device_scope("lm_head"):
         logits = _head(_rms(x, p["lnf_g"], cfg["rms_norm_eps"]), p["head"])
     aux = _aux(cfg, s_n, routing, stats, ctx)
     if sampling is None:
         return logits, logits.argmax(-1).astype(jnp.int32), new_caches, aux
     temps, top_ks, top_ps, keys = sampling
-    with jax.named_scope("sample"):
+    with device_scope("sample"):
         nxt, new_keys = lax.cond(
             jnp.any(temps > 0),
             lambda: sample_tokens(logits, temps, top_ks, top_ps, keys),
@@ -502,7 +508,6 @@ def paged_prefill(p, tokens, prompt_len, prefix_len, block_table_row,
     (the first generated token when the chunk is the prompt's last)
     with ``aux`` appended (:func:`_aux`, rows = the chunk's padded rows).
     """
-    import jax
     import jax.numpy as jnp
     from jax import lax
     from .gpt import _first_token
@@ -512,44 +517,44 @@ def paged_prefill(p, tokens, prompt_len, prefix_len, block_table_row,
     positions = prefix_len + jnp.arange(t_pad, dtype=jnp.int32)
     n_rows = prompt_len - prefix_len
     valid = positions < prompt_len
-    with jax.named_scope("embed"):
+    with device_scope("embed"):
         x = p["wte"][tokens].astype(jnp.float32)
     new_caches, routing, stats = [], [], []
     for lp, kind, (kc, vc) in zip(p["layers"], layer_kinds(cfg), caches):
         q, k, v = _qkv(lp["attn"], x, positions, kind == SLIDING, cfg)
         if kind == FULL:
-            with jax.named_scope("kv_write"):
+            with device_scope("kv_write"):
                 kc, vc = (_blocks.page_scatter(pool, block_table_row, rows,
                                                prefix_len, n_rows)
                           for pool, rows in ((kc, k), (vc, v)))
-            with jax.named_scope("attn.full"):
+            with device_scope("attn.full"):
                 o = _attend_pages(q, kc, vc, block_table_row, prefix_len)
         else:
             window = kc.shape[1]
-            k, v = k.astype(kc.dtype), v.astype(vc.dtype)
-            old_k, old_v = (lax.dynamic_index_in_dim(
-                ring, slot, 0, keepdims=False).reshape(window, *k.shape[1:])
-                for ring in (kc, vc))
-            # the ring's rows in the order of their positions, prefix_len
-            # - window .. prefix_len - 1
-            behind = (prefix_len + jnp.arange(window)) % window
-            with jax.named_scope("attn.window"):
+            with device_scope("attn.window"):
+                k, v = k.astype(kc.dtype), v.astype(vc.dtype)
+                old_k, old_v = (lax.dynamic_index_in_dim(
+                    ring, slot, 0, keepdims=False)
+                    .reshape(window, *k.shape[1:]) for ring in (kc, vc))
+                # the ring's rows in the order of their positions,
+                # prefix_len - window .. prefix_len - 1
+                behind = (prefix_len + jnp.arange(window)) % window
                 o = _attend_window(q, k, v, old_k[behind], old_v[behind],
                                    prefix_len, cfg["sliding_window"])
-            # row r now holds the newest position p < prompt_len with p
-            # % window == r: this chunk's row if the chunk reaches it
-            newest = prompt_len - 1 \
-                - (prompt_len - 1 - jnp.arange(window)) % window
-            mine = (newest >= prefix_len)[:, None, None]
-            row = jnp.clip(newest - prefix_len, 0, t_pad - 1)
-            with jax.named_scope("kv_write"):
+            with device_scope("kv_write"):
+                # row r now holds the newest position p < prompt_len with
+                # p % window == r: this chunk's row if the chunk reaches it
+                newest = prompt_len - 1 \
+                    - (prompt_len - 1 - jnp.arange(window)) % window
+                mine = (newest >= prefix_len)[:, None, None]
+                row = jnp.clip(newest - prefix_len, 0, t_pad - 1)
                 kc, vc = (lax.dynamic_update_index_in_dim(
                     ring, jnp.where(mine, new[row], old)
                     .reshape(window, -1), slot, 0)
                     for ring, new, old in ((kc, k, old_k), (vc, v, old_v)))
         x = _finish(lp, x, o, cfg, routing, stats, valid)
         new_caches.append((kc, vc))
-    with jax.named_scope("lm_head"):
+    with device_scope("lm_head"):
         last = lax.dynamic_index_in_dim(
             _rms(x, p["lnf_g"], cfg["rms_norm_eps"]), n_rows - 1, 0,
             keepdims=False)

@@ -27,6 +27,7 @@ import functools
 
 from .. import nn
 from ..block import HybridBlock
+from ...telemetry import device_scope
 # the page-at-a-time write of one slot's rows, shared with the other
 # served models
 from .decoder_blocks import page_scatter as _page_scatter
@@ -385,19 +386,19 @@ def _decode_params(net):
 def _ln(x, g, b, eps=1e-5):
     import jax.numpy as jnp
     from jax import lax
-    mu = x.mean(-1, keepdims=True)
-    var = jnp.square(x - mu).mean(-1, keepdims=True)
-    return (x - mu) * lax.rsqrt(var + eps) * g + b
+    with device_scope("norm"):
+        mu = x.mean(-1, keepdims=True)
+        var = jnp.square(x - mu).mean(-1, keepdims=True)
+        return (x - mu) * lax.rsqrt(var + eps) * g + b
 
 
 def _block_qkv(lp, x, n_heads):
     """Shared per-layer front half: LN1 + fused head-major qkv.
     x [B, T, C] -> q, k, v [B, H, T, D] (layout from basic_layers.py's
     FlashSelfAttention; the ONE copy _prefill and _decode_one share)."""
-    import jax
     b, t, c = x.shape
     d = c // n_heads
-    with jax.named_scope("attn"):
+    with device_scope("attn.proj"):
         h = _ln(x, lp["ln1_g"], lp["ln1_b"])
         qkv = (h @ lp["qkv_w"].T + lp["qkv_b"]).reshape(
             b, t, n_heads, 3, d)
@@ -410,9 +411,9 @@ def _block_finish(lp, x, o):
     residual + LN2 + MLP (dense gelu or mixture of experts) +
     residual."""
     import jax
-    with jax.named_scope("attn"):
+    with device_scope("attn.out"):
         x = x + o @ lp["out_w"].T + lp["out_b"]
-    with jax.named_scope("mlp"):
+    with device_scope("mlp"):
         h = _ln(x, lp["ln2_g"], lp["ln2_b"])
         if "moe" in lp:
             from ...parallel.moe import moe_dense
@@ -677,11 +678,10 @@ def _block_qkv_kv(lp, x, n_heads):
     Returns ``q [B, H, T, D], k, v [B, K_kv, T, D]``."""
     if "qkv_w" in lp:
         return _block_qkv(lp, x, n_heads)
-    import jax
     b, t, c = x.shape
     d = c // n_heads
     kv_heads = lp["k_w"].shape[0] // d
-    with jax.named_scope("attn"):
+    with device_scope("attn.proj"):
         h = _ln(x, lp["ln1_g"], lp["ln1_b"])
         q = (h @ lp["q_w"].T + lp["q_b"]).reshape(b, t, n_heads, d)
         k = (h @ lp["k_w"].T + lp["k_b"]).reshape(b, t, kv_heads, d)
@@ -856,52 +856,53 @@ def paged_decode_step(p, tokens, positions, active, kv_pages,
     page_size = kv_pages[0][0].shape[1]
     from ...ops.pallas.paged_attention import paged_attention
 
-    import jax
-    with jax.named_scope("embed"):
+    with device_scope("embed"):
         x = p["wte"][tokens][:, None] + p["wpe"][positions][:, None]
     c = x.shape[-1]
     # where each slot's new K/V lands: (physical page, in-page offset);
     # inactive slots are routed to scratch page 0
-    logical = positions // page_size
-    phys = jnp.where(active,
-                     jnp.take_along_axis(block_tables, logical[:, None],
-                                         axis=1)[:, 0], 0)
-    offs = positions % page_size
+    with device_scope("kv_write"):
+        logical = positions // page_size
+        phys = jnp.where(active,
+                         jnp.take_along_axis(block_tables, logical[:, None],
+                                             axis=1)[:, 0], 0)
+        offs = positions % page_size
     # the kernel masks keys at position >= ctx; this step's own token is
     # key position `positions`, so the inclusive context is positions+1
-    ctx = jnp.where(active, positions + 1, 0).astype(jnp.int32)
+    with device_scope("attn"):
+        ctx = jnp.where(active, positions + 1, 0).astype(jnp.int32)
     quantized = _kv_quantized(kv_pages)
     new_pages = []
     for lp, entry in zip(p["layers"], kv_pages):
         q, k, v = _block_qkv_kv(lp, x, n_heads)     # q [S, H, 1, D]
         if quantized:
             kc, vc, ks, vs = entry                  # k/v [S, K_kv, 1, D]
-            with jax.named_scope("kv_write"):
+            with device_scope("kv_write"):
                 kc, ks = _quant_scatter(kc, ks, phys, offs,
                                         k[:, :, 0, :], active)
                 vc, vs = _quant_scatter(vc, vs, phys, offs,
                                         v[:, :, 0, :], active)
-            with jax.named_scope("attn"):
+            with device_scope("attn"):
                 o = paged_attention(q[:, :, 0, :], kc, vc, block_tables,
                                     ctx, k_scales=ks, v_scales=vs)
             new_pages.append((kc, vc, ks, vs))
         else:
             kc, vc = entry
-            with jax.named_scope("kv_write"):
+            with device_scope("kv_write"):
                 kc = kc.at[phys, offs].set(
                     k.reshape(s_n, -1).astype(kc.dtype))
                 vc = vc.at[phys, offs].set(
                     v.reshape(s_n, -1).astype(vc.dtype))
-            with jax.named_scope("attn"):
+            with device_scope("attn"):
                 o = paged_attention(q[:, :, 0, :], kc, vc, block_tables,
                                     ctx)
             new_pages.append((kc, vc))
         x = _block_finish(lp, x, o.reshape(s_n, 1, c))
-    with jax.named_scope("lm_head"):
+    with device_scope("lm_head"):
         h = _ln(x[:, 0], p["lnf_g"], p["lnf_b"])
         logits = h @ p["wte"].T
     if sampling is None:
-        with jax.named_scope("sample"):
+        with device_scope("sample"):
             return logits, logits.argmax(-1).astype(jnp.int32), new_pages
     temps, top_ks, top_ps, keys = sampling
     # an all-greedy resident batch must not pay the sampling math
@@ -910,7 +911,7 @@ def paged_decode_step(p, tokens, positions, active, kv_pages,
     # its tokens, so its key still advances exactly once per token —
     # the per-request determinism law is composition-independent.
     from jax import lax
-    with jax.named_scope("sample"):
+    with device_scope("sample"):
         nxt, new_keys = lax.cond(
             jnp.any(temps > 0),
             lambda: sample_tokens(logits, temps, top_ks, top_ps, keys),
@@ -1045,16 +1046,17 @@ def paged_spec_decode_step(p, tokens, positions, active, draft_len,
     # query-row validity: the slot is live and the row is the current
     # token (i == 0) or a real draft (i <= draft_len)
     qmask = active[:, None] & (qpos[None, :] <= draft_len[:, None])
-    import jax
-    with jax.named_scope("embed"):
+    with device_scope("embed"):
         x = p["wte"][tokens] + p["wpe"][positions]      # [S, K, C]
     c = x.shape[-1]
-    logical = positions // page_size
-    phys = jnp.where(qmask,
-                     jnp.take_along_axis(block_tables, logical, axis=1),
-                     0)
-    offs = positions % page_size
-    ctx = jnp.where(qmask, positions + 1, 0).astype(jnp.int32)
+    with device_scope("kv_write"):
+        logical = positions // page_size
+        phys = jnp.where(qmask,
+                         jnp.take_along_axis(block_tables, logical, axis=1),
+                         0)
+        offs = positions % page_size
+    with device_scope("attn"):
+        ctx = jnp.where(qmask, positions + 1, 0).astype(jnp.int32)
     quantized = _kv_quantized(kv_pages)
     flat = lambda a: a.reshape(s_n * k1)
     new_pages = []
@@ -1064,35 +1066,35 @@ def paged_spec_decode_step(p, tokens, positions, active, draft_len,
         vr = v.transpose(0, 2, 1, 3)
         if quantized:
             kc, vc, ks, vs = entry
-            with jax.named_scope("kv_write"):
+            with device_scope("kv_write"):
                 kc, ks = _quant_scatter(
                     kc, ks, flat(phys), flat(offs),
                     kr.reshape((s_n * k1,) + kr.shape[2:]), flat(qmask))
                 vc, vs = _quant_scatter(
                     vc, vs, flat(phys), flat(offs),
                     vr.reshape((s_n * k1,) + vr.shape[2:]), flat(qmask))
-            with jax.named_scope("attn"):
+            with device_scope("attn"):
                 o = paged_attention_multi(
                     q.transpose(0, 2, 1, 3), kc, vc, block_tables, ctx,
                     k_scales=ks, v_scales=vs)       # [S, K, H, D]
             new_pages.append((kc, vc, ks, vs))
         else:
             kc, vc = entry
-            with jax.named_scope("kv_write"):
+            with device_scope("kv_write"):
                 kc = kc.at[phys, offs].set(
                     kr.reshape(s_n, k1, -1).astype(kc.dtype))
                 vc = vc.at[phys, offs].set(
                     vr.reshape(s_n, k1, -1).astype(vc.dtype))
-            with jax.named_scope("attn"):
+            with device_scope("attn"):
                 o = paged_attention_multi(q.transpose(0, 2, 1, 3), kc,
                                           vc, block_tables, ctx)
             new_pages.append((kc, vc))
         x = _block_finish(lp, x, o.reshape(s_n, k1, c))
-    with jax.named_scope("lm_head"):
+    with device_scope("lm_head"):
         h = _ln(x, p["lnf_g"], p["lnf_b"])
         logits = h @ p["wte"].T                        # [S, K, V]
     draft_valid = qmask[:, 1:]          # draft at input column i+1
-    with jax.named_scope("sample"):
+    with device_scope("sample"):
         greedy_next, acc_g = _spec_accept_greedy(logits, tokens,
                                                  draft_valid)
         n_new_g = jnp.where(active, acc_g + 1, 0).astype(jnp.int32)
@@ -1118,7 +1120,7 @@ def paged_spec_decode_step(p, tokens, positions, active, draft_len,
                              keys)
         return out, n_new, new_keys
 
-    with jax.named_scope("sample"):
+    with device_scope("sample"):
         out_tokens, n_new, new_keys = lax.cond(
             jnp.any(temps > 0), _sampled,
             lambda: (greedy_next, n_new_g, keys))
@@ -1130,11 +1132,10 @@ def _first_token(logits, sampling, new_pages):
     4-tuple with the functionally-advanced key (scalar flavor of
     :func:`sample_tokens`; greedy requests skip the sampling math via
     cond)."""
-    import jax
     import jax.numpy as jnp
     from jax import lax
     if sampling is None:
-        with jax.named_scope("sample"):
+        with device_scope("sample"):
             return logits, logits.argmax(-1).astype(jnp.int32), new_pages
     temp, top_k, top_p, key = sampling
 
@@ -1145,7 +1146,7 @@ def _first_token(logits, sampling, new_pages):
             jnp.reshape(top_p, (1,)).astype(jnp.float32), key[None])
         return tok[0], new_key[0]
 
-    with jax.named_scope("sample"):
+    with device_scope("sample"):
         tok, new_key = lax.cond(
             temp > 0, _sampled,
             lambda: (logits.argmax(-1).astype(jnp.int32), key))
@@ -1166,25 +1167,26 @@ def _prefill_rows(p, tokens, prompt_len, prefix_len, prefix_kv, n_heads):
 
     t_pad = tokens.shape[0]
     positions = prefix_len + jnp.arange(t_pad)
-    with jax.named_scope("embed"):
+    with device_scope("embed"):
         x = (p["wte"][tokens] + p["wpe"][positions])[None]  # [1, T_pad, C]
     c = x.shape[-1]
     d = c // n_heads
-    valid = jnp.arange(t_pad) < prompt_len - prefix_len
-    # suffix-vs-suffix: causal within the window, pads masked
-    mask_suf = (jnp.tril(jnp.ones((t_pad, t_pad), bool))
-                & valid[None, :])[None, None]
-    if prefix_kv is not None:
-        # suffix-vs-cached-prefix: every suffix query sees every cached
-        # key
-        t_ctx = prefix_kv[0][0].shape[0] * prefix_kv[0][0].shape[1]
-        pre_valid = jnp.arange(t_ctx) < prefix_len
-        mask_pre = pre_valid[None, None, None, :]
-    scale = jnp.sqrt(jnp.float32(d))
+    with device_scope("attn"):
+        valid = jnp.arange(t_pad) < prompt_len - prefix_len
+        # suffix-vs-suffix: causal within the window, pads masked
+        mask_suf = (jnp.tril(jnp.ones((t_pad, t_pad), bool))
+                    & valid[None, :])[None, None]
+        if prefix_kv is not None:
+            # suffix-vs-cached-prefix: every suffix query sees every
+            # cached key
+            t_ctx = prefix_kv[0][0].shape[0] * prefix_kv[0][0].shape[1]
+            pre_valid = jnp.arange(t_ctx) < prefix_len
+            mask_pre = pre_valid[None, None, None, :]
+        scale = jnp.sqrt(jnp.float32(d))
     rows = []
     for i, lp in enumerate(p["layers"]):
         q, k, v = _block_qkv_kv(lp, x, n_heads)   # [1, H|K_kv, T_pad, D]
-        with jax.named_scope("attn"):
+        with device_scope("attn"):
             kd, vd = _bcast_kv(k, n_heads), _bcast_kv(v, n_heads)
             st = jnp.where(mask_suf,
                            jnp.einsum("bhqd,bhkd->bhqk", q, kd) / scale,
@@ -1262,7 +1264,6 @@ def paged_prefill(p, tokens, prompt_len, prefix_len, block_table_row,
     is produced here (the suffix is always >= 1 token — a fully-cached
     prompt still runs its final position through the model).
     """
-    import jax
     import jax.numpy as jnp
     from jax import lax
 
@@ -1272,8 +1273,9 @@ def paged_prefill(p, tokens, prompt_len, prefix_len, block_table_row,
     # copy-on-write FIRST: the prefix gather must see the copy, and it
     # carries the donor page's SCALE row with its bytes — a COW page
     # dequantizes identically to its donor
-    kv_pages = [tuple(a.at[cow_dst].set(a[cow_src]) for a in entry)
-                for entry in kv_pages]
+    with device_scope("kv_write"):
+        kv_pages = [tuple(a.at[cow_dst].set(a[cow_src]) for a in entry)
+                    for entry in kv_pages]
     from ...ops.pallas.paged_attention import dequant_pages
 
     def gathered(pool, scales=None):
@@ -1284,8 +1286,9 @@ def paged_prefill(p, tokens, prompt_len, prefix_len, block_table_row,
         return dequant_pages(pages, scales[block_table_row])
 
     # an entry is (k, v) or (k, v, k_scales, v_scales)
-    prefix_kv = [(gathered(*entry[0::2]), gathered(*entry[1::2]))
-                 for entry in kv_pages]
+    with device_scope("attn"), device_scope("attn.gather"):
+        prefix_kv = [(gathered(*entry[0::2]), gathered(*entry[1::2]))
+                     for entry in kv_pages]
     h, rows = lax.cond(
         prefix_len > 0,
         lambda: _prefill_rows(p, tokens, prompt_len, prefix_len,
@@ -1293,13 +1296,14 @@ def paged_prefill(p, tokens, prompt_len, prefix_len, block_table_row,
         lambda: _prefill_rows(p, tokens, prompt_len, 0, None, n_heads))
     suffix_len = prompt_len - prefix_len
     # where each row lands, for the pools that take a row an update (int8)
-    positions = prefix_len + jnp.arange(t_pad)
-    valid = jnp.arange(t_pad) < suffix_len
-    phys = jnp.where(valid, block_table_row[positions // page_size], 0)
-    offs = positions % page_size
+    with device_scope("kv_write"):
+        positions = prefix_len + jnp.arange(t_pad)
+        valid = jnp.arange(t_pad) < suffix_len
+        phys = jnp.where(valid, block_table_row[positions // page_size], 0)
+        offs = positions % page_size
     new_pages = []
     for entry, (k, v) in zip(kv_pages, rows):
-        with jax.named_scope("kv_write"):
+        with device_scope("kv_write"):
             if quantized:
                 # the COW page is the only written page with
                 # pre-existing content; _quant_scatter's grow-only
@@ -1315,7 +1319,7 @@ def paged_prefill(p, tokens, prompt_len, prefix_len, block_table_row,
                     _page_scatter(pool, block_table_row, x, prefix_len,
                                   suffix_len)
                     for pool, x in zip(entry, (k, v))))
-    with jax.named_scope("lm_head"):
+    with device_scope("lm_head"):
         last = lax.dynamic_index_in_dim(h, suffix_len - 1, 0,
                                         keepdims=False)
         logits = last @ p["wte"].T

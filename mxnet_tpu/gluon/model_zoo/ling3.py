@@ -49,6 +49,7 @@ import functools
 
 import numpy as _np
 
+from ...telemetry import device_scope
 from ..block import Block
 from . import decoder_blocks as _blocks
 from .decoder_blocks import (EPS, LATENT_ALIGN, latent_width,
@@ -251,18 +252,20 @@ def _mla_qkv(lp, x, pos, cfg):
     h = cfg["num_attention_heads"]
     dn, dr = cfg["qk_nope_head_dim"], cfg["qk_rope_head_dim"]
     rank = cfg["kv_lora_rank"]
-    q = _rms(_mm(x, lp["q_w"]).reshape(t, h, dn + dr), lp["q_norm_g"])
-    kva = _mm(x, lp["kva_w"])
-    return (q[..., :dn], _rope(q[..., dn:], pos, cfg["rope_theta"]),
-            _rms(kva[:, :rank], lp["kv_norm_g"]),
-            _rope(kva[:, rank:], pos, cfg["rope_theta"]))
+    with device_scope("attn.proj"):
+        q = _rms(_mm(x, lp["q_w"]).reshape(t, h, dn + dr), lp["q_norm_g"])
+        kva = _mm(x, lp["kva_w"])
+        return (q[..., :dn], _rope(q[..., dn:], pos, cfg["rope_theta"]),
+                _rms(kva[:, :rank], lp["kv_norm_g"]),
+                _rope(kva[:, rank:], pos, cfg["rope_theta"]))
 
 
 def _mla_out(lp, x, o, cfg):
     import jax
     t = x.shape[0]
-    gate = jax.nn.sigmoid(_mm(x, lp["g_w"]))                # [T, H]
-    return _mm((o * gate[..., None]).reshape(t, -1), lp["o_w"])
+    with device_scope("attn.out"):
+        gate = jax.nn.sigmoid(_mm(x, lp["g_w"]))            # [T, H]
+        return _mm((o * gate[..., None]).reshape(t, -1), lp["o_w"])
 
 
 def _mla_plain(lp, x, valid, cfg):
@@ -276,13 +279,15 @@ def _mla_plain(lp, x, valid, cfg):
     dn, dr, dv = (cfg["qk_nope_head_dim"], cfg["qk_rope_head_dim"],
                   cfg["v_head_dim"])
     q_nope, q_rope, c, k_rope = _mla_qkv(lp, x, jnp.arange(t), cfg)
-    kv = _mm(c, lp["kvb_w"]).reshape(t, h, dn + dv)
-    s = (jnp.einsum("qhd,khd->hqk", q_nope, kv[..., :dn])
-         + jnp.einsum("qhd,kd->hqk", q_rope, k_rope)) \
-        / _np.float32(_np.sqrt(dn + dr))
-    mask = jnp.tril(jnp.ones((t, t), bool)) & valid[None, :]
-    p = jax.nn.softmax(jnp.where(mask[None], s, -1e30), axis=-1)
-    o = jnp.einsum("hqk,khd->qhd", p, kv[..., dn:])
+    with device_scope("attn.proj"):
+        kv = _mm(c, lp["kvb_w"]).reshape(t, h, dn + dv)
+    with device_scope("attn"):
+        s = (jnp.einsum("qhd,khd->hqk", q_nope, kv[..., :dn])
+             + jnp.einsum("qhd,kd->hqk", q_rope, k_rope)) \
+            / _np.float32(_np.sqrt(dn + dr))
+        mask = jnp.tril(jnp.ones((t, t), bool)) & valid[None, :]
+        p = jax.nn.softmax(jnp.where(mask[None], s, -1e30), axis=-1)
+        o = jnp.einsum("hqk,khd->qhd", p, kv[..., dn:])
     return _mla_out(lp, x, o, cfg), c, k_rope
 
 
@@ -290,7 +295,6 @@ def _mla_absorbed(lp, x, pos, pool, block_tables, ctx, phys, offs, cfg):
     """Decode form: the up-projection absorbed into the query, scores
     against the cached rows themselves (``mla_paged_decode``).  Writes
     this token's row first.  Returns ``(y [S, C], new pool)``."""
-    import jax
     import jax.numpy as jnp
     from ...ops.pallas.latent_attention import mla_paged_decode
     s_n = x.shape[0]
@@ -299,26 +303,29 @@ def _mla_absorbed(lp, x, pos, pool, block_tables, ctx, phys, offs, cfg):
                   cfg["v_head_dim"])
     rank = cfg["kv_lora_rank"]
     q_nope, q_rope, c, k_rope = _mla_qkv(lp, x, pos, cfg)
-    with jax.named_scope("kv_write"):
+    with device_scope("kv_write"):
         pool = pool.at[phys, offs].set(
             _latent_rows(c, k_rope, pool.shape[2], pool.dtype))
-    kvb = lp["kvb_w"].reshape(rank, h, dn + dv)
-    q_lat = jnp.einsum("shd,chd->shc", q_nope.astype(kvb.dtype),
-                       kvb[..., :dn], preferred_element_type=jnp.float32)
-    q = jnp.concatenate([q_lat, q_rope], -1)
-    q = jnp.pad(q, ((0, 0), (0, 0), (0, pool.shape[2] - q.shape[2])))
-    with jax.named_scope("attn"):
+    with device_scope("attn.proj"):
+        kvb = lp["kvb_w"].reshape(rank, h, dn + dv)
+        q_lat = jnp.einsum("shd,chd->shc", q_nope.astype(kvb.dtype),
+                           kvb[..., :dn],
+                           preferred_element_type=jnp.float32)
+        q = jnp.concatenate([q_lat, q_rope], -1)
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, pool.shape[2] - q.shape[2])))
+    with device_scope("attn"):
         o_lat = mla_paged_decode(q, pool, block_tables, ctx, rank,
                                  1.0 / _np.sqrt(dn + dr))
-    o = jnp.einsum("shc,chd->shd", o_lat.astype(kvb.dtype), kvb[..., dn:],
-                   preferred_element_type=jnp.float32)
+    with device_scope("attn.out"):
+        o = jnp.einsum("shc,chd->shd", o_lat.astype(kvb.dtype),
+                       kvb[..., dn:], preferred_element_type=jnp.float32)
     return _mla_out(lp, x, o, cfg), pool
 
 
 def _kda_inputs(lp, x, qkv, cfg):
     """From the convolved ``qkv`` [T, 3HD] (after SiLU) and the layer
     input: normalised ``q, k``, ``v``, log decay ``g`` [T, H, D] and
-    ``beta`` [T, H]."""
+    ``beta`` [T, H] (under the caller's ``attn.proj`` scope)."""
     import jax
     import jax.numpy as jnp
     t = x.shape[0]
@@ -337,9 +344,10 @@ def _kda_inputs(lp, x, qkv, cfg):
 def _kda_out(lp, x, o, cfg):
     import jax
     t = x.shape[0]
-    o = _rms(o, lp["o_norm_g"])
-    gate = jax.nn.sigmoid(_mm(x, lp["g_w"]))
-    return _mm((o * gate[..., None]).reshape(t, -1), lp["o_w"])
+    with device_scope("attn.out"):
+        o = _rms(o, lp["o_norm_g"])
+        gate = jax.nn.sigmoid(_mm(x, lp["g_w"]))
+        return _mm((o * gate[..., None]).reshape(t, -1), lp["o_w"])
 
 
 def _kda_sequence(lp, x, valid, cfg):
@@ -352,16 +360,17 @@ def _kda_sequence(lp, x, valid, cfg):
     from ...ops.pallas.delta_rule import kda_chunked
     t = x.shape[0]
     width = cfg["short_conv_kernel_size"]
-    raw = _mm(x, lp["qkv_w"])                               # [T, 3HD]
-    padded = jnp.concatenate(
-        [jnp.zeros((width - 1, raw.shape[1]), jnp.float32), raw])
-    conv_w = lp["conv_w"].astype(jnp.float32)
-    qkv = jax.nn.silu(sum(padded[i:i + t] * conv_w[i]
-                          for i in range(width)))
-    q, k, v, g, beta = _kda_inputs(lp, x, qkv, cfg)
-    g = jnp.where(valid[:, None, None], g, 0.0)
-    beta = jnp.where(valid[:, None], beta, 0.0)
-    with jax.named_scope("kda_scan"):
+    with device_scope("attn.proj"):
+        raw = _mm(x, lp["qkv_w"])                           # [T, 3HD]
+        padded = jnp.concatenate(
+            [jnp.zeros((width - 1, raw.shape[1]), jnp.float32), raw])
+        conv_w = lp["conv_w"].astype(jnp.float32)
+        qkv = jax.nn.silu(sum(padded[i:i + t] * conv_w[i]
+                              for i in range(width)))
+        q, k, v, g, beta = _kda_inputs(lp, x, qkv, cfg)
+        g = jnp.where(valid[:, None, None], g, 0.0)
+        beta = jnp.where(valid[:, None], beta, 0.0)
+    with device_scope("kda_scan"):
         o, state = kda_chunked(q, k, v, g, beta)
     return _kda_out(lp, x, o, cfg), state, raw
 
@@ -373,20 +382,38 @@ def _kda_decode(lp, x, active, state, conv, cfg):
     import jax.numpy as jnp
     from ...ops.pallas.delta_rule import kda_step
     s_n = x.shape[0]
-    raw = _mm(x, lp["qkv_w"])                               # [S, 3HD]
-    window = jnp.concatenate(
-        [conv[:s_n].astype(jnp.float32), raw[:, None]], 1)  # [S, width, .]
-    qkv = jax.nn.silu(
-        (window * lp["conv_w"].astype(jnp.float32)[None]).sum(1))
-    q, k, v, g, beta = _kda_inputs(lp, x, qkv, cfg)
-    with jax.named_scope("kda_step"):
+    with device_scope("attn.proj"):
+        raw = _mm(x, lp["qkv_w"])                           # [S, 3HD]
+        window = jnp.concatenate(
+            [conv[:s_n].astype(jnp.float32), raw[:, None]], 1)
+        qkv = jax.nn.silu(                                  # [S, width, .]
+            (window * lp["conv_w"].astype(jnp.float32)[None]).sum(1))
+        q, k, v, g, beta = _kda_inputs(lp, x, qkv, cfg)
+    with device_scope("kda_step"):
         o, state = kda_step(state, q, k, v, g, beta, active)
-    conv = conv.at[:s_n].set(window[:, 1:].astype(conv.dtype))
+    with device_scope("state_write"):
+        conv = conv.at[:s_n].set(window[:, 1:].astype(conv.dtype))
     return _kda_out(lp, x, o, cfg), state, conv
 
 
 #: the decode program's last output: a float32 vector of these counts
 DECODE_STATS = _blocks.MOE_STATS
+
+
+def _ffn(lp, x, y, cfg, routing, stats):
+    """The layer after its attention's output ``y``: both residual adds
+    around the dense MLP or the routed experts."""
+    with device_scope("attn.out"):
+        x = x + y
+    h = _rms(x, lp["ln2_g"])
+    if "mlp" in lp:
+        with device_scope("mlp"):
+            return x + _swiglu(h, lp["mlp"]["gu_w"], lp["mlp"]["down_w"])
+    y, experts, st = _moe(lp["moe"], h, cfg)
+    routing.append(experts)
+    stats.append(st)
+    with device_scope("moe"):
+        return x + y
 
 
 def _sequence_pass(p, tokens, prompt_len, cfg):
@@ -395,11 +422,10 @@ def _sequence_pass(p, tokens, prompt_len, cfg):
     per layer what its cache keeps (MLA: ``(c, k_rope)``; KDA:
     ``(state, raw conv inputs)``) and the chosen experts per expert
     layer."""
-    import jax
     import jax.numpy as jnp
     t = tokens.shape[0]
     valid = jnp.arange(t) < prompt_len
-    with jax.named_scope("embed"):
+    with device_scope("embed"):
         x = p["wte"][tokens].astype(jnp.float32)
     kept, routing, stats = [], [], []
     for lp in p["layers"]:
@@ -410,15 +436,7 @@ def _sequence_pass(p, tokens, prompt_len, cfg):
         else:
             y, state, raw = _kda_sequence(lp["kda"], h, valid, cfg)
             kept.append((state, raw))
-        x = x + y
-        h = _rms(x, lp["ln2_g"])
-        if "mlp" in lp:
-            x = x + _swiglu(h, lp["mlp"]["gu_w"], lp["mlp"]["down_w"])
-        else:
-            y, experts, st = _moe(lp["moe"], h, cfg)
-            routing.append(experts)
-            stats.append(st)
-            x = x + y
+        x = _ffn(lp, x, y, cfg, routing, stats)
     return _rms(x, p["lnf_g"]), kept, routing, stats
 
 
@@ -441,13 +459,12 @@ def paged_decode_step(p, tokens, positions, active, caches, block_tables,
     [len(DECODE_STATS)], "experts": int32 [expert layers, S, k]}``), and
     without it ``(logits, next_tokens, new_caches, aux)``.
     """
-    import jax
     import jax.numpy as jnp
     from jax import lax
     from .gpt import sample_tokens
 
     s_n = tokens.shape[0]
-    with jax.named_scope("embed"):
+    with device_scope("embed"):
         x = p["wte"][tokens].astype(jnp.float32)
     ctx = jnp.where(active, positions + 1, 0).astype(jnp.int32)
     new_caches, routing, stats = [], [], []
@@ -456,36 +473,31 @@ def paged_decode_step(p, tokens, positions, active, caches, block_tables,
         if "mla" in lp:
             pool, = entry
             page_size = pool.shape[1]
-            phys = jnp.where(active, jnp.take_along_axis(
-                block_tables, (positions // page_size)[:, None],
-                axis=1)[:, 0], 0)
+            with device_scope("kv_write"):
+                phys = jnp.where(active, jnp.take_along_axis(
+                    block_tables, (positions // page_size)[:, None],
+                    axis=1)[:, 0], 0)
+                offs = positions % page_size
             y, pool = _mla_absorbed(lp["mla"], h, positions, pool,
-                                    block_tables, ctx, phys,
-                                    positions % page_size, cfg)
+                                    block_tables, ctx, phys, offs, cfg)
             new_caches.append((pool,))
         else:
             y, state, conv = _kda_decode(lp["kda"], h, active, *entry,
                                          cfg)
             new_caches.append((state, conv))
-        x = x + y
-        h = _rms(x, lp["ln2_g"])
-        if "mlp" in lp:
-            x = x + _swiglu(h, lp["mlp"]["gu_w"], lp["mlp"]["down_w"])
-        else:
-            y, experts, st = _moe(lp["moe"], h, cfg)
-            routing.append(experts)
-            stats.append(st)
-            x = x + y
-    with jax.named_scope("lm_head"):
+        x = _ffn(lp, x, y, cfg, routing, stats)
+    with device_scope("lm_head"):
         logits = _head(_rms(x, p["lnf_g"]), p["head"])
     k = cfg["num_experts_per_tok"]
-    aux = {"stats": _stats_vector(stats, s_n * k, cfg["experts_held"][1]),
-           "experts": jnp.stack(routing) if routing
-           else jnp.zeros((0, s_n, k), jnp.int32)}
+    with device_scope("moe"), device_scope("moe.route"):
+        aux = {"stats": _stats_vector(stats, s_n * k,
+                                      cfg["experts_held"][1]),
+               "experts": jnp.stack(routing) if routing
+               else jnp.zeros((0, s_n, k), jnp.int32)}
     if sampling is None:
         return logits, logits.argmax(-1).astype(jnp.int32), new_caches, aux
     temps, top_ks, top_ps, keys = sampling
-    with jax.named_scope("sample"):
+    with device_scope("sample"):
         nxt, new_keys = lax.cond(
             jnp.any(temps > 0),
             lambda: sample_tokens(logits, temps, top_ks, top_ps, keys),
@@ -509,7 +521,6 @@ def paged_prefill(p, tokens, prompt_len, prefix_len, block_table_row,
     decode step's, ``experts`` int32 [expert layers, T_pad, k]; the
     counts are over the padded prompt).
     """
-    import jax
     import jax.numpy as jnp
     from jax import lax
     from .gpt import _first_token
@@ -525,29 +536,31 @@ def paged_prefill(p, tokens, prompt_len, prefix_len, block_table_row,
         if len(entry) == 1:
             pool, = entry
             page_size = pool.shape[1]
-            phys = jnp.where(valid,
-                             block_table_row[positions // page_size], 0)
-            with jax.named_scope("kv_write"):
+            with device_scope("kv_write"):
+                phys = jnp.where(
+                    valid, block_table_row[positions // page_size], 0)
                 new_caches.append((pool.at[phys, positions % page_size].set(
                     _latent_rows(*held, pool.shape[2], pool.dtype)),))
         else:
             state, conv = entry
             final, raw = held
-            # the last `hist` real inputs, zeros before position 0
-            at = prompt_len - hist + jnp.arange(hist)
-            tail = jnp.where((at >= 0)[:, None],
-                             raw[jnp.maximum(at, 0)], 0.0)
-            with jax.named_scope("state_write"):
+            with device_scope("state_write"):
+                # the last `hist` real inputs, zeros before position 0
+                at = prompt_len - hist + jnp.arange(hist)
+                tail = jnp.where((at >= 0)[:, None],
+                                 raw[jnp.maximum(at, 0)], 0.0)
                 new_caches.append((
                     lax.dynamic_update_index_in_dim(state, final, slot, 0),
                     lax.dynamic_update_index_in_dim(
                         conv, tail.astype(conv.dtype), slot, 0)))
-    with jax.named_scope("lm_head"):
+    with device_scope("lm_head"):
         last = lax.dynamic_index_in_dim(h, prompt_len - 1, 0,
                                         keepdims=False)
         logits = _head(last, p["head"])
     k = cfg["num_experts_per_tok"]
-    aux = {"stats": _stats_vector(stats, t_pad * k, cfg["experts_held"][1]),
-           "experts": jnp.stack(routing) if routing
-           else jnp.zeros((0, t_pad, k), jnp.int32)}
+    with device_scope("moe"), device_scope("moe.route"):
+        aux = {"stats": _stats_vector(stats, t_pad * k,
+                                      cfg["experts_held"][1]),
+               "experts": jnp.stack(routing) if routing
+               else jnp.zeros((0, t_pad, k), jnp.int32)}
     return _first_token(logits, sampling, new_caches) + (aux,)

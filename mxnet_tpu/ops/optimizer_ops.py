@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from .registry import register_op
+from .. import telemetry as _telemetry
 
 
 def _rescale(grad, rescale_grad, clip_gradient):
@@ -325,17 +326,17 @@ def make_guarded_apply(apply_fn, zero_shardings=None, param_shardings=None):
         # scope names are what a device trace is read by (PERF.md
         # section 3): the guard's pass over the gradients apart from
         # the optimizer's arithmetic
-        with jax.named_scope("divergence_guard"):
+        with _telemetry.device_scope("divergence_guard"):
             grads = {name: g + poison for name, g in grads.items()}
             # dp grad sum → reduce-scatter
             grads = _wsc(grads, zero_shardings)
             ok = all_finite(grads)
-        with jax.named_scope("optimizer_apply"):
+        with _telemetry.device_scope("optimizer_apply"):
             new_params, new_state = apply_fn(params, grads, state, lr, wd,
                                              rescale_grad, t)
             # 1/N update compute
             new_params = _wsc(new_params, zero_shardings)
-        with jax.named_scope("divergence_guard"):
+        with _telemetry.device_scope("divergence_guard"):
             new_params = jax.tree_util.tree_map(
                 lambda n, o: jnp.where(ok, n, o), new_params, params)
             new_state = jax.tree_util.tree_map(
@@ -387,7 +388,6 @@ def handle_guard_verdict(ok, optimizer, indices, streak, pre_num_update,
         # both ways — ok steps become False-skipped, diverged True.
         # Module.fit_step records the verdict inline instead (marking
         # here would force a flight-ring drain on every step).
-        from .. import telemetry as _telemetry
         _telemetry.mark_last_step_verdict(ok_host)
     if ok_host:
         return 0

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .mesh import AXIS_DP
+from .. import telemetry as _telemetry
 from . import sharding as shd
 
 
@@ -109,9 +110,9 @@ def make_train_step(loss_fn, mesh, optimizer_apply=None, optimizer_init=None,
         # scope names are what a device trace is read by (PERF.md
         # section 3); the backward's ops carry "loss" inside their
         # transpose(jvp(...)) path
-        with jax.named_scope("loss"):
+        with _telemetry.device_scope("loss"):
             loss, grads = jax.value_and_grad(loss_fn)(params, batch, rng)
-        with jax.named_scope("optimizer_apply"):
+        with _telemetry.device_scope("optimizer_apply"):
             new_params, new_state = optimizer_apply(params, grads,
                                                     opt_state)
         return new_params, new_state, loss

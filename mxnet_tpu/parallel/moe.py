@@ -26,6 +26,7 @@ from ._shard_map import shard_map
 
 from .collectives import axis_size
 from .mesh import AXIS_EP
+from ..telemetry import device_scope
 
 
 def top1_gating(logits, capacity):
@@ -275,26 +276,33 @@ def held_experts_ffn(x, experts, weights, gu_w, down_w, first,
     m = t * k
     if tile_rows is None:
         tile_rows = expert_tile_rows(m, num_experts)
-    local = experts.reshape(m) - first
-    is_local = (local >= 0) & (local < num_held)
-    dest, tile_expert, n_valid, counts, rows = expert_tiles(
-        jnp.where(is_local, local, num_held).astype(jnp.int32),
-        num_held, tile_rows)
-    token = jnp.arange(m, dtype=jnp.int32) // k
-    x_rows = jnp.zeros((rows, c), gu_w.dtype).at[dest].set(
-        x.astype(gu_w.dtype)[token], mode="drop")
+    with device_scope("moe.route"):
+        local = experts.reshape(m) - first
+        is_local = (local >= 0) & (local < num_held)
+        dest, tile_expert, n_valid, counts, rows = expert_tiles(
+            jnp.where(is_local, local, num_held).astype(jnp.int32),
+            num_held, tile_rows)
+        token = jnp.arange(m, dtype=jnp.int32) // k
+    with device_scope("moe.scatter"):
+        x_rows = jnp.zeros((rows, c), gu_w.dtype).at[dest].set(
+            x.astype(gu_w.dtype)[token], mode="drop")
     # float32 between the two matmuls and after them: the only rounding
     # to the stored type is of each matmul's input, as in a dense MLP
-    gu = moe_gmm(x_rows, gu_w, tile_expert, n_valid, tile_rows,
-                 out_dtype=jnp.float32)
-    act = (jax.nn.silu(gu[:, :two_f // 2]) * gu[:, two_f // 2:]) \
-        .astype(gu_w.dtype)
-    y_rows = moe_gmm(act, down_w, tile_expert, n_valid, tile_rows,
+    with device_scope("moe.experts"):
+        gu = moe_gmm(x_rows, gu_w, tile_expert, n_valid, tile_rows,
                      out_dtype=jnp.float32)
-    y = jnp.where(is_local[:, None], y_rows[jnp.minimum(dest, rows - 1)],
-                  0.0) * weights.reshape(m, 1)
-    stats = {"experts_hit": (counts > 0).sum().astype(jnp.float32),
-             "local_assignments": counts.sum().astype(jnp.float32),
-             "max_tokens_per_expert": counts.max().astype(jnp.float32),
-             "weight_tiles": n_valid[0].astype(jnp.float32)}
-    return y.reshape(t, k, c).sum(1), stats
+        act = (jax.nn.silu(gu[:, :two_f // 2]) * gu[:, two_f // 2:]) \
+            .astype(gu_w.dtype)
+        y_rows = moe_gmm(act, down_w, tile_expert, n_valid, tile_rows,
+                         out_dtype=jnp.float32)
+    with device_scope("moe.combine"):
+        y = jnp.where(is_local[:, None],
+                      y_rows[jnp.minimum(dest, rows - 1)], 0.0) \
+            * weights.reshape(m, 1)
+    with device_scope("moe.route"):
+        stats = {"experts_hit": (counts > 0).sum().astype(jnp.float32),
+                 "local_assignments": counts.sum().astype(jnp.float32),
+                 "max_tokens_per_expert": counts.max().astype(jnp.float32),
+                 "weight_tiles": n_valid[0].astype(jnp.float32)}
+    with device_scope("moe.combine"):
+        return y.reshape(t, k, c).sum(1), stats

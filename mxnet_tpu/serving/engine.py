@@ -879,7 +879,12 @@ class ServingEngine:
           consumable variant off the hot path.
 
         Every tier returns a ``profiler.instrument``-wrapped callable so
-        steady-state dispatch/recompile accounting holds engine-wide.
+        steady-state dispatch/recompile accounting holds engine-wide,
+        and notes the program it serves on for good
+        (``telemetry.note_program``: its text says which scope each
+        instruction of a device trace lies in; the twin of a hot-swap
+        serves a few steps and the lazy jit has no text: neither is
+        noted).
         Any cache failure falls back to guarded lazy jit — the cache can
         make spin-up faster, never break serving."""
         import jax
@@ -888,9 +893,11 @@ class ServingEngine:
             return jax.jit(fn, donate_argnums=(1,) if donated else ())
 
         try:
-            key = _aot.cache_key("serve_" + name, examples, extra=extra)
+            tag = "serve_" + name
+            key = _aot.cache_key(tag, examples, extra=extra)
             memo = _aot.memo_get(key)
             if memo is not None:
+                _telemetry.note_program(tag, memo)
                 return _profiler.instrument(memo,
                                             first_call_compiles=False)
             if _aot.enabled():
@@ -901,6 +908,7 @@ class ServingEngine:
                     _watchdog.note_warm_start()
                     if var == _aot.VARIANT_DONATED:
                         _aot.memo_put(key, compiled)
+                        _telemetry.note_program(tag, compiled)
                         return _profiler.instrument(
                             compiled, first_call_compiles=False)
                     # warm hazard-backend spin-up: serve on the twin
@@ -915,6 +923,7 @@ class ServingEngine:
                 with _aot.bypass_persistent_cache():
                     compiled = mk_jit().lower(*examples).compile()
             _aot.memo_put(key, compiled)
+            _telemetry.note_program(tag, compiled)
             if _aot.enabled():
                 _aot.spawn_variant_store(mk_jit, examples, key,
                                          compiled,

@@ -90,15 +90,18 @@ import itertools
 import json
 import math
 import os
+import re
 import sys
 import threading
 import time
+import weakref
 
 import numpy as _np
 
 __all__ = ["Counter", "Gauge", "Histogram", "counter", "gauge",
            "histogram", "span", "stamp_span", "observe_phase", "report",
-           "reset",
+           "reset", "DEVICE_SCOPES", "device_scope", "note_program",
+           "program_scopes",
            "note_train_step", "note_fault", "mark_last_step_verdict",
            "flight_records", "flight_capacity", "dump_postmortem",
            "start_emitter", "stop_emitter", "set_enabled", "enabled",
@@ -413,6 +416,156 @@ def observe_phase(name, seconds):
     (rendered exactly like a span of the same name)."""
     if not _DISABLED:
         _span_hist(name).observe(seconds)
+
+
+# -- device-side scopes ------------------------------------------------------
+#: every name a program may enter as a device-side scope
+#: (:func:`device_scope`), with the row it has in OBSERVABILITY.md
+#: section 2.  Declared, not collected while tracing: a program that
+#: comes back from the AOT cache is never traced in its process, and its
+#: text must still be read by the same names.
+DEVICE_SCOPES = frozenset((
+    # the serving programs of gluon/model_zoo
+    "embed", "norm", "attn", "attn.proj", "attn.out", "attn.full",
+    "attn.window", "attn.gather", "index", "index.select", "kv_write",
+    "state_write", "kda_step", "kda_scan", "mlp", "moe", "moe.route",
+    "moe.scatter", "moe.experts", "moe.combine", "moe.shared", "lm_head",
+    "sample",
+    # the training steps (Module.fit_step, gluon.Trainer, gpt_spmd)
+    "forward_backward", "divergence_guard", "optimizer_apply", "loss"))
+
+
+def device_scope(name):
+    """``jax.named_scope(name)`` for a name of :data:`DEVICE_SCOPES`:
+    the one way this package names a stretch of a traced program.  The
+    name goes into the ``op_name`` of every instruction traced under it
+    (metadata: the executable and its cache key are the same with and
+    without it) and costs nothing when the program runs;
+    :func:`program_scopes` reads it back.  Scopes nest, and a reader
+    takes an instruction by its innermost one."""
+    if name not in DEVICE_SCOPES:
+        raise ValueError("telemetry.device_scope: %r is not declared in "
+                         "DEVICE_SCOPES" % (name,))
+    import jax
+    return jax.named_scope(name)
+
+
+#: compiled program -> the name it was noted under; weak, so a program
+#: nobody can run anymore leaves nothing here
+_programs = weakref.WeakKeyDictionary()
+#: compiled program -> ``(module, {instruction: scope path})``, filled
+#: by :func:`program_scopes`
+_scope_tables = weakref.WeakKeyDictionary()
+
+
+def note_program(name, compiled):
+    """Remember (weakly) that ``compiled``, a ``jax.stages.Compiled``,
+    is a program of this process, under ``name``.  Called where a
+    program is compiled, loaded or taken from the memo; one dictionary
+    store, no text is read."""
+    _programs[compiled] = name
+
+
+_HLO_MODULE = re.compile(r"^HloModule ([^\s,]+)")
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%(\S+) \(.*\{$")
+_HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%(\S+) = ")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_HLO_CALLS = re.compile(r"\bfusion\(.*\bcalls=%([^\s,)]+)")
+_NAME_TRANSFORM = re.compile(r"^(\w+)\((.*)\)$")
+
+
+def _scope_path(op_name):
+    """``jit(prefill)/jit(main)/while/body/moe/moe.scatter/scatter`` ->
+    ``moe/moe.scatter``: the declared scopes of an ``op_name``, outermost
+    first.  A transformation's wrapper (``jvp(attn)``,
+    ``transpose(jvp(attn))``) is the scope it wraps; ``jit(name)`` is a
+    function, never a scope.  Where the compiler merged instructions it
+    joins their names with ``;``: the first one counts.  A jitted
+    helper traced once and called again repeats the path that led to it
+    (``jit(decode)/moe/jit(searchsorted)/jit(decode)/moe/...``): the
+    path counts from the program's last mention.  A kernel named as the
+    scope that holds it (``kda_step``) counts once."""
+    parts = op_name.split(";", 1)[0].split("/")
+    for i in range(len(parts) - 1, 0, -1):
+        if parts[i] == parts[0]:
+            parts = parts[i:]
+            break
+    keep = []
+    for part in parts:
+        m = _NAME_TRANSFORM.match(part)
+        while m and m.group(1) not in ("jit", "pjit"):
+            part = m.group(2)
+            m = _NAME_TRANSFORM.match(part)
+        if part in DEVICE_SCOPES and part not in keep[-1:]:
+            keep.append(part)
+    return "/".join(keep)
+
+
+def _parse_scopes(text):
+    """``(module name, {instruction name: scope path})`` of a compiled
+    program's text.  A fusion the compiler left without ``op_name``
+    takes the one its fused computation ends on (its root's, or the last
+    named operation before a root that has none: a tuple); any other
+    instruction without ``op_name`` (a layout ``copy``, the
+    ``copy-done`` / ``slice-done`` of a prefetch) maps to the empty
+    path."""
+    module, table = None, {}
+    ends_on = {}       # computation -> the op_name it ends on
+    fusions = []       # fusions without op_name: (name, fused computation)
+    computation = None
+    for line in text.splitlines():
+        m = _HLO_INSTRUCTION.match(line)
+        if m:
+            name = m.group(1)
+            op = _HLO_OP_NAME.search(line, m.end())
+            table[name] = _scope_path(op.group(1)) if op else ""
+            if op:
+                ends_on[computation] = op.group(1)
+            else:
+                calls = _HLO_CALLS.search(line, m.end())
+                if calls:
+                    fusions.append((name, calls.group(1)))
+            continue
+        m = _HLO_COMPUTATION.match(line)
+        if m:
+            computation = m.group(1)
+        elif module is None:
+            m = _HLO_MODULE.match(line)
+            if m:
+                module = m.group(1)
+    for name, fused in fusions:
+        table[name] = _scope_path(ends_on.get(fused, ""))
+    return module, table
+
+
+def program_scopes():
+    """The scope of every compiled instruction of every noted program
+    that is still alive: ``[{"program": the name it was noted under,
+    "module": the text's HloModule name (``jit_decode``), "scopes":
+    {instruction name as a device trace prints it (``fusion.1608``):
+    scope path (``moe/moe.scatter``; empty for an instruction without
+    ``op_name`` or with no declared scope in it)}}]``.  Two programs may
+    share a module name (an engine per prefill length): a reader tells
+    them apart by the instruction names a run holds.
+
+    The read side of :func:`note_program`, for whoever wants the table
+    (the benchmark's ``device_scopes`` reader; an operator with a
+    ``jax.profiler`` trace): each program's ``as_text()`` is parsed
+    once, here.  Its resolution is the compiler's: a fusion carries the
+    ``op_name`` of its ROOT, so work the compiler fused across a scope's
+    edge counts where the fusion's last operation was written (a norm's
+    sum of squares computed in the matmul that feeds it is that
+    matmul's), and what the compiler added by itself (a layout copy, a
+    prefetch) lies in no scope: a reader counts it as unattributed."""
+    out = []
+    for compiled, name in list(_programs.items()):
+        table = _scope_tables.get(compiled)
+        if table is None:
+            table = _scope_tables[compiled] = _parse_scopes(
+                compiled.as_text())
+        out.append({"program": name, "module": table[0],
+                    "scopes": table[1]})
+    return out
 
 
 # -- XLA compile attribution (jax.monitoring bridge) -----------------------

@@ -396,8 +396,10 @@ def program_text():
 
 
 SCOPES = {
-    "decode": ("embed", "attn", "kv_write", "mlp", "lm_head", "sample"),
-    "prefill": ("embed", "attn", "kv_write", "mlp", "lm_head", "sample"),
+    "decode": ("embed", "norm", "attn.proj", "attn", "attn.out",
+               "kv_write", "mlp", "lm_head", "sample"),
+    "prefill": ("embed", "norm", "attn.proj", "attn", "attn.gather",
+                "attn.out", "kv_write", "mlp", "lm_head", "sample"),
     "fit": ("forward_backward", "divergence_guard", "optimizer_apply"),
     "gpt_spmd": ("loss", "optimizer_apply"),
 }
@@ -407,7 +409,7 @@ SCOPES = {
     (prog, scope) for prog in sorted(SCOPES) for scope in SCOPES[prog]])
 def test_program_holds_its_scope_name(program_text, program, scope):
     text = program_text[program]
-    assert re.search(r'op_name="[^"]*[/(]%s[/)]' % scope, text), \
+    assert re.search(r'op_name="[^"]*[/(]%s[/)]' % re.escape(scope), text), \
         "scope %r is not in the %s program's text" % (scope, program)
 
 
@@ -496,3 +498,69 @@ def test_every_documented_span_live():
     assert not stale, (
         "OBSERVABILITY.md documents spans no code writes anymore: %s"
         % stale)
+
+
+# -- scope inventory lint ---------------------------------------------------
+
+#: a name through the primitive, with or without the module's alias
+_SCOPE_RE = re.compile(r"(?<![\w.])(?:_telemetry\.|telemetry\.)?"
+                       r"device_scope\(\s*['\"]([a-z0-9_.]+)['\"]")
+_SCOPE_ROW_RE = re.compile(r"^\|(?P<names>[^|]+)\|\s*scope\s*\|")
+
+
+def _package_sources():
+    for dirpath, dirnames, filenames in os.walk(
+            os.path.join(REPO, "mxnet_tpu")):
+        dirnames[:] = [d for d in dirnames if d != "__pycache__"]
+        for fname in filenames:
+            if fname.endswith(".py"):
+                path = os.path.join(dirpath, fname)
+                with open(path, encoding="utf-8") as f:
+                    yield os.path.relpath(path, REPO), f.read()
+
+
+def scopes_in_code():
+    out = {}
+    for rel, src in _package_sources():
+        for name in _SCOPE_RE.findall(src):
+            out.setdefault(name, set()).add(rel)
+    return out
+
+
+def scopes_in_doc():
+    """Names from the rows of OBSERVABILITY.md's "Device-side names"
+    tables whose kind is ``scope``."""
+    with open(os.path.join(REPO, "OBSERVABILITY.md"),
+              encoding="utf-8") as f:
+        rows = [_SCOPE_ROW_RE.match(line.strip()) for line in f]
+    return {n for m in rows if m for n in _NAME_RE.findall(m.group("names"))}
+
+
+def test_every_scope_in_code_is_declared_and_documented():
+    code, doc = scopes_in_code(), scopes_in_doc()
+    assert len(code) >= 20, sorted(code)
+    undeclared = {n: sorted(code[n]) for n in code
+                  if n not in telemetry.DEVICE_SCOPES}
+    assert not undeclared, undeclared
+    missing = {n: sorted(code[n]) for n in code if n not in doc}
+    assert not missing, (
+        "scopes in code but MISSING from OBSERVABILITY.md's device-side "
+        "names: %s" % missing)
+
+
+def test_every_declared_and_documented_scope_is_live():
+    code, doc = scopes_in_code(), scopes_in_doc()
+    assert doc == set(telemetry.DEVICE_SCOPES), (
+        sorted(doc ^ set(telemetry.DEVICE_SCOPES)))
+    stale = sorted(doc - set(code))
+    assert not stale, (
+        "OBSERVABILITY.md documents scopes no code enters anymore: %s"
+        % stale)
+
+
+def test_no_named_scope_outside_telemetry():
+    """Every device-side scope goes through ``telemetry.device_scope``,
+    which holds it to the declared names."""
+    users = sorted(rel for rel, src in _package_sources()
+                   if "named_scope" in src)
+    assert users == [os.path.join("mxnet_tpu", "telemetry.py")], users
