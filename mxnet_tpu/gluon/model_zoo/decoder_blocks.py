@@ -1,7 +1,7 @@
 """What the served decoder models of this zoo share: the small layer
 functions over a parameter tree (``ling3.py``, ``deepseek_v32.py``,
-``exaone_moe.py``) and the page-at-a-time write of a slot's rows
-(``gpt.py`` too).
+``exaone_moe.py``, ``gigachat35.py``) and the page-at-a-time write of a
+slot's rows (``gpt.py`` too).
 
 Every function takes arrays, not a net: bfloat16 weights as stored,
 float32 residual stream, float32 accumulation.  A rotary embedding takes
@@ -126,6 +126,25 @@ def yarn_inv_freq(dim, theta, factor, original_max, beta_fast, beta_slow):
         .astype(_np.float32)
 
 
+def yarn_freqs(cfg):
+    """YaRN's frequencies of a latent-attention config's rotary values
+    (``qk_rope_head_dim``, ``rope_theta``, ``rope_scaling``)."""
+    rs = cfg["rope_scaling"]
+    return yarn_inv_freq(
+        cfg["qk_rope_head_dim"], float(cfg["rope_theta"]), rs["factor"],
+        rs["original_max_position_embeddings"], rs["beta_fast"],
+        rs["beta_slow"])
+
+
+def yarn_softmax_scale(cfg):
+    """``(dn + dr) ** -0.5 * m * m`` with YaRN's ``m = 0.1 ln(factor) +
+    1``."""
+    rs = cfg["rope_scaling"]
+    m = 0.1 * rs["mscale_all_dim"] * _np.log(rs["factor"]) + 1.0
+    return float((cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"]) ** -0.5
+                 * m * m)
+
+
 def rope(x, pos, inv_freq, interleaved=False):
     """Rotary embedding on the last axis at frequencies ``inv_freq``
     [last / 2]; ``pos`` indexes the first axis.  Pairs are ``(i, i +
@@ -154,11 +173,11 @@ def head(x, w):
                            preferred_element_type=jnp.float32)
 
 
-def swiglu(x, gu_w, down_w):
-    import jax
-    gu = mm(x, gu_w)
-    half = gu.shape[-1] // 2
-    return mm(jax.nn.silu(gu[..., :half]) * gu[..., half:], down_w)
+def swiglu(x, gu_w, down_w, limit=None):
+    """SwiGLU, clamped where ``limit`` is given
+    (``parallel.moe.swiglu_activation``)."""
+    from ...parallel.moe import swiglu_activation
+    return mm(swiglu_activation(mm(x, gu_w), limit), down_w)
 
 
 def moe(lp, x, cfg, valid=None):
@@ -166,9 +185,12 @@ def moe(lp, x, cfg, valid=None):
     marks the rows that are tokens: a pad row (a chunk's tail, an empty
     slot) is routed like any row and given to NO held expert, so what it
     would have chosen costs no expert a row and counts in no statistic
-    but ``assignments``.  Returns ``(y, experts [T, k], stats)``."""
+    but ``assignments``.  Every SwiGLU is clamped at
+    ``cfg["swiglu_limit"]`` where the model has one.  Returns ``(y,
+    experts [T, k], stats)``."""
     import jax.numpy as jnp
     from ...parallel import moe as _moe
+    limit = cfg.get("swiglu_limit")
     with device_scope("moe"):
         with device_scope("moe.route"):
             experts, weights = _moe.grouped_topk_route(
@@ -179,9 +201,9 @@ def moe(lp, x, cfg, valid=None):
                 else jnp.where(valid[:, None], experts, -1)
         y, stats = _moe.held_experts_ffn(
             x, local, weights, lp["gu_w"], lp["down_w"],
-            cfg["experts_held"][0], lp["router_w"].shape[1])
+            cfg["experts_held"][0], lp["router_w"].shape[1], limit=limit)
         with device_scope("moe.shared"):
-            return y + swiglu(x, lp["sh_gu_w"], lp["sh_down_w"]), \
+            return y + swiglu(x, lp["sh_gu_w"], lp["sh_down_w"], limit), \
                 experts, stats
 
 

@@ -237,21 +237,8 @@ def decode_params(net):
 # the layer, as functions of the parameter tree
 # ---------------------------------------------------------------------------
 
-def _freqs(cfg):
-    rs = cfg["rope_scaling"]
-    return _blocks.yarn_inv_freq(
-        cfg["qk_rope_head_dim"], float(cfg["rope_theta"]), rs["factor"],
-        rs["original_max_position_embeddings"], rs["beta_fast"],
-        rs["beta_slow"])
-
-
-def softmax_scale(cfg):
-    """``(dn + dr) ** -0.5 * m * m`` with YaRN's ``m = 0.1 ln(factor) +
-    1``."""
-    rs = cfg["rope_scaling"]
-    m = 0.1 * rs["mscale_all_dim"] * _np.log(rs["factor"]) + 1.0
-    return float((cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"]) ** -0.5
-                 * m * m)
+_freqs = _blocks.yarn_freqs
+softmax_scale = _blocks.yarn_softmax_scale
 
 
 def _layer_norm(x, g, b):

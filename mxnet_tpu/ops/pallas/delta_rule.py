@@ -19,6 +19,12 @@ bound by the state's bytes.  The five vectors a head needs arrive as
 one ``[S, 5, H, D]`` operand (``a, k, q, beta a k, beta v``); those that
 scale the state's rows are transposed in the cell.  A slot that is not
 live is passed ``a = 1, k = 0``: its state is written back unchanged.
+
+Beside them the chunked form of the GATED DELTA RULE of grouped heads
+with a scalar decay (``gdn_chunked``): value heads share query/key heads,
+one decay a value head a token, and the scan starts from a given state,
+so a prompt can be scanned a chunk of the engine's at a time.  Its decode
+step is ``kda_step`` on the key heads repeated and the decay broadcast.
 """
 from __future__ import annotations
 
@@ -158,6 +164,75 @@ def kda_chunked(q, k, v, g, beta, chunk=64):
     split = lambda a: a.reshape((n, chunk) + a.shape[1:])
     s_fin, o = lax.scan(one_chunk, jnp.zeros((h, d, v.shape[2]),
                                              jnp.float32),
+                        tuple(split(a.astype(jnp.float32))
+                              for a in (q, k, v, g, beta)))
+    return o.reshape(n * chunk, h, -1)[:t], s_fin
+
+
+# ---------------------------------------------------------------------------
+# the gated delta rule with a SCALAR decay a head and grouped heads
+# ---------------------------------------------------------------------------
+#
+# ``Hv`` value heads share ``Hk`` query/key heads (value head ``h`` reads
+# key head ``h // (Hv / Hk)``), and the decay ``a = exp(g)`` is one scalar
+# a value head a token.  A decode step is ``kda_step`` on the key heads
+# repeated and the decay broadcast along the key channels.
+
+def gdn_chunked(q, k, v, g, beta, initial_state=None, chunk=64):
+    """The grouped, scalar-gated recurrence over a sequence, a chunk at a
+    time, FROM ``initial_state`` (zeros if None): inside a chunk the
+    corrections ``u`` solve one unit lower-triangular system, between
+    chunks one state is passed.
+
+    ``q, k``: float32 [T, Hk, D]; ``v``: [T, Hv, Dv]; ``g``, ``beta``:
+    [T, Hv]; ``initial_state``: [Hv, D, Dv].  A position with ``g = 0``
+    and ``beta = 0`` (padding) leaves the state as it is.  A decay is a
+    difference ``G_i - G_j``, ``i >= j``, of the chunk's running sums, one
+    scalar a head: the pairwise decays are ``[Hv, C, C]``.
+
+    Returns ``(o [T, Hv, Dv], final state [Hv, D, Dv])``.
+    """
+    t, hk, d = q.shape
+    h = v.shape[1]
+    group = h // hk
+    q, k = (jnp.repeat(x, group, axis=1) for x in (q, k))
+    chunk = min(chunk, t)
+    pad = -t % chunk
+    if pad:
+        q, k, v = (jnp.pad(a, ((0, pad), (0, 0), (0, 0))) for a in (q, k, v))
+        g, beta = (jnp.pad(a, ((0, pad), (0, 0))) for a in (g, beta))
+    n = (t + pad) // chunk
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+    strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
+    eye = jnp.eye(chunk, dtype=jnp.float32)
+
+    def one_chunk(s0, xs):
+        q_c, k_c, v_c, g_c, b_c = xs               # [C, H, D], [C, H]
+        run = jnp.cumsum(g_c, axis=0).T                     # [H, C]
+        pair = jnp.exp(jnp.where(lower, run[:, :, None] - run[:, None, :],
+                                 -jnp.inf))                 # [H, C, C]
+        kk = jnp.einsum("ihd,jhd->hij", k_c, k_c, precision=_HIGH) * pair
+        qk = jnp.einsum("ihd,jhd->hij", q_c, k_c, precision=_HIGH) * pair
+        from_start = jnp.exp(run)                           # [H, C]
+        rhs = b_c.T[..., None] * (v_c.transpose(1, 0, 2)
+                                  - from_start[..., None] * jnp.einsum(
+                                      "ihk,hkv->hiv", k_c, s0,
+                                      precision=_HIGH))     # [H, C, Dv]
+        system = eye + jnp.where(strict, kk, 0.0) * b_c.T[:, :, None]
+        u = jax.scipy.linalg.solve_triangular(
+            system, rhs, lower=True, unit_diagonal=True)    # [H, C, Dv]
+        o = from_start[..., None] * jnp.einsum(
+            "ihk,hkv->hiv", q_c, s0, precision=_HIGH) + jnp.einsum(
+            "hij,hjv->hiv", jnp.where(lower, qk, 0.0), u, precision=_HIGH)
+        to_end = jnp.exp(run[:, -1:] - run)                 # [H, C]
+        s1 = s0 * from_start[:, -1, None, None] + jnp.einsum(
+            "jhk,hjv->hkv", k_c * to_end.T[..., None], u, precision=_HIGH)
+        return s1, o.transpose(1, 0, 2)
+
+    split = lambda a: a.reshape((n, chunk) + a.shape[1:])
+    s0 = jnp.zeros((h, d, v.shape[2]), jnp.float32) if initial_state is None \
+        else initial_state.astype(jnp.float32)
+    s_fin, o = lax.scan(one_chunk, s0,
                         tuple(split(a.astype(jnp.float32))
                               for a in (q, k, v, g, beta)))
     return o.reshape(n * chunk, h, -1)[:t], s_fin

@@ -322,11 +322,24 @@ def expert_tile_rows(m, num_experts):
     return min(max(16, 1 << (twice - 1).bit_length()), 256)
 
 
+def swiglu_activation(gu, limit=None):
+    """``silu(gate) * up`` of ``gu`` = ``[gate | up]`` on its last axis;
+    with a ``limit``, ``silu(min(gate, limit)) * clip(up, -limit,
+    limit)`` (a clamped SwiGLU)."""
+    half = gu.shape[-1] // 2
+    if limit is None:
+        return jax.nn.silu(gu[..., :half]) * gu[..., half:]
+    return jax.nn.silu(jnp.minimum(gu[..., :half], limit)) \
+        * jnp.clip(gu[..., half:], -limit, limit)
+
+
 def held_experts_ffn(x, experts, weights, gu_w, down_w, first,
-                     num_experts, tile_rows=None, layout_rows=None):
+                     num_experts, tile_rows=None, layout_rows=None,
+                     limit=None):
     """The held experts' part of ``sum_e w_e down_e(silu(gate_e x) *
     up_e x)`` for tokens ``x`` [T, C] routed by
-    :func:`grouped_topk_route` over ``num_experts`` experts.
+    :func:`grouped_topk_route` over ``num_experts`` experts (a clamped
+    SwiGLU where ``limit`` is given: :func:`swiglu_activation`).
 
     ``gu_w`` [E_held, C, 2F] (gate columns, then up), ``down_w``
     [E_held, F, C]: the matrices of experts ``first .. first + E_held``
@@ -377,8 +390,7 @@ def held_experts_ffn(x, experts, weights, gu_w, down_w, first,
         with device_scope("moe.experts"):
             gu = moe_gmm(x_rows, gu_w, tile_expert, n_valid, tile_rows,
                          out_dtype=jnp.float32)
-            act = (jax.nn.silu(gu[:, :two_f // 2]) * gu[:, two_f // 2:]) \
-                .astype(gu_w.dtype)
+            act = swiglu_activation(gu, limit).astype(gu_w.dtype)
             y_rows = moe_gmm(act, down_w, tile_expert, n_valid, tile_rows,
                              out_dtype=jnp.float32)
         with device_scope("moe.combine"):

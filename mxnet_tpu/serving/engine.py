@@ -1400,6 +1400,10 @@ class ServingEngine:
                 end - start)
             _telemetry.counter("serving.prefill.chunk_rows_padded").inc(
                 self.max_prefill_len)
+            if start and self._slot_bytes("state"):
+                # the run began from the recurrent state the slot's
+                # previous chunk run left
+                _telemetry.counter("serving.state.handoffs").inc()
             _telemetry.note_request_event(
                 req.trace, "prefill_chunk", t_ns=sync.t1,
                 args={"offset": start, "rows": end - start,

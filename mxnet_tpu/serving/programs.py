@@ -22,8 +22,9 @@ Cache kinds:
   rows it reads (learned sparse attention);
 - :class:`SlotState` — arrays with one row a SLOT (``[num_slots + 1,
   *shape]``, the last row scratch), freed with the slot, never paged.
-  Its ``role`` says what the rows are: "state", a recurrent state that
-  a prefill overwrites at admission and a decode step updates in place;
+  Its ``role`` says what the rows are: "state", a recurrent state (and
+  a short convolution's tail) that a prefill overwrites at admission, a
+  chunk run hands on to the next, and a decode step updates in place;
   or "ring", the newest ``window`` K and V rows of a sliding-window
   attention layer, position ``p`` at row ``p % window``, which a decode
   step overwrites a row at a time and a prefill reads before it leaves
@@ -37,10 +38,13 @@ layers only, a slot's bytes count its :class:`SlotState` arrays
 Prefix reuse, speculative decoding, int8 pages and a reduced KV-head
 count are defined for a model of :class:`KVPages` alone; the engine
 refuses them for a model with any other kind, in a sentence that names
-the kind.  A CHUNKED prefill may meet :class:`KVPages`,
-:class:`LatentPages` and a :class:`SlotState` of role "ring" (the chunk
-before left what this one reads: pages, or the ring's rows); a recurrent
-"state" has no chunked form here (``ling3.py`` prefills a prompt whole).
+the kind.  A CHUNKED prefill may meet every kind: the chunk before left
+what this one reads in the slot's pages, its ring's rows, or its state
+rows.  A chunk run at ``prefix_len`` 0 starts a recurrent state from
+zero, whatever the slot's rows hold; a later one starts from the rows
+and writes its own final state back (``gigachat35.py``; the engine
+counts these runs as ``serving.state.handoffs``).  ``ling3.py`` still
+prefills a prompt whole.
 """
 from __future__ import annotations
 
@@ -75,7 +79,7 @@ class ServingPrograms:
       at a time: its ``prefix_len`` argument is the position of the
       chunk's first row, ``prompt_len`` the prompt's length so far, and
       it reads what the chunks before wrote from the slot's own pages
-      (and rings).
+      (and rings, and recurrent states).
       The engine then admits prompts longer than ``max_prefill_len``
       and runs the program as often as a prompt needs;
     - ``config_key``: whatever the programs bake in that the input

@@ -428,7 +428,8 @@ DEVICE_SCOPES = frozenset((
     # the serving programs of gluon/model_zoo
     "embed", "norm", "attn", "attn.proj", "attn.out", "attn.full",
     "attn.window", "attn.gather", "index", "index.select", "kv_write",
-    "state_write", "kda_step", "kda_scan", "mlp", "moe", "moe.route",
+    "state_write", "kda_step", "kda_scan", "attn.mla", "gdn.proj",
+    "gdn_scan", "gdn_step", "gdn.out", "mlp", "moe", "moe.route",
     "moe.scatter", "moe.experts", "moe.combine", "moe.shared", "lm_head",
     "sample",
     # the training steps (Module.fit_step, gluon.Trainer, gpt_spmd)

@@ -442,6 +442,108 @@ def test_ring_engine_programs_compile_at_the_published_widths(
     assert held < 15.5 * 2 ** 30, mem
 
 
+_LONGDOC_PROGRAMS = {}
+
+
+def _longdoc_engine_programs(monkeypatch):
+    """``{name: (fn, example shapes)}`` of the engine's programs over
+    GigaChat3.5's share at its PUBLISHED widths and the benchmark cell's
+    own engine sizes (``perfbench/workloads/gigachat-serve-longdoc.json``:
+    32 slots, 64-token pages, chunks of 2,048 rows, the worst case's
+    17,409 pages), every kept layer.  Neither weights nor caches are made
+    (``_hybrid_engine_programs``)."""
+    if not _LONGDOC_PROGRAMS:
+        from mxnet_tpu.gluon.model_zoo import gigachat35
+        from mxnet_tpu.serving import ServingEngine
+        with open(os.path.join(REPO, "perfbench", "workloads",
+                               "gigachat-serve-longdoc.json")) as f:
+            cell = json.load(f)
+        net = gigachat35.gigachat35()
+        programs = net.serving_programs()
+        programs.decode_params = lambda net, kv_heads=None: \
+            gigachat35.param_tree(net.cfg, lambda path, shape:
+                                  jax.ShapeDtypeStruct(shape, jnp.bfloat16))
+        monkeypatch.setattr(net, "serving_programs", lambda: programs,
+                            raising=False)
+        monkeypatch.setattr(
+            ServingEngine, "_compile",
+            lambda self, name, fn, examples, extra:
+            _LONGDOC_PROGRAMS.__setitem__(name, (fn, examples)))
+        make = ServingEngine._init_cache
+        monkeypatch.setattr(
+            ServingEngine, "_init_cache",
+            lambda self, kind: jax.eval_shape(lambda: make(self, kind)))
+        _LONGDOC_PROGRAMS["engine"] = ServingEngine(net, **cell["engine"])
+        _LONGDOC_PROGRAMS["compiled_gb"] = cell["compiled_gb"]
+    return _LONGDOC_PROGRAMS
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_longdoc_engine_programs_compile_at_the_published_widths(
+        chip, monkeypatch, program):
+    """Both programs of the model whose chunked prefill hands a recurrent
+    state on compile for the described v5e at the cell's sizes: the
+    grouped, scalar-gated step (``kda_step`` over the 32 key heads
+    repeated for 64 value heads) and ``mla_paged_decode`` at 64 heads in
+    decode, ``moe_gmm`` in both;
+    every cache aliased (the states, tails and latent pool are updated
+    where they lie), and arguments and temporaries as the workload file's
+    sizing states them, inside the chip's 16 GiB."""
+    got = _longdoc_engine_programs(monkeypatch)
+    eng = got["engine"]
+    with open(os.path.join(REPO, "perfbench", "configs",
+                           "gigachat3.5-432b-a28b.json")) as f:
+        cache = json.load(f)["cache"]
+    assert eng.kv_bytes_per_token == cache["page_bytes_per_token"] == 1280
+    assert eng.state_bytes_per_slot == cache["state_bytes_per_slot"] \
+        == 4 * (64 * 128 * 128 + 3 * 16384) * 4
+    assert [tuple(a.shape) for a in eng._kv[4]] == [(17409, 64, 640)]
+    assert [tuple(a.shape) for a in eng._kv[0]] == [(33, 64, 128, 128),
+                                                   (33, 3, 16384)]
+    fn, examples = got[program]
+    caches = jax.tree_util.tree_leaves(examples[1])
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+            *jax.tree_util.tree_map(lambda a: chip(a.shape, a.dtype),
+                                    examples)).compile()
+    text = compiled.as_text()
+    assert "moe_gmm" in text
+    if program == "decode":
+        assert "kda_step" in text and "mla_paged_decode" in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(
+        int(np.prod(a.shape)) * a.dtype.itemsize for a in caches), mem
+    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        + max(0, mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    print(program, "arguments %.3f GB, temporaries %.3f GB"
+          % (mem.argument_size_in_bytes / 1e9, mem.temp_size_in_bytes / 1e9))
+    want = got["compiled_gb"]
+    assert mem.argument_size_in_bytes / 1e9 == pytest.approx(
+        want["arguments"], abs=0.01)
+    assert mem.temp_size_in_bytes / 1e9 == pytest.approx(
+        want[program + "_temporaries"], abs=0.02)
+    assert held < 15.5 * 2 ** 30, mem
+
+
+@pytest.mark.parametrize("slots", [1, 32])
+def test_gdn_step_kernel_compiles_at_the_served_widths(chip, slots):
+    """The grouped, scalar-gated delta-rule step at GigaChat3.5's widths
+    (64 value heads of 128 over 32 key heads): ``kda_step`` on the key
+    heads repeated and the decay broadcast, 16 value heads a grid cell;
+    the state is aliased."""
+    gigachat35 = importlib.import_module(
+        "mxnet_tpu.gluon.model_zoo.gigachat35")
+    f32 = jnp.float32
+    compiled = compile_for_chip(
+        lambda st, q, k, v, g, b, a: gigachat35.gdn_step(st, q, k, v, g, b,
+                                                         a),
+        chip((slots + 1, 64, 128, 128), f32), chip((slots, 32, 128), f32),
+        chip((slots, 32, 128), f32), chip((slots, 64, 128), f32),
+        chip((slots, 64), f32), chip((slots, 64), f32),
+        chip((slots,), jnp.bool_))
+    assert "kda_step" in compiled.as_text()
+
+
 @pytest.mark.parametrize("kernel,groups,rows", [
     ("dsa_index", 16, 1), ("dsa_index", 64, 32),
     ("mla_sparse", 16, 1), ("mla_sparse", 64, 1),

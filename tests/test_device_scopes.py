@@ -77,6 +77,14 @@ DOCUMENTED = {
     ("kexaone", "serve_prefill"): (
         "embed", "norm", "attn.proj", "attn.full", "attn.window",
         "attn.out", "kv_write", "mlp", "lm_head", "sample") + MOE,
+    ("gigachat", "serve_decode"): (
+        "embed", "norm", "attn.proj", "attn.mla", "attn.out", "kv_write",
+        "gdn.proj", "gdn_step", "gdn.out", "state_write", "mlp", "lm_head",
+        "sample") + MOE,
+    ("gigachat", "serve_prefill"): (
+        "embed", "norm", "attn.proj", "attn.mla", "attn.out", "kv_write",
+        "gdn.proj", "gdn_scan", "gdn.out", "state_write", "mlp", "lm_head",
+        "sample") + MOE,
     ("fit", "fit_step"): (
         "forward_backward", "divergence_guard", "optimizer_apply"),
 }
@@ -98,7 +106,8 @@ def _family(name):
         mod, batches = _module()
         mod.fit_step(batches[0])
         return mod
-    from mxnet_tpu.gluon.model_zoo import deepseek_v32, exaone_moe, ling3
+    from mxnet_tpu.gluon.model_zoo import (deepseek_v32, exaone_moe,
+                                           gigachat35, ling3)
     np.random.seed(0)
     mx.random.seed(0)
     if name == "ling3":
@@ -109,6 +118,10 @@ def _family(name):
         return ServingEngine(
             deepseek_v32.deepseek_v32_tiny().init_seeded(3), num_slots=2,
             page_size=8, num_pages=40, max_prefill_len=16, max_seq_len=72)
+    if name == "gigachat":
+        return ServingEngine(
+            gigachat35.gigachat35_tiny().init_seeded(3), num_slots=2,
+            page_size=4, num_pages=48, max_prefill_len=12, max_seq_len=80)
     assert name == "kexaone"
     return ServingEngine(
         exaone_moe.exaone_moe_tiny().init_seeded(3), num_slots=2,
@@ -121,7 +134,7 @@ def programs():
     ``program_scopes()`` has them, found by the compiled object its
     owner holds."""
     out, owners = {}, []
-    for family in ("gpt", "ling3", "dsv32", "kexaone", "fit"):
+    for family in ("gpt", "ling3", "dsv32", "kexaone", "gigachat", "fit"):
         before = {id(c) for c in telemetry._programs}
         owner = _family(family)
         owners.append(owner)
@@ -235,7 +248,8 @@ ENTRY %main.5 (x: f32[8], w: f32[8]) -> f32[8] {
 def test_device_scope_refuses_an_undeclared_name():
     with pytest.raises(ValueError, match="DEVICE_SCOPES"):
         telemetry.device_scope("attention")
-    assert len(telemetry.DEVICE_SCOPES) <= 27
+    # 27 before the gated delta rule's four scopes and `attn.mla`
+    assert len(telemetry.DEVICE_SCOPES) <= 32
 
 
 # -- (d) the registry ----------------------------------------------------------
