@@ -57,8 +57,10 @@ program of ``T`` rows that takes the position of its first row
 ``prefix_len`` 0 scans from a zero state and a zero tail (the reset at
 admission); a later one starts from what the chunk before left in the
 slot's rows and leaves its own there.  A latent layer writes the chunk's
-rows as whole pages and scores the chunk against the slot's pages a
-block at a time in the absorbed form (:func:`_attend_pages`).  No prefix
+rows as whole pages and scores the chunk against the slot's pages in the
+absorbed form with ``latent_attention.mla_paged_prefill``: one Pallas
+kernel whose running softmax stays in VMEM, a block of query rows by
+heads against a block of latent rows at a time.  No prefix
 reuse across requests (a recurrent prefix is a state), no speculative
 decoding (a state cannot be rolled back), no int8 rows.
 """
@@ -73,8 +75,7 @@ from ..block import Block
 from . import decoder_blocks as _blocks
 from .decoder_blocks import (latent_width, mm as _mm, head as _head,
                              swiglu as _swiglu, moe as _moe,
-                             latent_rows as _latent_rows,
-                             rows_per_block as _rows_per_block)
+                             latent_rows as _latent_rows)
 
 __all__ = ["GigaChat35LM", "gigachat35", "gigachat35_tiny",
            "decode_params", "param_tree", "forward", "paged_decode_step",
@@ -104,10 +105,6 @@ PUBLISHED = {
     "layernorm_gating_weight": 2,
 }
 
-#: query rows and latent rows (keys) a block of a chunk's attention over
-#: the slot's pages (a block's scores are ``[H, rows, keys]`` float32)
-QUERY_BLOCK = 512
-KEY_BLOCK = 1024
 #: positions a step of the chunked delta-rule scan
 SCAN_CHUNK = 64
 #: value heads a grid cell of the decode step's kernel
@@ -117,8 +114,6 @@ STEP_HEADS = 16
 #: the latent layers, the latent rows the dispatch's queries read (a
 #: query's whole context)
 DECODE_STATS = _blocks.MOE_STATS + ("latent.rows_read",)
-
-_NEG = -1e30
 
 
 def layer_kinds(cfg):
@@ -370,56 +365,6 @@ def _mla_out(lp, h, o_lat, cfg):
                        kvb[..., dn:], preferred_element_type=jnp.float32)
         gate = jax.nn.sigmoid(_mm(h, lp["g_w"]))
         return _mm(o.reshape(t, n_h * dv) * gate, lp["o_w"])
-
-
-def _attend_pages(q, pool, block_table_row, first_pos, rank, scale):
-    """A chunk's latent attention: queries ``q`` [T, H, W] (absorbed, at
-    positions ``first_pos ..``) over the slot's latent pages, which hold
-    every row up to the chunk's own.  ``QUERY_BLOCK`` queries at a time
-    walk the blocks of ``KEY_BLOCK`` rows up to their last position with
-    a running softmax; a row's first ``rank`` lanes are its value.
-    Returns ``o_lat`` float32 [T, H, rank]."""
-    import jax.numpy as jnp
-    from jax import lax
-    t, h, w = q.shape
-    page = pool.shape[1]
-    pages = max(1, min(KEY_BLOCK // page, block_table_row.shape[0]))
-    keys = pages * page
-    table = jnp.pad(block_table_row,
-                    (0, -block_table_row.shape[0] % pages))
-    rows = _rows_per_block(t, QUERY_BLOCK)
-
-    def one_block(args):
-        qb, first = args                                    # [rows, H, W]
-        q_pos = first + jnp.arange(rows)
-
-        def step(j, carry):
-            m, total, acc = carry
-            at = lax.dynamic_slice_in_dim(table, j * pages, pages)
-            kb = pool[at].reshape(keys, w)
-            mask = (j * keys + jnp.arange(keys))[None, :] <= q_pos[:, None]
-            s = jnp.where(mask, jnp.einsum(
-                "qhw,kw->hqk", qb, kb,
-                preferred_element_type=jnp.float32) * scale, _NEG)
-            m_new = jnp.maximum(m, s.max(-1))
-            e = jnp.where(mask, jnp.exp(s - m_new[..., None]), 0.0)
-            keep = jnp.exp(m - m_new)
-            return (m_new, total * keep + e.sum(-1),
-                    acc * keep[..., None] + jnp.einsum(
-                        "hqk,kc->hqc", e.astype(kb.dtype), kb[:, :rank],
-                        preferred_element_type=jnp.float32))
-
-        _, total, acc = lax.fori_loop(
-            0, (first + rows - 1) // keys + 1, step,
-            (jnp.full((h, rows), _NEG, jnp.float32),
-             jnp.zeros((h, rows), jnp.float32),
-             jnp.zeros((h, rows, rank), jnp.float32)))
-        return acc / total[..., None]
-
-    o = lax.map(one_block, (
-        q.reshape(t // rows, rows, h, w).astype(pool.dtype),
-        first_pos + jnp.arange(t // rows) * rows))
-    return o.transpose(0, 2, 1, 3).reshape(t, h, rank)
 
 
 def _conv_silu(window, conv_w):
@@ -676,6 +621,7 @@ def paged_prefill(p, tokens, prompt_len, prefix_len, block_table_row,
     import jax.numpy as jnp
     from jax import lax
     from .gpt import _first_token
+    from ...ops.pallas.latent_attention import mla_paged_prefill
 
     del cow_src, cow_dst
     t_pad = tokens.shape[0]
@@ -702,8 +648,9 @@ def paged_prefill(p, tokens, prompt_len, prefix_len, block_table_row,
                 q = _absorbed_query(lp["attn"], q_nope, q_rope,
                                     pool.shape[2], cfg)
             with device_scope("attn.mla"):
-                o_lat = _attend_pages(q, pool, block_table_row, prefix_len,
-                                      rank, softmax_scale(cfg))
+                o_lat = mla_paged_prefill(q, pool, block_table_row,
+                                          prefix_len, n_rows, rank,
+                                          softmax_scale(cfg))
             x = _mix_add(lp, x, _mla_out(lp["attn"], h, o_lat, cfg), cfg,
                          "attn.out")
             new_caches.append((pool,))

@@ -485,7 +485,7 @@ def test_longdoc_engine_programs_compile_at_the_published_widths(
     state on compile for the described v5e at the cell's sizes: the
     grouped, scalar-gated step (``kda_step`` over the 32 key heads
     repeated for 64 value heads) and ``mla_paged_decode`` at 64 heads in
-    decode, ``moe_gmm`` in both;
+    decode, ``mla_paged_prefill`` in a chunk run, ``moe_gmm`` in both;
     every cache aliased (the states, tails and latent pool are updated
     where they lie), and arguments and temporaries as the workload file's
     sizing states them, inside the chip's 16 GiB."""
@@ -510,6 +510,8 @@ def test_longdoc_engine_programs_compile_at_the_published_widths(
     assert "moe_gmm" in text
     if program == "decode":
         assert "kda_step" in text and "mla_paged_decode" in text
+    else:
+        assert "mla_paged_prefill" in text
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= sum(
         int(np.prod(a.shape)) * a.dtype.itemsize for a in caches), mem
@@ -523,6 +525,40 @@ def test_longdoc_engine_programs_compile_at_the_published_widths(
     assert mem.temp_size_in_bytes / 1e9 == pytest.approx(
         want[program + "_temporaries"], abs=0.02)
     assert held < 15.5 * 2 ** 30, mem
+
+
+def test_latent_prefill_kernel_at_the_longdoc_cell_stays_in_its_vmem(chip):
+    """``mla_paged_prefill`` at ``gigachat-serve-longdoc``'s widths: a
+    chunk of 2,048 rows, 64 heads, rows of 640 lanes in 17,409 pages of
+    64, 544 pages a block-table row.  A cell is 64 query rows by 16
+    heads (1,024 matrix rows) against 8 pages.  The call asks for a VMEM
+    limit of its own, from the shapes; what it keeps there (its scratch,
+    and two buffers of every blocked operand) is counted from the call
+    itself and has to leave half the limit to the score block's
+    temporaries, and the limit stays under half the chip's 128 MiB."""
+    la = importlib.import_module("mxnet_tpu.ops.pallas.latent_attention")
+    args = (chip((2048, 64, 640), jnp.float32),
+            chip((17409, 64, 640), jnp.bfloat16), chip((544,), jnp.int32),
+            chip((), jnp.int32), chip((), jnp.int32))
+
+    def attend(q, pool, table, first, n_rows):
+        return la.mla_paged_prefill(q, pool, table, first, n_rows, 512,
+                                    0.0934)
+
+    compiled = compile_for_chip(attend, *args)
+    assert "mla_paged_prefill" in compiled.as_text()
+    (call,) = _pallas_calls(jax.make_jaxpr(attend)(*args).jaxpr)
+    limit = call.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+    mapping = call.params["grid_mapping"]
+    assert mapping.grid == (32, 4)
+    scratch = [v.aval for v in call.params["jaxpr"].invars[
+        -mapping.num_scratch_operands:]]
+    held = sum(_tiled_bytes(a.shape, a.dtype) for a in scratch
+               if "vmem" in str(a))
+    blocks = [m.transformed_block_aval for m in mapping.block_mappings]
+    held += sum(2 * _tiled_bytes(a.shape, a.dtype) for a in blocks
+                if "any" not in str(a))
+    assert held <= limit // 2 and limit <= 64 * 2 ** 20, (held, limit)
 
 
 @pytest.mark.parametrize("slots", [1, 32])

@@ -491,8 +491,7 @@ def test_both_programs_name_their_scopes(net):
 
 
 def test_this_model_shares_its_layer_functions():
-    for name in ("mm", "head", "swiglu", "moe", "latent_rows",
-                 "rows_per_block"):
+    for name in ("mm", "head", "swiglu", "moe", "latent_rows"):
         assert getattr(gc, "_" + name) is getattr(decoder_blocks, name)
     assert gc.DECODE_STATS[:len(decoder_blocks.MOE_STATS)] \
         == decoder_blocks.MOE_STATS
