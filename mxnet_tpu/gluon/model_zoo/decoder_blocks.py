@@ -1,7 +1,8 @@
 """What the served decoder models of this zoo share: the small layer
 functions over a parameter tree (``ling3.py``, ``deepseek_v32.py``,
-``exaone_moe.py``, ``gigachat35.py``) and the page-at-a-time write of a
-slot's rows (``gpt.py`` too).
+``exaone_moe.py``, ``gigachat35.py``, ``mellum2.py``), the attention of
+window and full layers, and the page-at-a-time write of a slot's rows
+(``gpt.py`` too).
 
 Every function takes arrays, not a net: bfloat16 weights as stored,
 float32 residual stream, float32 accumulation.  A rotary embedding takes
@@ -17,6 +18,13 @@ from ...telemetry import device_scope
 EPS = 1e-6
 #: a latent row is padded to a multiple of this many lanes
 LATENT_ALIGN = 128
+#: query rows and keys a block of a chunk's attention over the slot's
+#: pages (:func:`attend_pages`: a block's scores are ``[H, rows, keys]``
+#: float32 at once)
+FULL_QUERY_BLOCK = 512
+FULL_KEY_BLOCK = 1024
+#: a masked score
+NEG = -1e30
 
 #: the decode program's last output: a float32 vector of these counts,
 #: summed over the expert layers of one step
@@ -181,27 +189,40 @@ def swiglu(x, gu_w, down_w, limit=None):
 
 
 def moe(lp, x, cfg, valid=None):
-    """Routed experts (held share) + shared expert.  ``valid`` bool [T]
-    marks the rows that are tokens: a pad row (a chunk's tail, an empty
-    slot) is routed like any row and given to NO held expert, so what it
-    would have chosen costs no expert a row and counts in no statistic
-    but ``assignments``.  Every SwiGLU is clamped at
-    ``cfg["swiglu_limit"]`` where the model has one.  Returns ``(y,
-    experts [T, k], stats)``."""
+    """Routed experts (held share) + the shared expert where the layer
+    has one (``lp["sh_gu_w"]``).  The router is ``cfg["scoring_func"]``'s:
+    "sigmoid" (the default: ``parallel.moe.grouped_topk_route`` with the
+    layer's bias, groups and scale) or "softmax"
+    (``parallel.moe.softmax_topk_route``).  ``valid`` bool [T] marks the
+    rows that are tokens: a pad row (a chunk's tail, an empty slot) is
+    routed like any row and given to NO held expert, so what it would
+    have chosen costs no expert a row and counts in no statistic but
+    ``assignments``.  Every SwiGLU is clamped at ``cfg["swiglu_limit"]``
+    where the model has one.  Returns ``(y, experts [T, k], stats)``."""
     import jax.numpy as jnp
     from ...parallel import moe as _moe
     limit = cfg.get("swiglu_limit")
+    scoring = cfg.get("scoring_func", "sigmoid")
     with device_scope("moe"):
         with device_scope("moe.route"):
-            experts, weights = _moe.grouped_topk_route(
-                x, lp["router_w"], lp["router_b"], cfg["n_group"],
-                cfg["topk_group"], cfg["num_experts_per_tok"],
-                cfg["routed_scaling_factor"])
+            if scoring == "sigmoid":
+                experts, weights = _moe.grouped_topk_route(
+                    x, lp["router_w"], lp["router_b"], cfg["n_group"],
+                    cfg["topk_group"], cfg["num_experts_per_tok"],
+                    cfg["routed_scaling_factor"])
+            elif scoring == "softmax":
+                experts, weights = _moe.softmax_topk_route(
+                    x, lp["router_w"], cfg["num_experts_per_tok"])
+            else:
+                raise ValueError("decoder_blocks.moe: no router for "
+                                 "scoring_func %r" % (scoring,))
             local = experts if valid is None \
                 else jnp.where(valid[:, None], experts, -1)
         y, stats = _moe.held_experts_ffn(
             x, local, weights, lp["gu_w"], lp["down_w"],
             cfg["experts_held"][0], lp["router_w"].shape[1], limit=limit)
+        if "sh_gu_w" not in lp:
+            return y, experts, stats
         with device_scope("moe.shared"):
             return y + swiglu(x, lp["sh_gu_w"], lp["sh_down_w"], limit), \
                 experts, stats
@@ -236,6 +257,206 @@ def rows_per_block(t, want):
     while t % r:
         r -= 1
     return r
+
+
+# ---------------------------------------------------------------------------
+# grouped-query attention of a model with window and full layers
+# (``exaone_moe.py``, ``mellum2.py``): a sliding layer's ring at decode and
+# its chunk, a full layer's chunk over the slot's pages
+# ---------------------------------------------------------------------------
+
+def attend(q, k, v, mask):
+    """Grouped-query softmax attention of queries ``q`` [Q, H, D] over
+    keys and values ``k``, ``v`` [K, K_kv, D] where ``mask`` [Q, K]
+    holds: the operands in the keys' stored type, scores, softmax and
+    sums in float32.  A query that sees no key gives zeros.  Returns
+    float32 [Q, H, D]."""
+    import jax.numpy as jnp
+    n, h, d = q.shape
+    kv = k.shape[1]
+    s = jnp.einsum("qkgd,skd->kgqs",
+                   q.reshape(n, kv, h // kv, d).astype(k.dtype), k,
+                   preferred_element_type=jnp.float32) \
+        * jnp.float32(d ** -0.5)
+    s = jnp.where(mask, s, NEG)
+    e = jnp.where(mask, jnp.exp(s - s.max(-1, keepdims=True)), 0.0)
+    o = jnp.einsum("kgqs,skd->qkgd", e.astype(v.dtype), v,
+                   preferred_element_type=jnp.float32)
+    total = e.sum(-1).transpose(2, 0, 1)[..., None]          # [Q, kv, g, 1]
+    return (o / jnp.maximum(total, 1e-30)).reshape(n, h, d)
+
+
+def attend_ring(q, ring_k, ring_v, positions, active, cfg):
+    """One decode step of a sliding layer: every slot's query ``q`` [S,
+    H, D] over its ring ``[S, window, K_kv * D]``, a row valid by the
+    position it holds (``pos - (pos - r) % window >= 0``)."""
+    import jax
+    import jax.numpy as jnp
+    s_n, window = q.shape[0], ring_k.shape[1]
+    kv, d = cfg["num_key_value_heads"], cfg["head_dim"]
+    held = positions[:, None] - (positions[:, None]
+                                 - jnp.arange(window)[None, :]) % window
+    mask = active[:, None] & (held >= 0)
+    o = jax.vmap(attend)(q[:, None], ring_k.reshape(s_n, window, kv, d),
+                         ring_v.reshape(s_n, window, kv, d),
+                         mask[:, None, :])
+    return o[:, 0]
+
+
+def attend_window(q, k, v, prev_k, prev_v, first_pos, window):
+    """A chunk of a sliding layer: queries ``q`` [T, H, D] at positions
+    ``first_pos ..`` over the chunk's own keys ``k`` [T, K_kv, D] and
+    the ``prev`` rows [R, K_kv, D] at positions ``first_pos - R ..
+    first_pos - 1``, a block of queries against the ``block + R`` keys
+    it can see.  Returns float32 [T, H, D]."""
+    import jax
+    import jax.numpy as jnp
+    t, r = q.shape[0], prev_k.shape[0]
+    rows = rows_per_block(t, window)
+    blocks = t // rows
+    idx = (jnp.arange(blocks) * rows)[:, None] \
+        + jnp.arange(rows + r)[None, :]
+    q_pos = first_pos + jnp.arange(t).reshape(blocks, rows)
+    k_pos = first_pos - r + idx
+    gap = q_pos[:, :, None] - k_pos[:, None, :]
+    mask = (k_pos[:, None, :] >= 0) & (gap >= 0) & (gap < window)
+    o = jax.vmap(attend)(q.reshape(blocks, rows, *q.shape[1:]),
+                         jnp.concatenate([prev_k, k])[idx],
+                         jnp.concatenate([prev_v, v])[idx], mask)
+    return o.reshape(q.shape)
+
+
+def attend_pages(q, k_pages, v_pages, block_table_row, first_pos):
+    """A chunk of a full layer: queries ``q`` [T, H, D] at positions
+    ``first_pos ..`` over the slot's pages, which hold every key up to
+    the chunk's own.  ``FULL_QUERY_BLOCK`` queries at a time walk the
+    blocks of ``FULL_KEY_BLOCK`` keys up to their last position with a
+    running softmax; the blocks behind are not entered.  Returns
+    float32 [T, H, D]."""
+    import jax.numpy as jnp
+    from jax import lax
+    t, h, d = q.shape
+    page = k_pages.shape[1]
+    kv = k_pages.shape[2] // d
+    g = h // kv
+    pages = max(1, min(FULL_KEY_BLOCK // page, block_table_row.shape[0]))
+    keys = pages * page
+    table = jnp.pad(block_table_row,
+                    (0, -block_table_row.shape[0] % pages))
+    rows = rows_per_block(t, FULL_QUERY_BLOCK)
+    scale = jnp.float32(d ** -0.5)
+
+    def one_block(args):
+        qb, first = args                                  # [rows, kv, g, d]
+        q_pos = first + jnp.arange(rows)
+
+        def step(j, carry):
+            m, total, acc = carry
+            at = lax.dynamic_slice_in_dim(table, j * pages, pages)
+            kb = k_pages[at].reshape(keys, kv, d)
+            vb = v_pages[at].reshape(keys, kv, d)
+            mask = (j * keys + jnp.arange(keys))[None, :] <= q_pos[:, None]
+            s = jnp.where(mask, jnp.einsum(
+                "qkgd,skd->kgqs", qb, kb,
+                preferred_element_type=jnp.float32) * scale, NEG)
+            m_new = jnp.maximum(m, s.max(-1))
+            e = jnp.where(mask, jnp.exp(s - m_new[..., None]), 0.0)
+            keep = jnp.exp(m - m_new)
+            return (m_new, total * keep + e.sum(-1),
+                    acc * keep[..., None] + jnp.einsum(
+                        "kgqs,skd->kgqd", e.astype(vb.dtype), vb,
+                        preferred_element_type=jnp.float32))
+
+        _, total, acc = lax.fori_loop(
+            0, (first + rows - 1) // keys + 1, step,
+            (jnp.full((kv, g, rows), NEG, jnp.float32),
+             jnp.zeros((kv, g, rows), jnp.float32),
+             jnp.zeros((kv, g, rows, d), jnp.float32)))
+        return acc / total[..., None]
+
+    o = lax.map(one_block, (
+        q.reshape(t // rows, rows, kv, g, d).astype(k_pages.dtype),
+        first_pos + jnp.arange(t // rows) * rows))
+    return o.transpose(0, 3, 1, 2, 4).reshape(t, h, d)
+
+
+def decode_attention(q, k, v, kc, vc, full, positions, active, ctx,
+                     ring_row, block_tables, cfg):
+    """One decode step of a layer of a model with window and full
+    layers: every slot's new K and V row written into its page (a full
+    layer, ``(k pool, v pool)``) or its ring row ``position % window``
+    (a sliding layer, ``(ring_k, ring_v)``: row ``ring_row`` of the
+    rings, the scratch row for an inactive slot), then the query ``q``
+    [S, H, D] over the pages (``paged_attention``, contexts ``ctx``) or
+    the ring (:func:`attend_ring`).  Returns ``(o float32 [S, H, D],
+    kc, vc)``."""
+    import jax.numpy as jnp
+    from ...ops.pallas.paged_attention import paged_attention
+    s_n = q.shape[0]
+    with device_scope("kv_write"):
+        k, v = k.reshape(s_n, -1), v.reshape(s_n, -1)
+        if full:
+            page_size = kc.shape[1]
+            phys = jnp.where(active, jnp.take_along_axis(
+                block_tables, (positions // page_size)[:, None],
+                axis=1)[:, 0], 0)
+            at = (phys, positions % page_size)
+        else:
+            at = (ring_row, positions % kc.shape[1])
+        kc = kc.at[at].set(k.astype(kc.dtype))
+        vc = vc.at[at].set(v.astype(vc.dtype))
+    if full:
+        with device_scope("attn.full"):
+            o = paged_attention(q, kc, vc, block_tables, ctx)
+    else:
+        with device_scope("attn.window"):
+            o = attend_ring(q, kc[:s_n], vc[:s_n], positions, active, cfg)
+    return o, kc, vc
+
+
+def chunk_attention(q, k, v, kc, vc, full, block_table_row, slot,
+                    prefix_len, prompt_len, n_rows, cfg):
+    """One chunk of a prompt (positions ``prefix_len ..``, the first
+    ``n_rows`` real, up to ``prompt_len``) through a layer of a model
+    with window and full layers.  A full layer writes the chunk's K and
+    V as whole pages (:func:`page_scatter`) and scores the chunk against
+    the slot's pages (:func:`attend_pages`); a sliding layer scores it
+    against its own rows and the ``window`` rows the chunk before left
+    in slot ``slot``'s ring (:func:`attend_window`), then leaves its own
+    newest rows there.  Returns ``(o float32 [T, H, D], kc, vc)``."""
+    import jax.numpy as jnp
+    from jax import lax
+    if full:
+        with device_scope("kv_write"):
+            kc, vc = (page_scatter(pool, block_table_row, rows,
+                                   prefix_len, n_rows)
+                      for pool, rows in ((kc, k), (vc, v)))
+        with device_scope("attn.full"):
+            o = attend_pages(q, kc, vc, block_table_row, prefix_len)
+        return o, kc, vc
+    window = kc.shape[1]
+    with device_scope("attn.window"):
+        k, v = k.astype(kc.dtype), v.astype(vc.dtype)
+        old_k, old_v = (lax.dynamic_index_in_dim(
+            ring, slot, 0, keepdims=False)
+            .reshape(window, *k.shape[1:]) for ring in (kc, vc))
+        # the ring's rows in the order of their positions,
+        # prefix_len - window .. prefix_len - 1
+        behind = (prefix_len + jnp.arange(window)) % window
+        o = attend_window(q, k, v, old_k[behind], old_v[behind],
+                          prefix_len, cfg["sliding_window"])
+    with device_scope("kv_write"):
+        # row r now holds the newest position p < prompt_len with
+        # p % window == r: this chunk's row if the chunk reaches it
+        newest = prompt_len - 1 \
+            - (prompt_len - 1 - jnp.arange(window)) % window
+        mine = (newest >= prefix_len)[:, None, None]
+        row = jnp.clip(newest - prefix_len, 0, q.shape[0] - 1)
+        kc, vc = (lax.dynamic_update_index_in_dim(
+            ring, jnp.where(mine, new[row], old)
+            .reshape(window, -1), slot, 0)
+            for ring, new, old in ((kc, k, old_k), (vc, v, old_v)))
+    return o, kc, vc
 
 
 def page_scatter(pool, block_table_row, rows, start, n_rows):

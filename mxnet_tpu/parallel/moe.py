@@ -7,12 +7,13 @@ one all_to_all moves token slots to their expert's device, each device runs
 its local experts as one batched matmul (MXU-friendly — no gather loops),
 and a second all_to_all brings results home for the weighted combine.
 
-Beside it, the expert layer of a SHARE (:func:`grouped_topk_route`,
-:func:`held_experts_ffn`): top-k over ALL experts with no capacity and
-no dropped token, computed for the contiguous range of experts this
-chip holds, at a cost that follows the tokens each held expert got.  It
-is what expert parallelism asks of one chip; the exchange that would
-bring other chips' tokens here is not part of it.
+Beside it, the expert layer of a SHARE (:func:`grouped_topk_route` or
+:func:`softmax_topk_route`, :func:`held_experts_ffn`): top-k over ALL
+experts with no capacity and no dropped token, computed for the
+contiguous range of experts this chip holds, at a cost that follows the
+tokens each held expert got.  It is what expert parallelism asks of one
+chip; the exchange that would bring other chips' tokens here is not part
+of it.
 """
 from __future__ import annotations
 
@@ -194,6 +195,25 @@ def grouped_topk_route(x, router_w, router_b, n_group, topk_group, k,
     return experts, w / w.sum(-1, keepdims=True) * scale
 
 
+def softmax_topk_route(x, router_w, k):
+    """Softmax router over all experts (Qwen3-MoE form,
+    ``norm_topk_prob``).
+
+    ``x`` [T, C]; ``router_w`` [C, E].  Probabilities ``p = softmax(x
+    W)`` over all ``E`` experts in float32; the ``k`` largest are
+    chosen and their weights are their ``p`` renormalised to sum 1 over
+    the chosen.  No bias, no groups, no scale.
+
+    Returns ``(experts int32 [T, k], weights float32 [T, k])``, as
+    :func:`grouped_topk_route`.
+    """
+    probs = jax.nn.softmax(jnp.dot(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST), axis=-1)
+    w, experts = lax.top_k(probs, k)
+    return experts.astype(jnp.int32), w / w.sum(-1, keepdims=True)
+
+
 def _round_up(n, to):
     return -(-n // to) * to
 
@@ -338,7 +358,8 @@ def held_experts_ffn(x, experts, weights, gu_w, down_w, first,
                      limit=None):
     """The held experts' part of ``sum_e w_e down_e(silu(gate_e x) *
     up_e x)`` for tokens ``x`` [T, C] routed by
-    :func:`grouped_topk_route` over ``num_experts`` experts (a clamped
+    :func:`grouped_topk_route` (or :func:`softmax_topk_route`) over
+    ``num_experts`` experts (a clamped
     SwiGLU where ``limit`` is given: :func:`swiglu_activation`).
 
     ``gu_w`` [E_held, C, 2F] (gate columns, then up), ``down_w``

@@ -527,6 +527,88 @@ def test_longdoc_engine_programs_compile_at_the_published_widths(
     assert held < 15.5 * 2 ** 30, mem
 
 
+_CODEASSIST_PROGRAMS = {}
+
+
+def _codeassist_engine_programs(monkeypatch):
+    """``{name: (fn, example shapes)}`` of the engine's programs over
+    Mellum 2's pipeline stage at its PUBLISHED widths and the benchmark
+    cell's own engine sizes (``perfbench/workloads/
+    mellum2-serve-codeassist.json``: 128 slots, 64-token pages, chunks of
+    2,048 rows, 12,288 pages), every kept layer, every expert held.
+    Neither weights nor caches are made (``_hybrid_engine_programs``)."""
+    if not _CODEASSIST_PROGRAMS:
+        from mxnet_tpu.gluon.model_zoo import mellum2
+        from mxnet_tpu.serving import ServingEngine
+        with open(os.path.join(REPO, "perfbench", "workloads",
+                               "mellum2-serve-codeassist.json")) as f:
+            cell = json.load(f)
+        net = mellum2.mellum2()
+        programs = net.serving_programs()
+        programs.decode_params = lambda net, kv_heads=None: \
+            mellum2.param_tree(net.cfg, lambda path, shape:
+                               jax.ShapeDtypeStruct(shape, jnp.bfloat16))
+        monkeypatch.setattr(net, "serving_programs", lambda: programs,
+                            raising=False)
+        monkeypatch.setattr(
+            ServingEngine, "_compile",
+            lambda self, name, fn, examples, extra:
+            _CODEASSIST_PROGRAMS.__setitem__(name, (fn, examples)))
+        make = ServingEngine._init_cache
+        monkeypatch.setattr(
+            ServingEngine, "_init_cache",
+            lambda self, kind: jax.eval_shape(lambda: make(self, kind)))
+        _CODEASSIST_PROGRAMS["engine"] = ServingEngine(net, **cell["engine"])
+        _CODEASSIST_PROGRAMS["compiled_gb"] = cell["compiled_gb"]
+    return _CODEASSIST_PROGRAMS
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_codeassist_engine_programs_compile_at_the_published_widths(
+        chip, monkeypatch, program):
+    """Both programs of the model with rings of 1,024 rows beside pages
+    and all 64 experts held compile for the described v5e at the cell's
+    sizes: the paged kernel at 32 query heads over 4 K/V heads of 128
+    and the grouped matmul at K 2304 / N 1792 and K 896 / N 2304, every
+    cache aliased (pools and rings are updated where they lie), and
+    arguments and temporaries as the workload file's ``compiled_gb``
+    states them, inside the chip's 16 GiB.  The engine's sizing units
+    are the configuration file's."""
+    got = _codeassist_engine_programs(monkeypatch)
+    eng = got["engine"]
+    with open(os.path.join(REPO, "perfbench", "configs",
+                           "mellum2-12b-a2.5b.json")) as f:
+        cache = json.load(f)["cache"]
+    assert eng.kv_bytes_per_token == cache["page_bytes_per_token"] == 4096
+    assert eng.state_bytes_per_slot == cache["ring_bytes_per_slot"] \
+        == 6 * 2 * 1024 * 1024
+    assert [tuple(a.shape) for a in eng._kv[3]] == [(12288, 64, 512)] * 2
+    assert [tuple(a.shape) for a in eng._kv[0]] == [(129, 1024, 512)] * 2
+    fn, examples = got[program]
+    caches = jax.tree_util.tree_leaves(examples[1])
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+            *jax.tree_util.tree_map(lambda a: chip(a.shape, a.dtype),
+                                    examples)).compile()
+    text = compiled.as_text()
+    assert "moe_gmm" in text
+    if program == "decode":
+        assert "paged_decode" in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(
+        int(np.prod(a.shape)) * a.dtype.itemsize for a in caches), mem
+    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        + max(0, mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    print(program, "arguments %.3f GB, temporaries %.3f GB"
+          % (mem.argument_size_in_bytes / 1e9, mem.temp_size_in_bytes / 1e9))
+    want = got["compiled_gb"]
+    assert mem.argument_size_in_bytes / 1e9 == pytest.approx(
+        want["arguments"], abs=0.01)
+    assert mem.temp_size_in_bytes / 1e9 == pytest.approx(
+        want[program + "_temporaries"], abs=0.02)
+    assert held < 15.5 * 2 ** 30, mem
+
+
 def test_latent_prefill_kernel_at_the_longdoc_cell_stays_in_its_vmem(chip):
     """``mla_paged_prefill`` at ``gigachat-serve-longdoc``'s widths: a
     chunk of 2,048 rows, 64 heads, rows of 640 lanes in 17,409 pages of

@@ -5,7 +5,7 @@
 instruction its scope path, and ``perfbench/readers/device_scopes.py``
 sums a traced slice by program and scope.  Here, on the CPU:
 
-- the tiny engines of all four model families and the fused fit step:
+- the tiny engines of every served model family and the fused fit step:
   every scope documented for a program is on at least one of its
   instructions, the table's paths use declared names only, and the share
   of the instructions the program wrote (non-trivial, with an
@@ -77,6 +77,12 @@ DOCUMENTED = {
     ("kexaone", "serve_prefill"): (
         "embed", "norm", "attn.proj", "attn.full", "attn.window",
         "attn.out", "kv_write", "mlp", "lm_head", "sample") + MOE,
+    ("mellum2", "serve_decode"): (
+        "embed", "norm", "attn.proj", "attn.full", "attn.window",
+        "attn.out", "kv_write", "lm_head", "sample") + MOE[:-1],
+    ("mellum2", "serve_prefill"): (
+        "embed", "norm", "attn.proj", "attn.full", "attn.window",
+        "attn.out", "kv_write", "lm_head", "sample") + MOE[:-1],
     ("gigachat", "serve_decode"): (
         "embed", "norm", "attn.proj", "attn.mla", "attn.out", "kv_write",
         "gdn.proj", "gdn_step", "gdn.out", "state_write", "mlp", "lm_head",
@@ -107,7 +113,7 @@ def _family(name):
         mod.fit_step(batches[0])
         return mod
     from mxnet_tpu.gluon.model_zoo import (deepseek_v32, exaone_moe,
-                                           gigachat35, ling3)
+                                           gigachat35, ling3, mellum2)
     np.random.seed(0)
     mx.random.seed(0)
     if name == "ling3":
@@ -122,6 +128,10 @@ def _family(name):
         return ServingEngine(
             gigachat35.gigachat35_tiny().init_seeded(3), num_slots=2,
             page_size=4, num_pages=48, max_prefill_len=12, max_seq_len=80)
+    if name == "mellum2":
+        return ServingEngine(
+            mellum2.mellum2_tiny().init_seeded(3), num_slots=2, page_size=4,
+            num_pages=48, max_prefill_len=12, max_seq_len=80)
     assert name == "kexaone"
     return ServingEngine(
         exaone_moe.exaone_moe_tiny().init_seeded(3), num_slots=2,
@@ -134,7 +144,8 @@ def programs():
     ``program_scopes()`` has them, found by the compiled object its
     owner holds."""
     out, owners = {}, []
-    for family in ("gpt", "ling3", "dsv32", "kexaone", "gigachat", "fit"):
+    for family in ("gpt", "ling3", "dsv32", "kexaone", "gigachat", "mellum2",
+                   "fit"):
         before = {id(c) for c in telemetry._programs}
         owner = _family(family)
         owners.append(owner)

@@ -109,9 +109,9 @@ def test_a_sliding_layer_rotates_and_a_full_layer_does_not(net):
                                           (12, 24, 7), (16, 16, 16),
                                           (6, 36, 1)])
 def test_a_chunk_of_a_sliding_layer_is_the_banded_softmax(t, first, rows):
-    """``_attend_window`` (a block of queries against the keys it can
-    see, the rows before the chunk from the ring) against one softmax
-    under the banded mask over the whole sequence."""
+    """``decoder_blocks.attend_window`` (a block of queries against the
+    keys it can see, the rows before the chunk from the ring) against one
+    softmax under the banded mask over the whole sequence."""
     rng = np.random.default_rng(t + first)
     total = first + t
     q, k, v = (jnp.asarray(rng.normal(size=(total, h, 16)), jnp.float32)
@@ -125,20 +125,22 @@ def test_a_chunk_of_a_sliding_layer_is_the_banded_softmax(t, first, rows):
     for p in range(first):
         ring_k[p % WINDOW], ring_v[p % WINDOW] = k[p], v[p]
     behind = (first + np.arange(WINDOW)) % WINDOW
-    got = ex._attend_window(q[first:], k[first:], v[first:],
-                            jnp.asarray(ring_k[behind]),
-                            jnp.asarray(ring_v[behind]), first, WINDOW)
+    got = decoder_blocks.attend_window(q[first:], k[first:], v[first:],
+                                       jnp.asarray(ring_k[behind]),
+                                       jnp.asarray(ring_v[behind]), first,
+                                       WINDOW)
     assert np.abs(np.asarray(got - want))[:rows].max() < 2e-6
 
 
 @pytest.mark.parametrize("first", [0, 12, 36])
 def test_a_chunk_of_a_full_layer_walks_the_pages_in_blocks(first,
                                                            monkeypatch):
-    """``_attend_pages`` with blocks of 4 queries and 8 keys (so that a
-    chunk takes several of each and the blocks behind a query block are
-    skipped) against one softmax under the causal mask."""
-    monkeypatch.setattr(ex, "FULL_QUERY_BLOCK", 4)
-    monkeypatch.setattr(ex, "FULL_KEY_BLOCK", 8)
+    """``decoder_blocks.attend_pages`` with blocks of 4 queries and 8
+    keys (so that a chunk takes several of each and the blocks behind a
+    query block are skipped) against one softmax under the causal
+    mask."""
+    monkeypatch.setattr(decoder_blocks, "FULL_QUERY_BLOCK", 4)
+    monkeypatch.setattr(decoder_blocks, "FULL_KEY_BLOCK", 8)
     rng = np.random.default_rng(first)
     total = first + CHUNK
     q, k, v = (jnp.asarray(rng.normal(size=(total, h, 16)), jnp.float32)
@@ -151,9 +153,9 @@ def test_a_chunk_of_a_full_layer_walks_the_pages_in_blocks(first,
     for pool, rows in zip(pools, (k, v)):
         for p in range(total):
             pool[table[p // PAGE], p % PAGE] = np.asarray(rows[p]).ravel()
-    got = ex._attend_pages(q[first:], jnp.asarray(pools[0]),
-                           jnp.asarray(pools[1]),
-                           jnp.asarray(table, jnp.int32), first)
+    got = decoder_blocks.attend_pages(q[first:], jnp.asarray(pools[0]),
+                                      jnp.asarray(pools[1]),
+                                      jnp.asarray(table, jnp.int32), first)
     assert np.abs(np.asarray(got - want)).max() < 2e-6
 
 
@@ -459,8 +461,8 @@ def test_this_model_shares_its_layer_functions():
     for name in ("mm", "rms", "swiglu", "moe", "head"):
         assert getattr(ex, "_" + name) is getattr(decoder_blocks, name)
         assert getattr(ling3, "_" + name) is getattr(decoder_blocks, name)
-    assert ex._rows_per_block is deepseek_v32._rows_per_block \
-        is decoder_blocks.rows_per_block
+    assert deepseek_v32._rows_per_block is decoder_blocks.rows_per_block
+    assert ex._attend is decoder_blocks.attend
     assert gpt._page_scatter is decoder_blocks.page_scatter
     assert ex.DECODE_STATS[:len(decoder_blocks.MOE_STATS)] \
         == decoder_blocks.MOE_STATS
